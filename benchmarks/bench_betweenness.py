@@ -1,4 +1,4 @@
-"""Betweenness at scale (VERDICT r4 item 10): sampled Brandes on a
+"""Betweenness at scale: sampled Brandes on a
 1M-node / 10M-edge graph with the autotuned (B, n_pad) chunking,
 correctness-anchored by exact parity at small scale.
 

@@ -1,5 +1,5 @@
 """Streaming-insert vector index micro-bench: O(delta) refresh vs full
-rebuild (VERDICT r3 item 6 'Done' criterion).
+rebuild.
 
 Run: python benchmarks/bench_vector_delta.py [n_vectors] [dim]
 """

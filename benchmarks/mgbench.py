@@ -11,7 +11,7 @@ methodology: isolated query groups, latency percentiles + throughput):
   aggregate         global count/avg
   analytical        CALL pagerank.get() (device path)
 
-Round 5 additions (VERDICT r4 item 4): a supernode-skew workload
+Round 5 additions: a supernode-skew workload
 (/root/reference/tests/mgbench/workloads/supernode.py — one hub node
 with CARDINALITY in-edges), a multiprocess read-executor group
 (server/mp_executor.py), and `--out OLTP_rN.json` so every round ships

@@ -1,9 +1,8 @@
 """On-chip microbench: large-array streaming rates for the kernel's primitive
 mix (FMA stream, Benes masked-swap stage, roll, one-hot einsum, transpose).
 
-Safety per docs/kernel_design_r2.md: runs with an internal deadline and
-exits cleanly (never SIGTERM a process with in-flight TPU work). Sync via
-1-element host transfer (block_until_ready unreliable on this platform).
+Safety: runs with an internal deadline and exits cleanly (never SIGTERM
+a process with in-flight TPU work). Sync via 1-element host transfer.
 
 Usage: python benchmarks/microbench_hbm.py [deadline_s]
 """

@@ -1,6 +1,6 @@
 """Framework-wide exception hierarchy.
 
-Mirrors the error taxonomy the reference surfaces to clients (storage errors
+Mirrors the error classification the reference surfaces to clients (storage errors
 at /root/reference/src/storage/v2/storage.hpp, query exceptions at
 /root/reference/src/query/exceptions.hpp) without copying its structure.
 """
@@ -135,7 +135,7 @@ class AuthException(MemgraphTpuError):
 
 
 #: Worker-shipped error envelopes carry ``(type_name, message)``
-#: strings; this is the decode table back into the typed taxonomy.
+#: strings; this is the decode table back into the typed classification.
 #: Message-only constructors only — classes with structured payloads
 #: (StaleShardEpoch) or process-lifecycle semantics (WorkerCrashedError,
 #: WriteInDoubtError) are deliberately absent and fall through to the
@@ -164,7 +164,7 @@ WIRE_ERRORS = {
 
 
 def raise_wire_error(type_name: str, message: str):
-    """Rehydrate a worker error envelope into its taxonomy class, so
+    """Rehydrate a worker error envelope into its typed class, so
     pool/plane clients surface SyntaxException as SyntaxException
     instead of a stringly generic error. Unknown type names (builtin
     exceptions, future classes crossing an old wire) degrade to

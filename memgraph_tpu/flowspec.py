@@ -34,7 +34,7 @@ class ServingRoot:
     function path inside it. ``raises`` lists exception type names that
     MAY propagate out of the root — subclasses are covered by their
     bases, so ``("MemgraphTpuError",)`` admits the whole typed
-    taxonomy. An empty contract means the root must be total: every
+    classification. An empty contract means the root must be total: every
     exception is handled inside the loop (the supervised-daemon shape).
     """
 
@@ -134,7 +134,7 @@ SERVING_ROOTS = (
         path="sharding/router.py",
         qualname="ShardedClient._prepare_one",
         raises=("MemgraphTpuError",),
-        why="prepare surfaces only the typed taxonomy: vote-no, bounce "
+        why="prepare surfaces only the typed classification: vote-no, bounce "
             "exhaustion and worker death all land in MemgraphTpuError "
             "subclasses the 2PC driver's presumed-abort path handles",
     ),

@@ -1,9 +1,9 @@
 """Benes permutation-network routing.
 
-TPU-native data movement: XLA on this platform runs elementwise/matmul at
-full speed but any gather/scatter/sort formulation is ~1000x slower (see
-docs/kernel_design_r2.md). A fixed permutation is therefore applied as a
-Benes network: 2*log2(N)-1 stages of masked aligned swaps, each stage a
+TPU-native data movement: the design premise is that the chip runs
+elementwise/matmul at full speed while gather/scatter/sort formulations
+are far slower (the ratio is not measured on the current machine). A
+fixed permutation is therefore applied as a Benes network: 2*log2(N)-1 stages of masked aligned swaps, each stage a
 pure reshape + reverse + select — all VPU-friendly XLA ops.
 
 The routing (which pairs swap at each stage) is computed once on the host

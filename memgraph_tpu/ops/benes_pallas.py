@@ -2,8 +2,7 @@
 
 The XLA roll formulation (ops/spmv_mxu._benes_apply_rolls) re-reads and
 re-writes the full array once per stage: 2*log2(N)-1 HBM round trips
-(~47 at N=2^24), which round-4 profiling showed is ~90% of the PageRank
-per-iteration cost. This module exploits the Benes stage order
+(47 at N=2^24). This module exploits the Benes stage order
 (d = N/2 ... 2, 1, 2 ... N/2): every stage with distance d < 2^K acts
 entirely inside aligned 2^K-element blocks (XOR by d < 2^K cannot leave
 the block), and those stages are CONTIGUOUS in the middle of the
@@ -19,8 +18,7 @@ schedule. So:
 Masks are shipped as per-element int32 bit-planes: bit b of
 word[plane, i] is stage (plane*31+b)'s swap decision for element i, so
 extraction is an elementwise shift+AND — no gathers, no repeats, no
-narrow dtypes (which this platform compiles pathologically, see
-ops/blob.py). 31 bits per int32 plane keeps the sign bit out of play.
+narrow dtypes (ops/blob.py). 31 bits per int32 plane keeps the sign bit out of play.
 
 Reference analog: none — the reference scatters via CUDA/C++; this is
 the TPU-native formulation of applying a fixed permutation at HBM speed.

@@ -15,7 +15,7 @@ Decomposition (1D edge partition, scaling-book style):
     replicated on every device (O(N) work, no comms).
 
 Per-iteration cost model: t_iter(P) = t_edge(E/P) + t_allreduce(N) +
-t_node(N); measured numbers in docs/scaling_model_r4.md.
+t_node(N); not measured on the current machine.
 
 Reference analog: the reference scales pagerank via cuGraph/NCCL
 (mage/cpp/cugraph_module/algorithms/pagerank.cu); this is the
@@ -179,8 +179,6 @@ def make_sharded_pagerank_kernel(plan: ShardedMXUPlan, mesh,
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    # version-gated central resolution (parallel/mesh.py): warns once on
-    # the jax-0.4 check_rep=False fallback instead of silently degrading
     from ..parallel.mesh import shard_map_fn
     shard_map = shard_map_fn()
     from .blob import pack_blob, unblob

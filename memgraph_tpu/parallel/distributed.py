@@ -32,9 +32,6 @@ from ..ops.csr import DeviceGraph, ShardedCSR
 from ..ops.semiring import (backend_extent, edge_combine, edge_reduce,
                             pagerank_update, resolve_semiring)
 
-# version-gated central resolution (parallel/mesh.py): jax >= 0.5 uses the
-# public jax.shard_map; the 0.4 line gets the experimental one with
-# check_rep=False and a WARNING logged once — never a silent fallback
 shard_map = shard_map_fn()
 
 
@@ -427,12 +424,17 @@ def _pc_pagerank_build(ctx: MeshContext, block: int, n_shards: int,
     n_pad2 = n_shards * block
 
     def step(src_blk, dst_blk, w_blk, n_nodes, damping, tol,
-             rank, local_err_v, g_err_prev, it, it_stop):
+             rank, local_err_v, g_err_v, it, it_stop):
         src_blk, dst_blk, w_blk = src_blk[0], dst_blk[0], w_blk[0]
         # local_err is a genuinely per-shard partial (it rides the next
         # iteration's collective), so it crosses chunk boundaries as a
         # P(axis)-sharded (n_shards,) vector: one lane per device
         local_err = local_err_v[0]
+        # the trailing global error is one lane of the psum_scatter
+        # result: every shard holds the same VALUE, but shard_map types a
+        # scattered row as varying over the axis, so it crosses chunk
+        # boundaries per shard too (the host reads lane 0)
+        g_err_prev = g_err_v[0]
         shard_id = jax.lax.axis_index(axis)
         base = shard_id * block
         n_f = n_nodes.astype(jnp.float32)
@@ -476,7 +478,7 @@ def _pc_pagerank_build(ctx: MeshContext, block: int, n_shards: int,
 
         rank, local_err, g_err, iters = jax.lax.while_loop(
             cond, body, (rank, local_err, g_err_prev, it))
-        return rank, local_err.reshape(1), g_err, iters
+        return rank, local_err.reshape(1), g_err.reshape(1), iters
 
     Pr = P()
     Pe = P(axis, None)
@@ -488,8 +490,8 @@ def _pc_pagerank_build(ctx: MeshContext, block: int, n_shards: int,
     # donated inputs (parallel/checkpoint.run_resumable)
     return jax.jit(shard_map(
         step, mesh=ctx.mesh,
-        in_specs=(Pe, Pe, Pe, Pr, Pr, Pr, Pv, Pv, Pr, Pr, Pr),
-        out_specs=(Pv, Pv, Pr, Pr)), donate_argnums=(6, 7, 8, 9))
+        in_specs=(Pe, Pe, Pe, Pr, Pr, Pr, Pv, Pv, Pv, Pr, Pr),
+        out_specs=(Pv, Pv, Pv, Pr)), donate_argnums=(6, 7, 8, 9))
 
 
 _PC_KERNEL_CACHE: dict = {}
@@ -600,7 +602,8 @@ def pagerank_partition_centric(scsr: ShardedCSR, ctx: MeshContext,
             rank0 /= np.float32(total)
     carry0 = (rank0,
               np.full((scsr.n_shards,), np.inf, dtype=np.float32),
-              np.float32(np.inf), np.int32(0))
+              np.full((scsr.n_shards,), np.inf, dtype=np.float32),
+              np.int32(0))
 
     def chunk_of(s):
         def chunk(carry, it_stop):
@@ -614,7 +617,7 @@ def pagerank_partition_centric(scsr: ShardedCSR, ctx: MeshContext,
         carry0=carry0, iter_index=3, max_iterations=max_iterations,
         checkpoint_every=checkpoint_every, job=job, store=store,
         retry=retry, chunk_deadline_s=chunk_deadline_s, report=report)
-    return rank[:scsr.n_nodes], float(err), int(iters)
+    return rank[:scsr.n_nodes], float(err[0]), int(iters)
 
 
 def _pc_katz_build(ctx: MeshContext, block: int, n_shards: int,

@@ -3,12 +3,8 @@
 This is the single place the analytics stack learns about devices. It
 provides:
 
-  * `resolve_shard_map()` — the version-gated `shard_map` resolution. On
-    jax >= 0.5 the public `jax.shard_map` (with replication checking) is
-    used; on the 0.4 line the experimental one is wrapped with
-    `check_rep=False` (0.4 has no replication rule for `while_loop`) and
-    a WARNING is logged ONCE per process instead of silently taking the
-    fallback.
+  * `shard_map_fn()` — the one name every sharded kernel takes
+    `jax.shard_map` through (replication checking stays on).
   * `MeshContext` — a mesh plus its canonical `NamedSharding`s
     (replicated / edge-blocked / vertex-blocked), built once per
     (device-count, axis) and cached, so kernels never re-derive
@@ -40,54 +36,20 @@ _EDGE_AXIS = "shard"
 
 
 # --------------------------------------------------------------------------
-# shard_map resolution (version-gated; warn once on the 0.4 fallback)
+# shard_map resolution
 # --------------------------------------------------------------------------
-
-_shard_map_cache = None
-_fallback_warned = False
-_resolve_lock = threading.Lock()
 
 
 def resolve_shard_map():
-    """Return (shard_map_fn, is_fallback).
-
-    jax >= 0.5 exports `jax.shard_map` with a `while_loop` replication
-    rule; there the public API is used unchanged. The jax-0.4 line only
-    has `jax.experimental.shard_map` and cannot replication-check
-    `while_loop` bodies, so it is wrapped with `check_rep=False` — and
-    that downgrade is WARNING-logged once per process, because it also
-    disables the rewrite that lets XLA fold replicated outputs without
-    an all-gather (the silent slow path BENCH_r05 paid).
-    """
-    global _shard_map_cache, _fallback_warned
-    if _shard_map_cache is not None:
-        return _shard_map_cache
-    with _resolve_lock:
-        if _shard_map_cache is not None:
-            return _shard_map_cache
-        try:
-            from jax import shard_map  # jax >= 0.5
-            _shard_map_cache = (shard_map, False)
-        except ImportError:
-            import functools
-            from jax.experimental.shard_map import shard_map as _sm
-            import jax
-            if not _fallback_warned:
-                _fallback_warned = True
-                logger.warning(
-                    "jax %s has no public jax.shard_map; using "
-                    "jax.experimental.shard_map with check_rep=False "
-                    "(no replication rule for while_loop on the 0.4 "
-                    "line). Correctness is unaffected; replicated "
-                    "outputs lose the check that they stay "
-                    "collective-free.", jax.__version__)
-            _shard_map_cache = (functools.partial(_sm, check_rep=False),
-                                True)
-    return _shard_map_cache
+    """Return (shard_map_fn, is_fallback). The installed JAX exports the
+    public `jax.shard_map`, whose varying-axes check covers `while_loop`
+    carries; there is no fallback, so the flag is always False."""
+    from jax import shard_map
+    return shard_map, False
 
 
 def shard_map_fn():
-    """The resolved shard_map callable (most call sites only want this)."""
+    """The shard_map callable (most call sites only want this)."""
     return resolve_shard_map()[0]
 
 

@@ -6,7 +6,7 @@ fronts the usearch HNSW index, src/storage/v2/indices/vector_index.cpp:22-73
 for the update path): here search IS the index — batched MXU matmul + top_k
 over a device-resident embedding matrix.
 
-Incremental maintenance design (solves the four NOTES_ROUND2 holes):
+Incremental maintenance design (four holes it closes):
   1. replica WAL apply bypasses commit hooks → there are NO hooks: the
      storage records changed-gid sets at every topology bump (including
      WAL apply and recovery), and the index PULLS the delta via
@@ -144,7 +144,7 @@ def _delta_refresh(ctx, parent: _IndexEntry, changed, version):
             new_vecs[gid] = vec
         else:
             # off-dimension candidate: counted (dominance tracking,
-            # NOTES_ROUND2 hole #4) but holds no row
+            # hole 4 above) but holds no row
             dim_counts[len(vec)] += 1
             offdim[gid] = len(vec)
             drop_row(gid)

@@ -1379,8 +1379,21 @@ class Interpreter:
                                            ["version"], "r")
         if node.kind == "build":
             from .. import __version__
+            import jax
+            from ..ops import native
+            from ..utils.jax_cache import ensure_compile_cache
+            # what the process actually holds, not what it was built for
+            ensure_compile_cache()
+            devices = jax.devices()
             rows = [["version", __version__], ["build_type", "Release"],
-                    ["backend", "jax/XLA (TPU)"]]
+                    ["backend", "jax/XLA"],
+                    ["device_platform", devices[0].platform],
+                    ["device_kind", devices[0].device_kind],
+                    ["device_count", len(devices)],
+                    ["native_builder", "loaded" if native.get_lib()
+                     is not None else "numpy fallback"],
+                    ["compile_cache_dir",
+                     jax.config.jax_compilation_cache_dir or "off"]]
             return self._prepare_generator(iter(rows),
                                            ["build info", "value"], "r")
         if node.kind == "license":

@@ -23,7 +23,7 @@ fingerprint (``lane.fallback_total.<reason>``; per-fingerprint table in
 ``GET /stats`` -> ``lane``) — and CORRECT: the host paths own the exact
 semantics, so a refused shape never changes results.
 
-Fallback taxonomy (docs/architecture.md §Compiled read lane):
+Fallback classification (docs/architecture.md §Compiled read lane):
   shape-level   group_by, agg_avg/agg_<kind>, remember, multi_key,
                 edge_prop, dynamic_predicate, direction, edge_type_mix
   data-level    float_column, float_rhs, big_int, column_kind,

@@ -85,6 +85,9 @@ class MPReadExecutor:
             self._locks.append(threading.Lock())
 
     def _spawn_one(self) -> tuple:
+        # a fork of the chip's owner inherits a TPU runtime it cannot use
+        from ..utils.devicefault import refuse_chip_child
+        refuse_chip_child("fork a read worker")
         req_r, req_w = os.pipe()
         resp_r, resp_w = os.pipe()
         pid = os.fork()
@@ -194,7 +197,7 @@ class MPReadExecutor:
     def execute(self, query: str, params: dict | None = None):
         """Round-robin a read-only query to a worker; returns
         (columns, rows). Worker-side errors are rehydrated into the
-        typed taxonomy (SyntaxException stays SyntaxException across
+        typed classification (SyntaxException stays SyntaxException across
         the fork boundary)."""
         from ..observability.metrics import global_metrics
         from ..observability.stats import global_query_stats

@@ -344,7 +344,7 @@ class Accessor:
         # what this reader's MVCC snapshot corresponds to: commits AFTER
         # this accessor began are invisible to it, so version-keyed caches
         # built through it must key on THIS, not the live version
-        # (vector-index delta maintenance, NOTES_ROUND2 hole #2).
+        # (vector-index delta maintenance).
         # Captured by _begin_transaction under the engine lock, atomically
         # with the snapshot timestamp.
         self.topology_snapshot = self.txn.topology_snapshot
@@ -1605,8 +1605,7 @@ class InMemoryStorage:
         consumers must fully rebuild). The bounded change log lets
         version-keyed caches (vector index) refresh O(delta) instead of
         O(n): every mutation path funnels here, INCLUDING replica WAL
-        apply and recovery, so deltas are never silently missed
-        (NOTES_ROUND2 hole #1)."""
+        apply and recovery, so deltas are never silently missed."""
         with self._change_log_lock:
             shared_write(self, "_change_log")
             self._topology_version += 1

@@ -500,7 +500,7 @@ def test_device_op_point_mapping():
         device_schedule(0, ops=("device_call", "typo"))
 
 
-def test_classify_device_error_taxonomy():
+def test_classify_device_error_classes():
     assert classify_device_error(DeviceOomError("x")) == "oom"
     assert classify_device_error(DeviceLostError("x")) == "device_lost"
     assert classify_device_error(ValueError("x")) is None

@@ -135,7 +135,7 @@ def test_garbage_header_drops_connection_not_thread(tmp_path):
 
 def test_typed_outcome_crosses_the_wire(tmp_path):
     """A KernelServerError raised inside dispatch ships its outcome +
-    retryable flag, and the client rehydrates the taxonomy class."""
+    retryable flag, and the client rehydrates the typed class."""
     from memgraph_tpu.server.kernel_server import (AdmissionRejected,
                                                    _raise_for_reply,
                                                    _recv_msg, _send_msg)
@@ -161,3 +161,84 @@ def test_typed_outcome_crosses_the_wire(tmp_path):
         assert reply["ok"] is True
     finally:
         conn.close()
+
+
+# --------------------------------------------------------------------------
+# one owner per chip (PR 22)
+# --------------------------------------------------------------------------
+
+def test_spawned_daemon_logs_stderr_next_to_its_socket(server):
+    _, sock = server
+    assert os.path.exists(sock + ".log")
+
+
+def test_cpu_process_does_not_hold_the_chip():
+    import jax
+    from memgraph_tpu.utils.devicefault import (process_holds_tpu,
+                                                refuse_chip_child)
+    jax.devices()                       # backend initialised: the CPU's
+    assert process_holds_tpu() is False
+    refuse_chip_child("do anything")    # no refusal
+
+
+@pytest.mark.parametrize("starter", ["ensure_server", "supervised_client",
+                                     "mp_executor"])
+def test_chip_owner_refuses_a_chip_owning_child(tmp_path, monkeypatch,
+                                                starter):
+    """A process that has initialised the TPU backend gets a typed
+    refusal — not a daemon that dies (or hangs) on the chip's lock."""
+    from memgraph_tpu.server import kernel_server as ks
+    from memgraph_tpu.utils import devicefault
+    monkeypatch.setattr(devicefault, "process_holds_tpu", lambda: True)
+    started = []
+    monkeypatch.setattr(ks.subprocess, "Popen",
+                        lambda *a, **k: started.append(a))
+    monkeypatch.setattr(os, "fork", lambda: started.append("fork"))
+    sock = str(tmp_path / "none.sock")
+    with pytest.raises(devicefault.ChipOwnedError, match="owns the chip"):
+        if starter == "ensure_server":
+            ensure_server(sock, spawn_timeout_s=5)
+        elif starter == "supervised_client":
+            # not a connection error: no supervised retry swallows it
+            ks.SupervisedKernelClient(
+                sock, spawn=True, spawn_timeout_s=5).pagerank(
+                src=[0], dst=[0], n_nodes=1)
+        else:
+            from memgraph_tpu.server.mp_executor import MPReadExecutor
+            MPReadExecutor(ictx=None, n_workers=1)
+    assert not started
+    assert not os.path.exists(sock + ".log")
+
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = \
+            platform, f"fake {platform}", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,expect", [
+    ("tpu", {"bytes_limit": 16 << 30}, 12 << 30),
+    ("cpu", None, 4 << 30),
+    ("cpu", {}, 4 << 30),
+    ("tpu", None, RuntimeError),
+    ("tpu", {"bytes_limit": 0}, RuntimeError),
+])
+def test_hbm_budget_never_guessed_on_a_tpu(monkeypatch, caplog, platform,
+                                           stats, expect):
+    import logging
+    import jax
+    from memgraph_tpu.server import kernel_server as ks
+    monkeypatch.delenv("MEMGRAPH_TPU_HBM_BUDGET_BYTES", raising=False)
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, stats)])
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match="no bytes_limit"):
+            ks._resolve_hbm_budget()
+        return
+    with caplog.at_level(logging.INFO, logger=ks.log.name):
+        assert ks._resolve_hbm_budget() == expect
+    assert sum("HBM admission budget" in r.getMessage()
+               for r in caplog.records) == 1

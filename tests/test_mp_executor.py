@@ -72,7 +72,7 @@ def test_concurrent_dispatch(ictx):
 
 def test_worker_error_transport(ictx):
     """Worker-side errors cross the fork boundary TYPED: the parent
-    re-raises the taxonomy class the worker named, not a stringly
+    re-raises the typed class the worker named, not a stringly
     RuntimeError."""
     from memgraph_tpu.exceptions import SyntaxException
     ex = MPReadExecutor(ictx, n_workers=1)

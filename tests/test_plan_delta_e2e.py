@@ -1,6 +1,6 @@
 """Commit-then-CALL plan refresh: a topology-mutating commit must NOT
 force a full MXU replan — the next pagerank call derives an O(delta)
-side-plan from the storage change log (VERDICT r4 item 2).
+side-plan from the storage change log.
 """
 
 import numpy as np

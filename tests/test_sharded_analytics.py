@@ -378,21 +378,19 @@ def test_mglint_flags_stub_exemption_and_dangling_target(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# shard_map version gate
+# shard_map resolution
 # --------------------------------------------------------------------------
 
-def test_shard_map_resolver_is_cached_and_warns_once(caplog):
+def test_shard_map_resolver_is_the_public_checked_one(caplog):
     import logging
     fn1, fb1 = resolve_shard_map()
     with caplog.at_level(logging.WARNING,
                          logger="memgraph_tpu.parallel.mesh"):
         fn2, fb2 = resolve_shard_map()
-    assert fn1 is fn2 and fb1 == fb2
-    # the warning (if the fallback applies) fired at first resolution,
-    # not on every call
+    assert fn1 is fn2 is jax.shard_map
+    # no fallback exists: replication checking is never switched off
+    assert fb1 is False and fb2 is False
     assert not caplog.records
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        assert fb1, "jax 0.4 must report the check_rep=False fallback"
 
 
 def test_resolve_mesh_accepts_all_spellings(ctx8):

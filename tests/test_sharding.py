@@ -517,7 +517,7 @@ def test_pre_send_crash_still_retries_transparently(
 
 
 def test_worker_errors_decode_typed_across_the_shard_wire(plane):
-    """Worker-side taxonomy errors cross the process boundary TYPED:
+    """Worker-side classification errors cross the process boundary TYPED:
     the plane re-raises the class the worker named instead of a
     stringly MemgraphTpuError."""
     from memgraph_tpu.exceptions import SyntaxException

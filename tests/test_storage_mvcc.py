@@ -324,7 +324,7 @@ def test_read_committed_sees_latest():
 
 
 def test_post_commit_accessor_sees_own_committed_state(storage):
-    """VERDICT r2 regression: an accessor returned to the client (RETURN n,
+    """Regression: an accessor returned to the client (RETURN n,
     materialized after the transaction committed and stream exhausted) must
     see the transaction's OWN committed writes, not the pre-txn state —
     commit rewrites delta timestamps to the commit ts, so the own-write

@@ -94,7 +94,7 @@ def test_system_state_in_catchup(cluster):
 
 
 def test_failover_preserves_system_state(cluster):
-    """The VERDICT e2e: create user + database on MAIN, fail over, both
+    """The e2e: create user + database on MAIN, fail over, both
     exist on the new MAIN."""
     main, rep, main_ictx, rep_ictx, port = cluster
     main.execute(f"REGISTER REPLICA r1 SYNC TO '127.0.0.1:{port}'")

@@ -1,7 +1,7 @@
 """Vector-index O(delta) maintenance: parity vs full rebuild, concurrent
 snapshot readers, replica WAL apply, dominant-dimension flips.
 
-Solves/locks-in the four NOTES_ROUND2 holes; reference:
+Locks in the four holes procedures/vector_search.py lists; reference:
 src/storage/v2/indices/vector_index.cpp:22-73 (usearch update path).
 """
 

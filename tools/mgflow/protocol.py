@@ -176,7 +176,7 @@ def check_wires(project: Project,
                 symbol=wid,
                 message=f"wire {wid!r}: outcome {v!r} has no client "
                         "decoder — the client would see it as a "
-                        "generic failure, losing the typed taxonomy",
+                        "generic failure, losing the typed classification",
                 fingerprint=f"undecoded:{wid}:{v}"))
         for v, (rel, line) in sorted(decoded.items()):
             if v not in declared:

@@ -230,6 +230,6 @@ def check_retries(project: Project,
 
 def _class_names(entries: dict) -> set[str]:
     """Entries naming exception classes rather than operations: no dot,
-    CamelCase-looking (matches the taxonomy's naming)."""
+    CamelCase-looking (matches the classification's naming)."""
     return {n for n in entries
             if "." not in n and n[:1].isupper() and "_" not in n}

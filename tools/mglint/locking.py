@@ -53,7 +53,7 @@ _BLOCKING_DOTTED = {
     "socket.create_connection": "socket connect",
     # device-plane dispatches (r12): a device call made while holding a
     # storage/server lock is EXACTLY the wedge class the kernel-server
-    # supervision exists to contain — a hung tunnel or lost chip stalls
+    # supervision exists to contain — a hung runtime or lost chip stalls
     # every thread queued behind that lock
     "jax.device_put": "device dispatch (device_put)",
     "jax.block_until_ready": "device sync (block_until_ready)",

@@ -13,7 +13,7 @@ Device dispatches (r12) — `jax.device_put`, `.to_device()` /
 `put_edge_blocks` placements, compiled-call invocations entering
 through the `device_fault_point()` boundary, and kernel-server
 `_send_msg`/`_recv_msg` frames — are classified as blocking too: a
-hung device tunnel or a lost chip under a storage/server lock is
+hung device runtime or a lost chip under a storage/server lock is
 EXACTLY the wedge class the kernel-server supervision (deadline +
 health-check restart) exists to contain, and it must never hide behind
 a lock the rest of the system waits on.

@@ -207,7 +207,7 @@ def _mesh_pagerank(precision: str):
         _sds(ep, "int32"), _sds(ep, "int32"), _sds(ep, "float32"),
         _sds((), "int32"), _sds((), "float32"), _sds((), "float32"),
         _sds(vp, "float32"), _sds((N_SHARDS,), "float32"),
-        _sds((), "float32"), _sds((), "int32"), _sds((), "int32")))
+        _sds((N_SHARDS,), "float32"), _sds((), "int32"), _sds((), "int32")))
 
 
 @builder("mesh:pagerank")
