@@ -1,0 +1,109 @@
+"""The readers of per-layer metrics, one per source kind. A metric is
+``layer_metrics/<name>.json``: its kind and that kind's parameters. A
+reader that finds nothing to read returns None and the harness leaves
+the metric out of the line; it never returns 0 for a share.
+
+  trace_idle      100 * (1 - device busy / traced slice)
+  trace_ops       seconds of the rows of "table" ("ops": device ops,
+                  "modules": jitted programs) whose names match, per cycle
+  trace_roofline  least seconds for the traced iterations / their ops' seconds
+  stats_delta     a ratio of /stats counter deltas over the window
+  client_class    the median of the named classes' request times
+"""
+
+from __future__ import annotations
+
+import fnmatch
+import importlib.util
+import os
+import statistics
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def _matching(ops: dict, patterns: list) -> dict:
+    return {name: row for name, row in ops.items()
+            if any(fnmatch.fnmatchcase(name, p) for p in patterns)}
+
+
+def _device_trace(ctx):
+    trace = ctx.get("trace")
+    if not trace or trace.get("stand_in") or not trace.get("device_planes"):
+        return None
+    return trace
+
+
+def trace_idle(params, ctx):
+    trace = _device_trace(ctx)
+    if trace is None or not ctx.get("trace_window_s"):
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / ctx["trace_window_s"])
+
+
+def trace_ops(params, ctx):
+    trace = _device_trace(ctx)
+    if trace is None:
+        return None
+    rows = _matching(trace[params["table"]], params["patterns"])
+    cycles = ctx.get("traced_cycles")
+    if not rows or not cycles:
+        return None
+    return params.get("scale", 1.0) * sum(
+        r["seconds"] for r in rows.values()) / cycles
+
+
+def trace_roofline(params, ctx):
+    trace = _device_trace(ctx)
+    if trace is None:
+        return None
+    rows = _matching(trace[params["table"]], params["patterns"])
+    ticks = _matching(trace["ops"], params["once_per_iteration"])
+    seconds = sum(r["seconds"] for r in rows.values())
+    iterations = sum(r["count"] for r in ticks.values())
+    if not seconds or not iterations:
+        return None
+    path = os.path.join(HERE, "rooflines", params["roofline"] + ".py")
+    spec = importlib.util.spec_from_file_location(params["roofline"], path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    least = module.least_seconds(ctx["n_nodes"], ctx["n_edges"], iterations,
+                                 ctx["peak"])
+    return 100.0 * least["seconds"] / seconds
+
+
+def _counter_sum(stats: dict, names: list) -> float:
+    return sum(value for key, value in stats.items()
+               if any(fnmatch.fnmatchcase(key, n) for n in names))
+
+
+def stats_delta(params, ctx):
+    before, after = ctx.get("stats_before"), ctx.get("stats_after")
+    if before is None or after is None:
+        return None
+
+    def delta(names):
+        if isinstance(names, str):
+            return float(ctx.get(names) or 0)        # "cycles", "requests"
+        return _counter_sum(after, names) - _counter_sum(before, names)
+
+    bottom = delta(params["denominator"])
+    if not bottom:
+        return None
+    return params.get("scale", 1.0) * delta(params["numerator"]) / bottom
+
+
+def client_class(params, ctx):
+    times = [r.end - r.start for r in ctx["requests"]
+             if r.name in params["classes"] and r.ok]
+    if len(times) < 2:
+        return None
+    return 1000.0 * statistics.median(times)
+
+
+READERS = {"trace_idle": trace_idle, "trace_ops": trace_ops,
+           "trace_roofline": trace_roofline, "stats_delta": stats_delta,
+           "client_class": client_class}
+
+
+def read(metric: dict, ctx: dict):
+    return READERS[metric["kind"]](metric.get("params", {}), ctx)

@@ -1,0 +1,822 @@
+"""One run of one cell of BENCHMARK.json, on the chip, through Bolt.
+
+    python benchmarks/chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+This parent never imports jax. It reads the cell's files (deployment,
+traffic mix, per-layer metrics: all data, found by the names in
+BENCHMARK.json), makes the deployment's graph (its ``graph_seed``: one
+data set for every run) and the traffic (``--seed``), starts the one
+chip owner (``owner.py``: the program's Bolt server,
+untouched), refuses to go on unless that process reports a TPU with
+the cell's chip count, loads over Bolt, warms what the window will
+use, measures for ``--seconds``, stops the owner, and only then
+computes the plain reference (``reference.py``) and compares. The last
+line of stdout is the result; without a chip there is none and the
+exit code is not 0.
+
+``--trace 0`` reports the cell's end-to-end metrics with no profiler
+started. ``--trace 1`` has the owner trace a short slice at the start
+of the window and reports the per-layer metrics.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS_START = time.perf_counter()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import signal  # noqa: E402
+import socket  # noqa: E402
+import statistics  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+import threading  # noqa: E402
+import urllib.request  # noqa: E402
+
+import numpy as np  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
+for _p in (HERE, REPO):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import layers  # noqa: E402
+import reference  # noqa: E402
+import traffic as traffic_mod  # noqa: E402
+
+MASTER_TIMEOUT_S = 350          # the driver allows a run 360
+TRACE_STOP_TIMEOUT_S = 120
+
+
+class RunFailure(Exception):
+    """The run cannot give a result (no chip, a child that died, ...)."""
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+# --------------------------------------------------------------------------
+# the cell's files
+# --------------------------------------------------------------------------
+
+def _load_json(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def load_cell(workload: str) -> dict:
+    """Everything BENCHMARK.json and the data files say about one cell."""
+    bench = _load_json(REPO, "BENCHMARK.json")
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise RunFailure(f"BENCHMARK.json has no workload {workload!r}; "
+                         f"it has {sorted(cells)}")
+    cell = cells[workload]
+    config_entry = next(c for c in bench["configs"]
+                        if c["name"] == cell["config"])
+
+    def of_cell(metric):
+        return workload in metric.get("workloads", [workload])
+
+    layer_metrics = []
+    for metric in bench["per_layer"]:
+        if of_cell(metric):
+            spec = _load_json(HERE, "layer_metrics", metric["name"] + ".json")
+            layer_metrics.append(dict(spec, name=metric["name"],
+                                      unit=metric["unit"]))
+    limits_path = os.path.join(HERE, "cells", workload + ".json")
+    return {
+        "name": workload,
+        "chips": cell["chips"],
+        "config": _load_json(REPO, config_entry["file"]),
+        "mix": _load_json(HERE, "traffic", cell["traffic"] + ".json"),
+        "end_to_end": [m for m in bench["end_to_end"] if of_cell(m)],
+        "per_layer": layer_metrics,
+        "limits": _load_json(limits_path)["limits"]
+        if os.path.exists(limits_path) else {},
+    }
+
+
+# --------------------------------------------------------------------------
+# the chip owner (copied from chip_smoke.py: _spawn, _connect, _stop)
+# --------------------------------------------------------------------------
+
+_CHILDREN: list = []
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _tail(path: str, n: int = 3000) -> str:
+    try:
+        with open(path, "rb") as f:
+            f.seek(0, os.SEEK_END)
+            f.seek(max(0, f.tell() - n))
+            return f.read().decode(errors="replace")
+    except OSError:
+        return ""
+
+
+def spawn_owner(workdir: str, bolt: int, metrics: int, config: dict,
+                extra_env: dict | None = None):
+    """Start the one chip owner. Its environment is the one given (JAX
+    picks its default backend; the compile cache goes where
+    JAX_COMPILATION_CACHE_DIR says, else <checkout>/.jax_cache), minus
+    the switch that would route analytics to a daemon."""
+    env = dict(os.environ)
+    env.pop("MEMGRAPH_TPU_ANALYTICS_KERNEL_SERVER", None)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env.update(config["owner"].get("env", {}))
+    env.update(extra_env or {})
+    ctl = os.path.join(workdir, "ctl")
+    os.makedirs(ctl, exist_ok=True)
+    args = [sys.executable, os.path.join(HERE, "owner.py"), "--ctl", ctl,
+            "--", "--bolt-port", str(bolt), "--metrics-port", str(metrics),
+            "--data-directory", os.path.join(workdir, "data")] \
+        + list(config["owner"]["server_flags"])
+    with open(os.path.join(workdir, "owner.log"), "ab") as log:
+        p = subprocess.Popen(args, cwd=REPO, env=env, stdout=log,
+                             stderr=subprocess.STDOUT,
+                             start_new_session=True)
+    _CHILDREN.append(p)
+    return p
+
+
+def connect(port: int, child, timeout_s: float = 180.0):
+    from memgraph_tpu.server.client import BoltClient
+    deadline = time.monotonic() + timeout_s
+    while True:
+        try:
+            return BoltClient(port=port, timeout=900.0)
+        except OSError:
+            if child.poll() is not None or time.monotonic() > deadline:
+                raise RunFailure("the Bolt server did not come up")
+            time.sleep(0.1)
+
+
+def stop_child(p, grace_s: float = 60.0) -> int:
+    """SIGTERM, wait, then kill the group; returns the exit code."""
+    if p.poll() is None:
+        p.terminate()
+        try:
+            p.wait(grace_s)
+        except subprocess.TimeoutExpired:
+            pass
+    try:
+        os.killpg(p.pid, signal.SIGKILL)    # stragglers of the group
+    except (ProcessLookupError, PermissionError):
+        pass
+    rc = p.wait(30)
+    if p in _CHILDREN:
+        _CHILDREN.remove(p)
+    return rc
+
+
+def stop_all() -> None:
+    for p in list(_CHILDREN):
+        try:
+            stop_child(p, grace_s=5.0)
+        except (OSError, subprocess.TimeoutExpired):
+            pass
+
+
+class Owner:
+    """The parent's side of owner.py's request files."""
+
+    def __init__(self, workdir: str, child):
+        self.ctl = os.path.join(workdir, "ctl")
+        self.child = child
+        self.seq = 0
+
+    def ask(self, op: str, timeout_s: float = 60.0, **fields) -> dict:
+        self.seq += 1
+        tmp = os.path.join(self.ctl, ".req.tmp")
+        with open(tmp, "w") as f:
+            json.dump(dict(fields, seq=self.seq, op=op), f)
+        os.replace(tmp, os.path.join(self.ctl, "req.json"))
+        ack_path = os.path.join(self.ctl, f"ack_{self.seq}.json")
+        deadline = time.monotonic() + timeout_s
+        while not os.path.exists(ack_path):
+            if self.child.poll() is not None:
+                raise RunFailure(f"the owner exited before answering {op}")
+            if time.monotonic() > deadline:
+                raise RunFailure(f"the owner did not answer {op} "
+                                 f"within {timeout_s:.0f} s")
+            time.sleep(0.005)
+        with open(ack_path) as f:
+            ack = json.load(f)
+        if "error" in ack:
+            raise RunFailure(f"the owner could not {op}: {ack['error']}")
+        return ack
+
+
+def build_info(client) -> dict:
+    _, rows, _ = client.execute("SHOW BUILD INFO")
+    return {k: v for k, v in rows}
+
+
+def device_of(info: dict) -> dict:
+    return {"platform": info.get("device_platform"),
+            "kind": info.get("device_kind"),
+            "count": info.get("device_count")}
+
+
+def require_tpu(device: dict, chips: int) -> None:
+    """The one device assertion: the chip owner's own report."""
+    if device.get("platform") != "tpu" or device.get("count") != chips:
+        raise RunFailure(f"needs {chips} TPU chip(s); the chip owner "
+                         f"reports {device}")
+
+
+def flat_stats(metrics_port: int) -> dict:
+    """GET /stats, flattened to {"section/.../name": number}."""
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{metrics_port}/stats", timeout=60) as r:
+        stats = json.load(r)
+    flat: dict = {}
+
+    def walk(prefix, node):
+        if isinstance(node, dict):
+            for key, value in node.items():
+                walk(f"{prefix}/{key}" if prefix else key, value)
+        elif isinstance(node, (int, float)) and not isinstance(node, bool):
+            flat[prefix] = float(node)
+
+    for section in ("device", "delta", "lane", "ppr"):
+        walk(section, stats.get(section, {}))
+    return flat
+
+
+# --------------------------------------------------------------------------
+# set-up: load, warm
+# --------------------------------------------------------------------------
+
+def load_graph(client, config: dict, src, dst) -> float:
+    """Index, then UNWIND batches on one connection, as the smoke loads.
+    Returns the seconds it took."""
+    load, n_nodes = config["load"], config["nodes"]
+    batch = int(load["batch"])
+    t0 = time.perf_counter()
+    client.execute(config["index"])
+    for start in range(0, n_nodes, batch):
+        client.execute(load["nodes_query"],
+                       {"ids": list(range(start,
+                                          min(start + batch, n_nodes)))})
+    pairs = np.stack([src, dst], axis=1)
+    for start in range(0, len(pairs), batch):
+        client.execute(load["edges_query"],
+                       {"pairs": pairs[start:start + batch].tolist()})
+    return time.perf_counter() - t0
+
+
+def per_cycle_of(mix: dict) -> int:
+    """Requests in one cycle: a pass over a sequence, else one request."""
+    return len(mix["classes"]) if mix["schedule"] == "sequence" else 1
+
+
+def apply_acknowledged(state, requests) -> None:
+    for req in requests:
+        if req.cls["kind"] == "write" and req.ok:
+            state.apply(req.cls["reference"], req.params)
+
+
+def warm_up(mix: dict, plan, transport, state) -> list:
+    """Every shape the window will use, once, on the first connection.
+    The reference's state follows the writes."""
+    done = []
+    for step in mix["warmup"]["first"]:
+        for _ in range(int(step["times"])):
+            done.append(transport.run(plan.request(step["class"])))
+    for _ in range(int(mix["warmup"].get("then_cycles", 0))
+                   * per_cycle_of(mix)):
+        done.append(transport.run(next(plan)))
+    for req in done:
+        if not req.ok:
+            raise RunFailure(f"warm-up request {req.name} failed: "
+                             f"{req.error}")
+    apply_acknowledged(state, done)
+    return done
+
+
+# --------------------------------------------------------------------------
+# the window
+# --------------------------------------------------------------------------
+
+def run_window(mix: dict, plans, transports, seconds: float, owner,
+               trace_dir: str | None):
+    """Closed loops for `seconds`; with a trace directory, the owner's
+    profiler covers a slice at the start. Returns (t0, requests by
+    client, trace info)."""
+    per_cycle = per_cycle_of(mix)
+    outs = [[] for _ in plans]
+    trace = None
+    if trace_dir is not None:
+        started = owner.ask("trace_start", dir=trace_dir)
+        trace = {"started_ns": started["started_ns"]}
+    t0 = time.perf_counter()
+    deadline = t0 + seconds
+    threads = [threading.Thread(
+        target=traffic_mod.closed_loop,
+        args=(transport, plan, deadline, out, per_cycle), daemon=True)
+        for transport, plan, out in zip(transports, plans, outs)]
+    for t in threads:
+        t.start()
+    if trace is not None:
+        want = mix["trace_slice"]
+        if "cycles" in want:
+            need = int(want["cycles"]) * per_cycle
+            while len(outs[0]) < need and time.perf_counter() < deadline \
+                    and threads[0].is_alive():
+                time.sleep(0.005)
+            trace["cycles"] = min(len(outs[0]), need) // per_cycle
+        else:
+            time.sleep(min(float(want["seconds"]), seconds))
+        trace["requests_in_slice"] = sum(len(o) for o in outs)
+        stopped = owner.ask("trace_stop", timeout_s=TRACE_STOP_TIMEOUT_S)
+        trace["window_s"] = (stopped["stopped_ns"]
+                             - trace["started_ns"]) / 1e9
+    for t in threads:
+        t.join()
+    return t0, outs, trace
+
+
+# --------------------------------------------------------------------------
+# after the window: read back, then compare with the reference
+# --------------------------------------------------------------------------
+
+def added_pairs(state) -> list:
+    return sorted({(a, b) for a, b in state.added})
+
+
+def read_back(mix: dict, client, plan, final_state) -> dict:
+    """Quiesced, after the window: the rows the comparison will hold
+    against the reference's final state. Only collected here; the
+    reference runs once the owner is gone."""
+    got = {"readback": {}, "quiesced": []}
+    for item in mix.get("readback", []):
+        params = {}
+        if item.get("params") == "added_pairs":
+            params = {"pairs": [list(p) for p in added_pairs(final_state)]}
+        _, rows, _ = client.execute(item["query"], params)
+        got["readback"][item["name"]] = rows
+    per_class = int(mix.get("quiesced_reads", {}).get("per_class", 0))
+    for cls in mix["classes"]:
+        if cls["kind"] != "read" or cls["reference"] == "pagerank_top":
+            continue
+        for _ in range(per_class if cls["params"] else min(per_class, 1)):
+            req = plan.request(cls["name"])
+            _, req.rows, _ = client.execute(cls["query"], req.params)
+            got["quiesced"].append(req)
+    return got
+
+
+def reference_readback(name: str, state) -> list:
+    if name == "age_rows":
+        return state.age_rows()
+    if name == "out_degree_rows":
+        return state.out_degree_rows()
+    if name == "added_edge_rows":
+        pairs = added_pairs(state)
+        if not pairs:
+            return []
+        src, dst = state.edge_arrays()
+        big = int(max(src.max(), dst.max())) + 1
+        codes = src * big + dst
+        wanted = np.asarray([a * big + b for a, b in pairs], dtype=np.int64)
+        hit = codes[np.isin(codes, wanted)]
+        uniq, counts = np.unique(hit, return_counts=True)
+        return [[int(c // big), int(c % big), int(n)]
+                for c, n in zip(uniq, counts)]
+    raise ValueError(f"no read-back reference named {name!r}")
+
+
+def compare_ranks(rows, want: np.ndarray, top: int) -> dict:
+    """One CALL's rows against the reference's vector for that state:
+    `rel_err`, the widest |rank - reference| / reference over the
+    returned ids, and `gap`, the widest share by which a returned id's
+    reference rank lies below the reference's top-th best (0 where the
+    ids are the reference's own top, whatever their order among ties)."""
+    ids = [r[0] for r in rows]
+    got = np.asarray([r[1] for r in rows], dtype=np.float64)
+    fault = (len(rows) != top or len(set(ids)) != top
+             or not bool(np.isfinite(got).all())
+             or bool((np.diff(got) > 0).any())
+             or any(not isinstance(i, int) or not 0 <= i < len(want)
+                    for i in ids))
+    if fault:
+        return {"fault": 1, "rel_err": float("inf"), "gap": float("inf")}
+    ref = want[np.asarray(ids)]
+    cut = np.sort(want)[-top]
+    return {"fault": 0,
+            "rel_err": float((np.abs(got - ref) / ref).max()),
+            "gap": float(max(0.0, (cut - ref.min()) / cut))}
+
+
+def compare(mix: dict, state0, final, window: list,
+            collected: dict) -> dict:
+    """The numbers that decide `correct`, each to be held to its limit.
+
+    `window` is every request of the window, by client; `state0` and
+    `final` are the reference's state before and after it. With one client
+    the order is known and every read has one right answer; with more,
+    a read of the window is held between the window's first and last
+    state, and exactness is for the quiesced reads after it."""
+    numbers: dict = {}
+    exact = len(window) == 1
+
+    if exact and any(c["reference"] == "pagerank_top"
+                     for c in mix["classes"]):
+        # every CALL of the window, against the reference for the graph
+        # with every burst acknowledged before it, and against the one
+        # without the last of them: a CALL at least as near to that one
+        # has not seen its write
+        worst = {"fault": 0, "rel_err": 0.0, "gap": 0.0}
+        compared = stale = 0
+        stale_sep = float("inf")
+        state = state0.copy()
+        rank, _ = reference.pagerank(*state.edge_arrays(), state.n_loaded)
+        without = None
+        for req in window[0]:
+            if req.cls["kind"] == "write":
+                if req.ok:
+                    apply_acknowledged(state, [req])
+                    without = rank
+                continue
+            if not req.ok or req.cls["reference"] != "pagerank_top":
+                continue
+            if without is rank:         # a burst since the last CALL
+                rank, _ = reference.pagerank(*state.edge_arrays(),
+                                             state.n_loaded, x0=rank)
+            top = int(req.cls["top"])
+            one = compare_ranks(req.rows, rank, top)
+            worst = {k: max(worst[k], one[k]) if k != "fault"
+                     else worst[k] + one[k] for k in worst}
+            compared += 1
+            if without is not None and not one["fault"]:
+                old = compare_ranks(req.rows, without, top)
+                sep = max(old["rel_err"], old["gap"])
+                stale += sep <= max(one["rel_err"], one["gap"])
+                stale_sep = min(stale_sep, sep)
+            without = None
+        if compared:
+            # one number: right values for the ids returned, and the
+            # right ids; the parts are shown beside it
+            numbers["rank_dev_max"] = max(worst["rel_err"], worst["gap"])
+            numbers["_rank_rel_err_max"] = worst["rel_err"]
+            numbers["_top_gap_max"] = worst["gap"]
+            numbers["row_faults"] = worst["fault"]
+            numbers["stale_calls"] = stale
+            numbers["_stale_sep_min"] = stale_sep
+            numbers["_rank_calls_compared"] = compared
+
+    if any(c["kind"] == "read" and c["reference"] != "pagerank_top"
+           for c in mix["classes"]):
+        outside = 0
+        bounds: dict = {}
+        for out in window:
+            for req in out:
+                if req.cls["kind"] != "read" or not req.ok \
+                        or req.cls["reference"] == "pagerank_top":
+                    continue
+                key = (req.name, tuple(sorted(req.params.items())))
+                if key not in bounds:
+                    bounds[key] = reference.read_bounds(
+                        req.cls["reference"], req.params, state0, final)
+                outside += not reference.within(req.rows, *bounds[key])
+        numbers["reads_out_of_bounds"] = outside
+        numbers["quiesced_mismatches"] = sum(
+            req.rows != getattr(final, req.cls["reference"])(req.params)
+            for req in collected["quiesced"])
+
+    mismatches = 0
+    for item in mix.get("readback", []):
+        want = reference_readback(item["reference"], final)
+        got = [list(r) for r in collected["readback"][item["name"]]]
+        if got != want:
+            as_set = {tuple(r) for r in got}
+            mismatches += len(as_set ^ {tuple(r) for r in want}) or 1
+    numbers["readback_mismatches"] = mismatches
+    return numbers
+
+
+def judge(numbers: dict, mix: dict, limits: dict):
+    """[(name, value, limit, ok)], and whether all held. A number the
+    mix names with no limit in the mix or the cell's file fails: a
+    comparison without a limit decides nothing."""
+    rows = []
+    for name, spec in mix["compare"].items():
+        if name not in numbers:
+            continue
+        limit = limits.get(name, spec.get("limit"))
+        value = numbers[name]
+        ok = limit is not None and value <= limit
+        rows.append((name, value, limit, bool(ok)))
+    return rows, all(r[3] for r in rows) and bool(rows)
+
+
+# --------------------------------------------------------------------------
+# metrics
+# --------------------------------------------------------------------------
+
+def end_to_end(name: str, ctx: dict):
+    reqs, t0, seconds = ctx["requests"], ctx["t0"], ctx["seconds"]
+    if name == "setup_s":
+        return ctx["setup_s"]
+    if name == "ingest_records_per_s":
+        return ctx["records"] / ctx["load_s"]
+    if name == "fresh_cycle_s":
+        per_cycle = ctx["per_cycle"]
+        done = ctx["cycles"]
+        if not done:
+            return None
+        return (reqs[done * per_cycle - 1].end - t0) / done
+    if name == "oltp_queries_per_s":
+        return sum(r.ok and r.end <= t0 + seconds for r in reqs) / seconds
+    if name == "oltp_query_p95_ms":
+        # a failed request missed every limit: it sits beyond the tail
+        times = sorted((r.end - r.start) if r.ok else float("inf")
+                       for r in reqs)
+        if len(times) < 20:
+            return None
+        value = times[min(len(times) - 1, int(0.95 * len(times)))]
+        return 1000.0 * value if value != float("inf") else None
+    raise ValueError(f"no end-to-end metric named {name!r}")
+
+
+def completed_cycles(reqs: list, per_cycle: int) -> int:
+    """Whole cycles, every request of which succeeded, from the start."""
+    done = 0
+    while (done + 1) * per_cycle <= len(reqs) and all(
+            r.ok for r in reqs[done * per_cycle:(done + 1) * per_cycle]):
+        done += 1
+    return done
+
+
+def reduce_trace(trace_dir: str, workdir: str) -> dict | None:
+    """trace_reduce.py in a process of its own, held to the CPU, after
+    the chip owner has exited."""
+    out_path = os.path.join(workdir, "trace_summary.json")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(HERE, "trace_reduce.py"), trace_dir,
+         out_path], env=env, cwd=REPO, capture_output=True, text=True,
+        timeout=200)
+    if proc.returncode != 0:
+        say(f"trace_reduce failed: {proc.stderr[-2000:]}")
+        return None
+    return _load_json(out_path)
+
+
+def breakdown_of(trace: dict) -> dict:
+    ops = sorted(trace["ops"].items(), key=lambda kv: -kv[1]["seconds"])
+    gaps = []
+    for plane in trace["planes"].values():
+        gaps.extend(plane["idle_gaps"])
+    gaps.sort(key=lambda g: -g[1])
+    # the device's clock and the client's are not aligned in this PR:
+    # a gap is not attributed to the request in flight
+    return {"device_ops": [[name, row["seconds"]] for name, row in ops[:10]],
+            "idle_gaps": [["unattributed", dur] for _, dur in gaps[:10]]}
+
+
+# --------------------------------------------------------------------------
+# one run
+# --------------------------------------------------------------------------
+
+def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
+             workdir: str, control: str | None = None,
+             device_check=require_tpu, transport_hook=None, t_start: float = T_PROCESS_START) -> dict:
+    """Returns the result object. Raises RunFailure where there is none.
+
+    `device_check` and `transport_hook` are for the tests: the first
+    stands in for the device assertion on a host with no chip, the
+    second plants a fault under the timed path. `setup_s` runs from
+    `t_start`, which is the start of this process."""
+    from memgraph_tpu.server.client import BoltClientError
+    config, mix = cell["config"], cell["mix"]
+    n_nodes, n_edges = int(config["nodes"]), int(config["edges"])
+    say(f"cell {cell['name']}: {config['name']} ({n_nodes:,} / "
+        f"{n_edges:,}) under {mix['name']}, seed {seed}, "
+        f"{seconds:g} s, trace {int(trace)}"
+        + (f", CONTROL {control}" if control else ""))
+
+    extra_env, drop_every = {}, 0
+    if control:
+        controls = dict(config.get("precision", {}).get("controls", {}))
+        controls.update(mix.get("controls", {}))
+        if control not in controls:
+            raise RunFailure(f"no control {control!r}; the cell has "
+                             f"{sorted(controls)}")
+        spec = controls[control]
+        extra_env = spec.get("owner_env", {})
+        if spec.get("harness") == "drop_every_nth_write":
+            drop_every = int(spec["n"])
+
+    # the dataset is one graph, as Pokec is one file: every seed shares
+    # it (and so the shapes of the programs compiled for it), and draws
+    # its own traffic
+    src, dst = reference.make_graph(int(config["graph_seed"]), n_nodes,
+                                    n_edges)
+    state0 = reference.GraphState(n_nodes, src, dst)
+    keys = traffic_mod.Keys(mix["keys"], n_nodes, seed) \
+        if "keys" in mix else None
+    plans = [traffic_mod.Plan(mix, n_nodes, seed, i, keys)
+             for i in range(int(mix["clients"]))]
+
+    bolt, metrics_port = _free_port(), _free_port()
+    child = spawn_owner(workdir, bolt, metrics_port, config, extra_env)
+    owner = Owner(workdir, child)
+    clients = []
+    try:
+        clients.append(connect(bolt, child))
+        info = build_info(clients[0])
+        device = device_of(info)
+        say(f"SHOW BUILD INFO: {info}")
+        device_check(device, cell["chips"])
+
+        load_s = load_graph(clients[0], config, src, dst)
+        say(f"loaded over Bolt in {load_s:.3f} s: "
+            f"{(n_nodes + n_edges) / load_s:,.0f} records/s")
+        for _ in plans[1:]:
+            clients.append(connect(bolt, child))
+        transports = [traffic_mod.Transport(
+            c, int(mix.get("retries", 0)), BoltClientError, drop_every)
+            for c in clients]
+        if transport_hook is not None:
+            transports = [transport_hook(t) for t in transports]
+        t_warm = time.perf_counter()
+        warm = warm_up(mix, plans[0], transports[0], state0)
+        for t in transports[1:]:
+            t.client.execute("RETURN 1")
+        say(f"warmed {len(warm)} requests in "
+            f"{time.perf_counter() - t_warm:.3f} s: "
+            + ", ".join(f"{r.name} {r.end - r.start:.3f}" for r in warm[:12]))
+
+        stats_before = flat_stats(metrics_port)
+        trace_dir = os.path.join(workdir, "trace") if trace else None
+        setup_s = time.perf_counter() - t_start
+        t0, outs, trace_info = run_window(mix, plans, transports, seconds,
+                                          owner, trace_dir)
+        t_end = time.perf_counter()
+        stats_after = flat_stats(metrics_port)
+        memory = owner.ask("memory")
+        # quiesced: the window's clients have all returned
+        final_state = state0.copy()
+        for out in outs:
+            apply_acknowledged(final_state, out)
+        check_plan = traffic_mod.Plan(mix, n_nodes, seed, len(plans), keys)
+        collected = read_back(mix, clients[0], check_plan, final_state)
+        t_readback = time.perf_counter()
+    except RunFailure:
+        raise
+    except Exception as e:
+        raise RunFailure(
+            f"{type(e).__name__}: {e}\n--- owner log ---\n"
+            f"{_tail(os.path.join(workdir, 'owner.log'))}") from e
+    finally:
+        for c in clients:
+            try:
+                c.close()
+            except OSError:
+                pass
+        rc = stop_child(child)
+        say(f"owner exited with code {rc}")
+
+    # the window has closed, the peak is read, the program is gone:
+    # now the reference
+    per_cycle = per_cycle_of(mix)
+    # a loop begins a cycle only before the deadline, and finishes it
+    reqs = sorted((r for out in outs for r in out), key=lambda r: r.start)
+    cycles = completed_cycles(outs[0], per_cycle) \
+        if mix["schedule"] == "sequence" else sum(r.ok for r in reqs)
+    t_ref = time.perf_counter()
+    numbers = compare(mix, state0, final_state, outs, collected)
+    rows, correct = judge(numbers, mix, cell["limits"])
+    ref_s = time.perf_counter() - t_ref
+
+    trace_summary = None
+    if trace:
+        trace_summary = reduce_trace(trace_dir, workdir)
+
+    peaks = _load_json(HERE, "peaks.json")
+    if device["platform"] == "tpu" and device["kind"] not in peaks:
+        raise RunFailure(f"peaks.json has no device kind "
+                         f"{device['kind']!r}")
+    ctx = {
+        "requests": reqs, "t0": t0, "seconds": seconds, "setup_s": setup_s,
+        "load_s": load_s, "records": n_nodes + n_edges, "cycles": cycles,
+        "per_cycle": per_cycle, "n_nodes": n_nodes,
+        "n_edges": n_edges + len(final_state.added),
+        "stats_before": stats_before, "stats_after": stats_after,
+        "trace": trace_summary, "peak": peaks.get(device["kind"]),
+        "trace_window_s": (trace_info or {}).get("window_s"),
+        "traced_cycles": (trace_info or {}).get("cycles"),
+    }
+    by_class: dict = {}
+    for r in reqs:
+        by_class.setdefault(r.name, []).append(r.end - r.start)
+    say(f"window {seconds:g} s (+{t_end - t0 - seconds:.3f} s to finish "
+        f"what had begun): {len(reqs)} requests, {cycles} cycles, "
+        f"{sum(r.tries > 1 for r in reqs)} retried, "
+        f"{sum(not r.ok for r in reqs)} failed; read-back "
+        f"{t_readback - t_end:.3f} s; reference {ref_s:.3f} s")
+    for name, times in sorted(by_class.items()):
+        say(f"  {name:<18} n {len(times):>6}  p50 "
+            f"{1000 * statistics.median(times):9.3f} ms  max "
+            f"{1000 * max(times):9.3f} ms")
+    if mix["schedule"] == "sequence":
+        ends = [r.end for r in outs[0][per_cycle - 1::per_cycle]]
+        say("  cycle seconds: " + " ".join(
+            f"{b - a:.3f}" for a, b in zip([t0] + ends, ends)))
+    moved = {k: stats_after[k] - stats_before.get(k, 0.0)
+             for k in stats_after if stats_after[k] != stats_before.get(k, 0.0)}
+    say(f"  /stats counters that moved in the window: {moved}")
+
+    metrics_out: dict = {}
+    for metric in cell["per_layer"] if trace else cell["end_to_end"]:
+        value = layers.read(metric, ctx) if trace \
+            else end_to_end(metric["name"], ctx)
+        if value is not None:       # nothing to read: left out of the line
+            metrics_out[metric["name"]] = {"value": value,
+                                           "unit": metric["unit"]}
+
+    device_out = dict(device,
+                      memory_peak_bytes=memory["memory_peak_bytes"])
+    result = {"correct": correct, "attempted": len(reqs),
+              "failed": sum(not r.ok for r in reqs),
+              "metrics": metrics_out, "device": device_out}
+    if trace and trace_summary is not None:
+        device_out["busy_s"] = trace_summary["busy_s"]
+        device_out["window_s"] = ctx["trace_window_s"]
+        result["breakdown"] = breakdown_of(trace_summary)
+    result["cycles"] = cycles
+    result["control"] = control
+    result["compared"] = {
+        name: {"value": min(value, 1e300), "limit": limit, "ok": ok}
+        for name, value, limit, ok in rows}
+    for name, value in numbers.items():     # shown, not judged
+        if name.startswith("_"):
+            result["compared"][name[1:]] = {"value": min(value, 1e300)}
+    return result
+
+
+# --------------------------------------------------------------------------
+# entry
+# --------------------------------------------------------------------------
+
+def _arm_watchdog(timeout_s: int) -> None:
+    def on_alarm(signum, frame):
+        print(f"FAILED: no result within {timeout_s} s",
+              file=sys.stderr, flush=True)
+        stop_all()
+        os._exit(3)
+    signal.signal(signal.SIGALRM, on_alarm)
+    signal.alarm(timeout_s)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--control", default=None,
+                    help="run the cell's named control instead (never "
+                         "part of a measured run)")
+    args = ap.parse_args(argv)
+    _arm_watchdog(MASTER_TIMEOUT_S)
+    workdir = tempfile.mkdtemp(prefix="chipbench_")
+    try:
+        cell = load_cell(args.workload)
+        result = run_cell(cell, args.seed, args.seconds, bool(args.trace),
+                          workdir, control=args.control)
+    except RunFailure as e:
+        print(f"FAILED: {e}", file=sys.stderr, flush=True)
+        return 1
+    finally:
+        stop_all()
+        shutil.rmtree(workdir, ignore_errors=True)
+    say(json.dumps(result))                 # "compared" comes last
+    sys.stdout.flush()
+    for name, row in result["compared"].items():
+        print(f"compared {name}: {row.get('value')!r} limit "
+              f"{row.get('limit')!r}"
+              + ("" if row.get("ok", True) else "  <-- FAILS"),
+              file=sys.stderr)
+    print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
