@@ -100,11 +100,13 @@ async def start_monitoring_server(host: str, port: int, ictx):
                             in global_metrics.snapshot()
                             if name.startswith(
                                 ("ppr.", "kernel_server.daemon.ppr."))},
-                    # device compile plane: the runtime witness for the
-                    # mgxla static compile budget (jit.compile_total)
+                    # the chip owner's plane: the compile witness
+                    # (jit.*), in-process fixpoint iterations (device.*)
+                    # and every phase span's seconds and closes (span.*)
                     "device": {name: value for name, _k, value
                                in global_metrics.snapshot()
-                               if name.startswith("jit.")},
+                               if name.startswith(
+                                   ("jit.", "device.", "span."))},
                     # incremental analytics plane (r19, mgdelta):
                     # delta applies/compactions/fallbacks, warm-start
                     # counters, resident-generation gauge (local plus

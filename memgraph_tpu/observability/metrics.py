@@ -116,8 +116,16 @@ STAT_NAMES = (
     "ppr.queue_depth",             # coalescing queue backlog gauge
     "ppr.window_occupancy",        # last batch width / max width gauge
     # device compile plane (r17, mgxla): runtime witness for the static
-    # compile budget — every XLA backend compile bumps it
+    # compile budget — every XLA backend compile bumps it (executable
+    # loads served from the persistent cache too)
     "jit.compile_total",
+    "jit.backend_seconds_total",    # seconds of those compiles and loads
+    "jit.cache_miss_total",         # compiles the persistent cache missed
+    # phase spans (mgtrace PHASES): seconds and closes of every phase,
+    # armed or not — span.<name>.seconds_total / span.<name>.count
+    "span.*",
+    # in-process device fixpoints (ops/pagerank.py): iterations run
+    "device.fixpoint_iterations_total",
     # compiled Cypher read lane (r20, mglane)
     "lane.compiled_total",          # lane programs compiled (per shape)
     "lane.hit_total",               # queries served from a compiled lane
@@ -129,6 +137,8 @@ STAT_NAMES = (
     "delta.applied_total",          # EdgeDelta splices applied
     "delta.compacted_total",        # bounded-accumulation full rebuilds
     "delta.fallback_rebuild_total",  # wrapped log / failed splice colds
+    "delta.plan_applied_total",     # in-process CALL: MXU DeltaPlan refresh
+    "delta.plan_rebuild_total",     # in-process CALL: full MXU plan build
     "delta.edge_count",             # histogram: edges per applied delta
     "delta.warm_start_total",
     "delta.cold_start_total",       # LOUD monotone-unsafe cold starts
@@ -256,15 +266,24 @@ class Histogram:
 class Metrics:
     def __init__(self) -> None:
         self._lock = tracked_lock("Metrics._lock")
-        self._counters: dict[str, int] = defaultdict(int)
+        self._counters: dict[str, float] = defaultdict(int)
         self._gauges: dict[str, float] = {}
         self._histograms: dict[str, Histogram] = {}
         shared_field(self, "_counters", "_gauges", "_histograms")
 
-    def increment(self, name: str, delta: int = 1) -> None:
+    def increment(self, name: str, delta: float = 1) -> None:
         with self._lock:
             shared_write(self, "_counters")
             self._counters[name] += delta
+
+    def add_seconds(self, seconds_name: str, count_name: str,
+                    seconds: float) -> None:
+        """One closed phase span (observability/trace.py): its seconds
+        and 1, under one lock."""
+        with self._lock:
+            shared_write(self, "_counters")
+            self._counters[seconds_name] += seconds
+            self._counters[count_name] += 1
 
     def set_gauge(self, name: str, value: float) -> None:
         with self._lock:

@@ -19,13 +19,19 @@ USE-style saturation accounting, built on the mgtrace substrate (PR 8):
 * **Device-stage attribution** — a thread-local
   :class:`StageAccumulator` collects where device seconds went
   (``kernel_dispatch`` / ``device_transfer`` / ``device_compile`` /
-  ``device_iterate``). The analytics entry points and the checkpoint
-  runner record into whichever accumulator is active; a kernel-server
-  dispatch collects on its worker thread and ships the result home in
-  the reply header (``stages``), which the client merges into ITS
-  active accumulator — so ``PROFILE`` on a device-routed query shows
-  HBM-seconds regardless of which process ran the kernel. Disarmed
-  (no accumulator active) every hook is one thread-local read.
+  ``device_iterate``). It is fed by the mgtrace phase spans: a boundary
+  is instrumented once, with a span, and ``trace.PHASES`` maps the
+  span's name to the stage it feeds while an accumulator collects
+  (``device.chunk`` -> ``device_iterate`` + ``semiring_<backend>``).
+  :func:`record_stage` is called directly only where what it records
+  is not a span's extent: the kernel-server client's round trip, the
+  checkpoint runner's compile/iterate split, the streamed tier's
+  measured transfer. A kernel-server dispatch collects on its worker
+  thread and ships the result home in the reply header (``stages``),
+  which the client merges into ITS active accumulator — so ``PROFILE``
+  on a device-routed query shows HBM-seconds regardless of which
+  process ran the kernel. Disarmed (no accumulator active) every hook
+  is one thread-local read.
 
 * **Saturation plane** — :class:`SaturationPlane` folds the USE-style
   gauges every bounded resource already exports (bolt session pool,
@@ -68,8 +74,8 @@ ENV_MAX_PPR_QUEUE = "MEMGRAPH_TPU_HEALTH_MAX_PPR_QUEUE"  # pending (192)
 ENV_MAX_SHARD_QUEUE = "MEMGRAPH_TPU_HEALTH_MAX_SHARD_QUEUE"  # depth (16)
 ENV_MAX_STREAM_LAG = "MEMGRAPH_TPU_HEALTH_MAX_STREAM_LAG"  # units (100000)
 
-#: every device stage the accumulator may carry — the attribution
-#: vocabulary PROFILE and BENCH records share. The ``lane_*`` stages
+#: every device stage the accumulator may carry — what PROFILE prints
+#: (plus the ``semiring_<backend>`` family). The ``lane_*`` stages
 #: are the compiled read lane's split (r20 mglane): program build /
 #: host staging + upload / device execution, so PROFILE on a
 #: lane-served query shows where its milliseconds went.

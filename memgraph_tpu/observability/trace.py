@@ -9,13 +9,27 @@ replication/raft wire.
 
 Design rules:
 
+* **One boundary, one span, four sinks.** A span is the single
+  instrument at a layer boundary. A name marked as a *phase*
+  (:data:`PHASES`) does this when it closes, armed or not: (1) adds its
+  seconds and 1 to ``span.<name>.seconds_total`` / ``span.<name>.count``
+  in ``global_metrics`` (``GET /stats`` section ``device``, and
+  ``/metrics``); (2) while a ``jax.profiler`` session is live in this
+  process, sits in the xplane as ``mgtrace:<name>`` on the clock of the
+  device's ops (one attribute read otherwise); (3) under ``PROFILE`` /
+  an active ``StageAccumulator`` feeds the stages :data:`PHASES` maps
+  it to; (4) when armed, is recorded as below. No site keeps a
+  ``perf_counter`` pair of its own beside a span.
+
 * **Disarmed costs ~nothing.** Tracing is compiled in everywhere but
   armed only via ``MEMGRAPH_TPU_TRACE=1`` (or programmatically,
   ``enable()``). Every public entry point starts with one attribute
-  read; disarmed, ``span()`` returns a shared no-op context manager and
-  ``inject()``/``activate()``/``begin_trace()`` return ``None``/no-ops.
-  The overhead-guard test (tests/test_mgtrace.py) enforces the ≤2%
-  budget on a tier-1 micro-benchmark.
+  read; disarmed, ``span()`` of a non-phase name returns a shared no-op
+  context manager and ``inject()``/``activate()`` return ``None``/
+  no-ops. A phase costs two clock reads and one locked add; at most
+  three lie on a point read's path. The overhead-guard test
+  (tests/test_mgtrace.py) enforces the ≤2% budget on a tier-1
+  micro-benchmark over both kinds of site.
 
 * **Spans open only through this module's context-manager API** —
   ``span()`` for synchronous extents, ``record_span()`` for atomic
@@ -42,9 +56,7 @@ Design rules:
 
 Exports: ``traces_json()`` (the /traces endpoint), ``to_jsonl()``, and
 ``chrome_trace()`` — Chrome trace-event JSON loadable in Perfetto /
-chrome://tracing. ``MEMGRAPH_TPU_TRACE_XLA=1`` additionally bridges
-every span through ``jax.profiler.TraceAnnotation`` so spans appear
-inside XLA device profiles.
+chrome://tracing.
 """
 
 from __future__ import annotations
@@ -52,8 +64,12 @@ from __future__ import annotations
 import json
 import logging
 import os
+import sys
 import threading
 import time
+
+from . import stats as mgstats
+from .metrics import global_metrics
 
 log = logging.getLogger(__name__)
 
@@ -61,7 +77,6 @@ ENV_ARM = "MEMGRAPH_TPU_TRACE"
 ENV_SAMPLE = "MEMGRAPH_TPU_TRACE_SAMPLE"
 ENV_SLOW_MS = "MEMGRAPH_TPU_TRACE_SLOW_MS"
 ENV_RING = "MEMGRAPH_TPU_TRACE_RING"
-ENV_XLA = "MEMGRAPH_TPU_TRACE_XLA"
 
 #: Every span name product code may open. mglint MG005 (span-registry)
 #: statically enforces that (a) every literal name passed to span()/
@@ -69,18 +84,38 @@ ENV_XLA = "MEMGRAPH_TPU_TRACE_XLA"
 #: (b) every name here has at least one live open site — a dead
 #: registration means dashboards "cover" a span that can never fire.
 SPAN_NAMES = (
-    "bolt.run",            # one Bolt RUN..PULL* exchange (session root)
+    "bolt.run",            # RUN received -> last PULL answered (session root)
+    "bolt.wait",           # message decoded -> executor thread starts on it
     "query",               # interpreter root: prepare -> summary
     "query.parse",         # text -> AST (cache-aware)
     "query.plan",          # AST -> operator tree (cache-aware)
     "query.execute",       # stream drain: first pull -> exhaustion
     "query.commit",        # autocommit finalization (interpreter side)
+    "query.sort",          # ORDER BY's sort of the rows it collected
     "mvcc.begin",          # storage transaction begin
-    "mvcc.commit",         # storage engine commit (durability + repl)
+    "mvcc.commit",         # storage engine commit of a writing txn
+    #                        (durability: WAL append/fsync, + repl)
+    "mvcc.release",        # end of a read-only txn: nothing to make durable
+    "storage.gc",          # one collect_garbage tick
+    "storage.gc.sweep",    # its O(V+E) delta-chain sweep (not the thaw)
+    "analytics.export",    # MVCC -> CSR/COO device snapshot (GraphCache)
+    "analytics.edge_diff",   # multiset edge diff against the base snapshot
+    "analytics.plan_build",  # MXU plan: delta side-net or full build
+    "analytics.launch",    # kernel closure + jitted call until it returns
+    "analytics.device_wait",  # readback of rank/err/iters: ends in a block
+    "analytics.rows",      # summed time in the procedure's row generator
+    "analytics.consume",   # summed time of the operators above it, per row
+    "lane.query",          # one compiled-lane attempt, refusals included
+    "lane.snapshot",       # columnar snapshot fetch (rebuilt after a write)
+    "lane.stage",          # argsort/endpoints/masks + edge upload
+    "lane.compile",        # one lane program build
+    "lane.dispatch",       # padding + program lookup
+    "lane.iterate",        # program call + readback: ends in a block
     "kernel.request",      # client->kernel-server round trip
     "kernel.dispatch",     # server-side supervised dispatch
     "device.transfer",     # partition-centric blocking + device_put
     "device.chunk",        # one compiled chunk of device iterations
+    "device.route",        # one mesh/streamed dispatch (chunks inside)
     "mp.execute",          # parent->mp-worker round trip
     "mp.worker",           # worker-side prepare+pull
     "shard.request",       # router->shard-owner round trip (r18)
@@ -91,8 +126,48 @@ SPAN_NAMES = (
     "raft.handle",         # inbound raft RPC application
 )
 
-_SPAN_NAME_SET = frozenset(SPAN_NAMES)
+#: The phase mark. A name listed here is accounted on every close,
+#: armed or not (module docstring); every other name keeps the disarmed
+#: no-op. The value names the mgstat stages (``stats.STAGE_NAMES`` plus
+#: the ``semiring_<backend>`` family) the phase feeds while a
+#: ``StageAccumulator`` collects on its thread. ``{backend}`` is filled
+#: from the attribute the site opens the span with; a site that states
+#: none feeds no stage (its child does — the segment route's launch —
+#: or its runner records for itself — the checkpoint's chunks). A
+#: leading ``+`` adds seconds without a count: the phase continues an
+#: extent another phase began (launch + wait are ONE ``device_iterate``
+#: that ends in a block). mglint MG005 requires every key to be a
+#: declared span name.
+PHASES = {
+    "bolt.run": (),
+    "bolt.wait": (),
+    "mvcc.commit": (),
+    "query.sort": (),
+    "storage.gc": (),
+    "storage.gc.sweep": (),
+    "analytics.export": (),
+    "analytics.edge_diff": (),
+    "analytics.plan_build": (),
+    "analytics.launch": ("device_iterate", "semiring_{backend}"),
+    "analytics.device_wait": ("+device_iterate", "+semiring_{backend}"),
+    "analytics.rows": (),
+    "analytics.consume": (),
+    "lane.query": (),
+    "lane.snapshot": (),
+    "lane.stage": (),
+    "lane.compile": ("lane_compile",),
+    "lane.dispatch": ("lane_dispatch",),
+    "lane.iterate": ("lane_iterate",),
+    "device.transfer": ("device_transfer",),
+    "device.chunk": ("device_iterate", "semiring_{backend}"),
+    "device.route": ("semiring_{backend}",),
+}
 
+#: name -> (seconds counter, count counter, ((stage template, counted),))
+_PHASE_KEYS = {
+    name: (f"span.{name}.seconds_total", f"span.{name}.count",
+           tuple((t.lstrip("+"), not t.startswith("+")) for t in stages))
+    for name, stages in PHASES.items()}
 
 def _env_flag(name: str) -> bool:
     return os.environ.get(name, "") not in ("", "0")
@@ -154,6 +229,88 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+#: jax's profiler state, bound on first sight of the imported module: a
+#: process that never imported jax can have no session to sit in
+_profile_state = None
+
+
+def _profiler_live() -> bool:
+    """True while a ``jax.profiler`` session is live in this process."""
+    global _profile_state
+    state = _profile_state
+    if state is None:
+        mod = sys.modules.get("jax._src.profiler")
+        state = getattr(mod, "_profile_state", None)
+        if state is None:
+            return False
+        _profile_state = state
+    return state.profile_session is not None
+
+
+def _enter_annotation(name: str):
+    """``mgtrace:<name>`` in the live profiler session's host plane."""
+    try:
+        from jax.profiler import TraceAnnotation
+        ann = TraceAnnotation("mgtrace:" + name)
+        ann.__enter__()
+        return ann
+    except Exception as e:  # noqa: BLE001 — profiling never breaks serving
+        log.debug("profiler trace-annotation unavailable: %s", e)
+        return None
+
+
+def _exit_annotation(ann) -> None:
+    try:
+        ann.__exit__(None, None, None)
+    except Exception as e:  # noqa: BLE001 — profiling never breaks serving
+        log.debug("profiler trace-annotation exit failed: %s", e)
+
+
+def _account(keys: tuple, seconds: float, attrs) -> None:
+    """A closed phase's always-on sinks: the two counters, and the
+    stages it maps to where an accumulator collects on this thread."""
+    global_metrics.add_seconds(keys[0], keys[1], seconds)
+    stages = keys[2]
+    if stages and mgstats.stages_active():
+        try:
+            named = [(t.format_map(attrs), counted) for t, counted in stages]
+        except KeyError:        # the site states no backend: no stage
+            return
+        for stage, counted in named:
+            mgstats.record_stage(stage, seconds, 1 if counted else 0)
+
+
+class _PhaseSpan:
+    """A phase span while disarmed: accounted, in no trace. Falsy, like
+    the no-op, so ``if sp:`` still guards attr computation."""
+
+    __slots__ = ("_keys", "_name", "_attrs", "_t0", "_ann", "seconds")
+
+    def __init__(self, name: str, keys: tuple, attrs: dict) -> None:
+        self._name = name
+        self._keys = keys
+        self._attrs = attrs
+        self.seconds = 0.0      # the closed extent, for the site's use
+
+    def __bool__(self):
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def __enter__(self):
+        self._ann = _enter_annotation(self._name) \
+            if _profiler_live() else None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = seconds = time.perf_counter() - self._t0
+        if self._ann is not None:
+            _exit_annotation(self._ann)
+        _account(self._keys, seconds, self._attrs)
+        return False
+
 
 class _NullActivation:
     __slots__ = ()
@@ -184,7 +341,7 @@ class _LiveSpan:
 
     __slots__ = ("_tracer", "name", "trace_id", "span_id", "parent_id",
                  "_t0_wall", "_t0_perf", "attrs", "status", "error",
-                 "_prev_ctx", "_xla")
+                 "_prev_ctx", "_ann", "seconds")
 
     def __init__(self, tracer: "Tracer", name: str, ctx_parent, attrs):
         self._tracer = tracer
@@ -202,14 +359,15 @@ class _LiveSpan:
         self.status = "ok"
         self.error = None
         self._prev_ctx = None
-        self._xla = None
+        self._ann = None
+        self.seconds = 0.0
         self._t0_wall = time.time()
         self._t0_perf = time.perf_counter()
         # children opened inside this extent hang off this span
         self._prev_ctx = tracer._swap_current(
             TraceContext(self.trace_id, self.span_id, sampled))
-        if tracer.xla_bridge:
-            self._xla = tracer._enter_xla(name)
+        if _profiler_live():
+            self._ann = _enter_annotation(name)
 
     def __bool__(self):
         return True
@@ -222,12 +380,18 @@ class _LiveSpan:
 
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
-            self.status = "error"
+            # a typed decline (the lane's LaneRefused names its own
+            # ``span_status``) is control flow: recorded, not an error
+            # that force-keeps the trace
+            self.status = getattr(exc_type, "span_status", "error")
             self.error = f"{exc_type.__name__}: {exc}"
         t = self._tracer
-        if self._xla is not None:
-            t._exit_xla(self._xla)
-        dur = time.perf_counter() - self._t0_perf
+        self.seconds = dur = time.perf_counter() - self._t0_perf
+        if self._ann is not None:
+            _exit_annotation(self._ann)
+        keys = _PHASE_KEYS.get(self.name)
+        if keys is not None:
+            _account(keys, dur, self.attrs)
         t._swap_current(self._prev_ctx)
         t._record(self.trace_id, {
             "trace_id": self.trace_id, "span_id": self.span_id,
@@ -277,13 +441,16 @@ class _Adoption(_Activation):
 class TraceHandle:
     """The one sanctioned long-lived root span (a query's lifetime spans
     multiple protocol messages, so its root cannot be a ``with`` block).
-    Mint with begin_trace(); the owner calls finish() exactly once."""
+    Mint with begin_trace(); the owner calls finish() exactly once.
+    Disarmed, a phase name's handle has no ``ctx``: finish() accounts
+    its seconds and records nothing."""
 
     __slots__ = ("_tracer", "name", "ctx", "parent_id", "t0_wall",
                  "t0_perf", "_done", "_owns_finalize")
 
-    def __init__(self, tracer: "Tracer", name: str, ctx: TraceContext,
-                 parent_id: str | None, owns_finalize: bool) -> None:
+    def __init__(self, tracer: "Tracer", name: str,
+                 ctx: TraceContext | None, parent_id: str | None,
+                 owns_finalize: bool) -> None:
         self._tracer = tracer
         self.name = name
         self.ctx = ctx
@@ -300,8 +467,8 @@ class TraceHandle:
         self._owns_finalize = owns_finalize
 
     @property
-    def trace_id(self) -> str:
-        return self.ctx.trace_id
+    def trace_id(self) -> str | None:
+        return self.ctx.trace_id if self.ctx is not None else None
 
     def finish(self, status: str = "ok", error: str | None = None,
                force_keep: bool = False, **attrs) -> None:
@@ -309,6 +476,11 @@ class TraceHandle:
             return
         self._done = True
         dur = time.perf_counter() - self.t0_perf
+        keys = _PHASE_KEYS.get(self.name)
+        if keys is not None:
+            _account(keys, dur, attrs)
+        if self.ctx is None:
+            return
         t = self._tracer
         t._record(self.ctx.trace_id, {
             "trace_id": self.ctx.trace_id, "span_id": self.ctx.span_id,
@@ -338,7 +510,6 @@ class Tracer:
         self.sample_rate = _env_float(ENV_SAMPLE, 1.0)
         self.slow_ms = _env_float(ENV_SLOW_MS, 250.0)
         self.ring_cap = int(_env_float(ENV_RING, 256))
-        self.xla_bridge = _env_flag(ENV_XLA)
         self._tls = threading.local()
         self._lock = threading.Lock()
         #: trace_id -> {"spans": [dict], "error": bool}
@@ -446,26 +617,6 @@ class Tracer:
         with self._lock:
             return dict(self._counts)
 
-    # --- xla bridge ---------------------------------------------------------
-
-    def _enter_xla(self, name: str):
-        try:
-            from jax.profiler import TraceAnnotation
-            ann = TraceAnnotation(f"mgtrace:{name}")
-            ann.__enter__()
-            return ann
-        except Exception as e:  # noqa: BLE001 — profiling never breaks serving
-            log.debug("xla trace-annotation bridge unavailable: %s", e)
-            return None
-
-    def _exit_xla(self, ann) -> None:
-        if ann is None:
-            return
-        try:
-            ann.__exit__(None, None, None)
-        except Exception as e:  # noqa: BLE001 — profiling never breaks serving
-            log.debug("xla trace-annotation exit failed: %s", e)
-
 
 TRACER = Tracer()
 
@@ -490,14 +641,19 @@ def disable() -> None:
 def span(name: str, **attrs):
     """Open a child span of the current context (context manager).
 
-    Disarmed: returns the shared no-op (one attribute read + one call).
-    The span object is truthy only when armed, so hot paths can guard
-    attr computation with ``if sp:``.
+    Disarmed: a phase (:data:`PHASES`) is accounted and recorded in no
+    trace; any other name returns the shared no-op (one attribute read,
+    one dict miss). The span object is truthy only when armed, so hot
+    paths can guard attr computation with ``if sp:`` — an attribute a
+    phase's stage template needs (``backend``) is given here, at open.
     """
     t = TRACER
-    if not t._armed:
+    if t._armed:
+        return _LiveSpan(t, name, t.current(), attrs)
+    keys = _PHASE_KEYS.get(name)
+    if keys is None:
         return _NOOP
-    return _LiveSpan(t, name, t.current(), attrs)
+    return _PhaseSpan(name, keys, attrs)
 
 
 def record_span(name: str, start_wall: float, duration_s: float,
@@ -506,7 +662,11 @@ def record_span(name: str, start_wall: float, duration_s: float,
     """Atomically record a completed span under the current context —
     for extents whose start and end straddle protocol messages (e.g.
     query.execute across PULL batches). No begin/end imbalance is
-    possible: one call, one span."""
+    possible: one call, one span. A phase is accounted armed or not; an
+    after-the-fact record cannot sit in a profiler session."""
+    keys = _PHASE_KEYS.get(name)
+    if keys is not None:
+        _account(keys, duration_s, attrs)
     t = TRACER
     if not t._armed:
         return
@@ -522,12 +682,15 @@ def record_span(name: str, start_wall: float, duration_s: float,
 
 
 def begin_trace(name: str, carrier: dict | None = None):
-    """Mint the root of a locally-owned trace. Returns a TraceHandle (or
-    None when disarmed); the owner must call ``handle.finish()`` exactly
-    once. If a remote ``carrier`` (or an ambient local context) exists,
-    the new root joins that trace as a child."""
+    """Mint the root of a locally-owned trace. Returns a TraceHandle;
+    the owner must call ``handle.finish()`` exactly once. Disarmed it
+    returns None, or for a phase a handle with no ``ctx`` that only
+    accounts. If a remote ``carrier`` (or an ambient local context)
+    exists, the new root joins that trace as a child."""
     t = TRACER
     if not t._armed:
+        if name in _PHASE_KEYS:
+            return TraceHandle(t, name, None, None, owns_finalize=False)
         return None
     parent = None
     edge = False
@@ -640,11 +803,3 @@ def chrome_trace(traces=None) -> dict:
                 "pid": s.get("pid", 0), "tid": s.get("tid", 0),
                 "args": args})
     return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def write_jsonl(path: str) -> int:
-    """Dump every retained span to a JSONL file; returns span count."""
-    text = to_jsonl()
-    with open(path, "w", encoding="utf-8") as f:
-        f.write(text)
-    return sum(1 for line in text.splitlines() if line)

@@ -704,10 +704,7 @@ class ResidentGraph:
         GraphCache path's ``_shard_traced`` — PROFILE on a resident-
         served query still shows where transfer seconds went (cache
         hits show as ~zero-duration extents, itself useful signal)."""
-        import time as _time
-        from ..observability import stats as mgstats
         from ..observability import trace as mgtrace
-        t0 = _time.perf_counter()
         with mgtrace.span("device.transfer") as sp:
             hv = self.host_variants.get((by, doubled))
             if hv is None:
@@ -717,8 +714,6 @@ class ResidentGraph:
             if sp:
                 sp.set(n_shards=ctx.n_shards, by=by,
                        n_nodes=int(self._n_nodes), resident=True)
-        mgstats.record_stage("device_transfer",
-                             _time.perf_counter() - t0)
         return dev
 
     def ensure_tier(self, precision: str = "f32",

@@ -23,6 +23,8 @@ from functools import partial
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import trace as mgtrace
+from ..observability.metrics import global_metrics
 from . import semiring as S
 from .csr import DeviceGraph
 
@@ -123,7 +125,8 @@ def _try_delta_plan(graph: DeviceGraph):
     if base_state is None or base_state[0].wsum is None:
         return None
     base_plan = base_state[0]
-    diff = _edge_diff(base_g, graph, changed_gids)
+    with mgtrace.span("analytics.edge_diff"):
+        diff = _edge_diff(base_g, graph, changed_gids)
     if diff is None:
         return None
     (a_s, a_d, a_w), (r_s, r_d, r_w) = diff
@@ -132,9 +135,12 @@ def _try_delta_plan(graph: DeviceGraph):
         return base_state    # property-only bump: plan still exact
     if n_delta > max(DELTA_RECOMPACT_FRACTION * base_g.n_edges, 1024):
         return None          # recompact: full replan is the better deal
-    delta = spmv_mxu.build_delta_plan(base_plan, a_s, a_d, a_w,
-                                      r_s, r_d, r_w)
-    run = spmv_mxu.make_pagerank_kernel(base_plan, delta=delta)
+    with mgtrace.span("analytics.plan_build", kind="delta"):
+        delta = spmv_mxu.build_delta_plan(base_plan, a_s, a_d, a_w,
+                                          r_s, r_d, r_w)
+    global_metrics.increment("delta.plan_applied_total")
+    with mgtrace.span("analytics.launch"):      # closure + blob upload
+        run = spmv_mxu.make_pagerank_kernel(base_plan, delta=delta)
     return (base_plan, run)
 
 
@@ -164,8 +170,11 @@ def _pagerank_via_mxu(graph: DeviceGraph, damping, max_iterations, tol,
                 src = np.asarray(graph.src_idx)[:graph.n_edges]
                 dst = np.asarray(graph.col_idx)[:graph.n_edges]
                 w = np.asarray(graph.weights)[:graph.n_edges]
-                plan = spmv_mxu.build_plan(src, dst, w, graph.n_nodes)
-                cached = (plan, spmv_mxu.make_pagerank_kernel(plan))
+                with mgtrace.span("analytics.plan_build", kind="full"):
+                    plan = spmv_mxu.build_plan(src, dst, w, graph.n_nodes)
+                global_metrics.increment("delta.plan_rebuild_total")
+                with mgtrace.span("analytics.launch"):
+                    cached = (plan, spmv_mxu.make_pagerank_kernel(plan))
                 # DeviceGraph is frozen; bypass its setattr guard
                 object.__setattr__(graph, "_mxu_state", cached)
                 # full plans anchor future delta refreshes (GraphCache)
@@ -188,11 +197,18 @@ def _pagerank_via_mxu(graph: DeviceGraph, damping, max_iterations, tol,
         if np.isfinite(total) and total > 0.0:
             x0_flat = np.zeros(len(plan.valid_out), dtype=np.float32)
             x0_flat[plan.out_relabel] = x0 / np.float32(total)
-    with S.backend_extent("mxu", record_iterate=True):
+    # launch returns at enqueue (re-trace, lowering, executable load or
+    # compile included); the readback is the block. Together they are
+    # the device_iterate stage.
+    with mgtrace.span("analytics.launch", backend="mxu"):
         # None = uniform start computed on-device (saves a transfer)
         rank, err, iters = run(x0_flat, np.float32(damping),
                                int(max_iterations), np.float32(tol))
-    return np.asarray(rank)[plan.out_relabel], float(err), int(iters)
+    with mgtrace.span("analytics.device_wait", backend="mxu"):
+        rank, err, iters = (np.asarray(rank)[plan.out_relabel],
+                            float(err), int(iters))
+    global_metrics.increment("device.fixpoint_iterations_total", iters)
+    return rank, err, iters
 
 
 def pagerank(graph: DeviceGraph, damping: float = 0.85,
@@ -239,18 +255,24 @@ def pagerank(graph: DeviceGraph, damping: float = 0.85,
             buf = np.zeros(graph.n_pad, dtype=np.float32)
             buf[:len(x0)] = x0 / np.float32(total)
             x0_pad = jnp.asarray(buf)
-    rank, err, iters = S.fixpoint(
-        "plus_times",
-        arrays={"src": graph.csc_src, "dst": graph.csc_dst,
-                "w": graph.csc_weights,
-                "csr_src": graph.src_idx, "csr_w": graph.weights},
-        params={"n_nodes": np.int32(graph.n_nodes),
-                "damping": np.float32(damping),
-                "tol": np.float32(tol)},
-        n_out=graph.n_pad, setup=_pagerank_setup,
-        epilogue=_pagerank_epilogue, max_iterations=max_iterations,
-        sorted=True, precision=precision, x0=x0_pad)
-    return rank[:graph.n_nodes], float(err), int(iters)
+    # the fixpoint's own device.chunk (its child) feeds the stages here
+    with mgtrace.span("analytics.launch"):
+        rank, err, iters = S.fixpoint(
+            "plus_times",
+            arrays={"src": graph.csc_src, "dst": graph.csc_dst,
+                    "w": graph.csc_weights,
+                    "csr_src": graph.src_idx, "csr_w": graph.weights},
+            params={"n_nodes": np.int32(graph.n_nodes),
+                    "damping": np.float32(damping),
+                    "tol": np.float32(tol)},
+            n_out=graph.n_pad, setup=_pagerank_setup,
+            epilogue=_pagerank_epilogue, max_iterations=max_iterations,
+            sorted=True, precision=precision, x0=x0_pad)
+    with mgtrace.span("analytics.device_wait", backend="segment"):
+        rank, err, iters = (np.asarray(rank)[:graph.n_nodes],
+                            float(err), int(iters))
+    global_metrics.increment("device.fixpoint_iterations_total", iters)
+    return rank, err, iters
 
 
 def _ppr_setup(A, P, n_out):
@@ -502,7 +524,7 @@ def personalized_pagerank_batch(graph: DeviceGraph, source_sets,
               "w": graph.csc_weights,
               "csr_src": graph.src_idx, "csr_w": graph.weights,
               "personalization": jnp.asarray(pm)}
-    with S.backend_extent("segment", record_iterate=True):
+    with mgtrace.span("device.chunk", backend="segment"):
         x, err, iters = fn(arrays, {"n_nodes": np.int32(graph.n_nodes),
                                     "damping": np.float32(damping),
                                     "tol": np.float32(tol)},

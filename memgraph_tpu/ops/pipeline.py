@@ -48,10 +48,10 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 
 import numpy as np
 
+from ..observability import trace as mgtrace
 from ..utils.locks import tracked_lock
 from ..utils.sanitize import shared_field, shared_read, shared_write
 
@@ -75,6 +75,8 @@ _I32_MIN = np.int32(-(2**31) + 1)
 class LaneRefused(Exception):
     """Typed device-lane refusal; ``reason`` feeds
     ``lane.fallback_total.<reason>`` and the per-fingerprint registry."""
+
+    span_status = "refused"     # mgtrace: a decline, not a span error
 
     def __init__(self, reason: str, detail: str = "") -> None:
         super().__init__(detail or reason)
@@ -114,22 +116,19 @@ def _get_program(key, build, *build_args):
     fn = _PROGRAM_CACHE.get(key)
     if fn is not None:
         return fn
-    from ..observability import stats as mgstats
     from ..observability.metrics import global_metrics
     from ..utils.jax_cache import ensure_compile_cache
     ensure_compile_cache()
     with _program_lock:
         fn = _PROGRAM_CACHE.get(key)
         if fn is None:
-            t0 = time.perf_counter()
-            fn = build(*build_args)
+            with mgtrace.span("lane.compile") as sp:
+                fn = build(*build_args)
             _PROGRAM_CACHE[key] = fn
-            dt = time.perf_counter() - t0
             global_metrics.increment("lane.compiled_total")
-            global_metrics.observe("lane.compile_latency_sec", dt)
+            global_metrics.observe("lane.compile_latency_sec", sp.seconds)
             global_metrics.set_gauge("lane.resident",
                                      float(len(_PROGRAM_CACHE)))
-            mgstats.record_stage("lane_compile", dt)
     return fn
 
 
@@ -293,27 +292,26 @@ def masked_aggregate(preds: tuple, aggs: tuple, vals: np.ndarray,
     order; raises :class:`LaneRefused` when the exactness witness
     cannot prove the int32 accumulation safe.
     """
-    from ..observability import stats as mgstats
-    n = vals.shape[1] if vals.size else len(base)
-    nb = _bucket(max(n, 1))
-    key = ("agg", preds, aggs, vals.shape[0], nb)
-    was = key in _PROGRAM_CACHE
-    fn = _get_program(key, _build_agg_program, preds, aggs)
-    if not was:
-        LANE_REGISTRY.note_compiled(fingerprint)
-    t0 = time.perf_counter()
-    if n != nb:
-        vals = np.concatenate(
-            [vals, np.zeros((vals.shape[0], nb - n), np.int32)], axis=1)
-        present = np.concatenate(
-            [present, np.zeros((present.shape[0], nb - n), bool)], axis=1)
-        base = _pad(base, nb, False)
-    rhs_arr = np.asarray(rhs, dtype=np.int32) if rhs else \
-        np.zeros(0, dtype=np.int32)
-    mgstats.record_stage("lane_dispatch", time.perf_counter() - t0)
-    t0 = time.perf_counter()
-    raw = [np.asarray(x) for x in fn(vals, present, base, rhs_arr)]
-    mgstats.record_stage("lane_iterate", time.perf_counter() - t0)
+    with mgtrace.span("lane.dispatch"):     # lookup (or build) + padding
+        n = vals.shape[1] if vals.size else len(base)
+        nb = _bucket(max(n, 1))
+        key = ("agg", preds, aggs, vals.shape[0], nb)
+        was = key in _PROGRAM_CACHE
+        fn = _get_program(key, _build_agg_program, preds, aggs)
+        if not was:
+            LANE_REGISTRY.note_compiled(fingerprint)
+        if n != nb:
+            vals = np.concatenate(
+                [vals, np.zeros((vals.shape[0], nb - n), np.int32)],
+                axis=1)
+            present = np.concatenate(
+                [present, np.zeros((present.shape[0], nb - n), bool)],
+                axis=1)
+            base = _pad(base, nb, False)
+        rhs_arr = np.asarray(rhs, dtype=np.int32) if rhs else \
+            np.zeros(0, dtype=np.int32)
+    with mgtrace.span("lane.iterate"):      # call + readback: a block
+        raw = [np.asarray(x) for x in fn(vals, present, base, rhs_arr)]
 
     out = []
     i = 0
@@ -410,29 +408,26 @@ def hop_counts(src, dst, emask, smask: np.ndarray,
     raw host arrays. Returns {"rows": int, "distinct": int} (keys per
     request); raises :class:`LaneRefused` when the f32 multiplicity
     witness trips."""
-    from ..observability import stats as mgstats
-    t0 = time.perf_counter()
-    n = int(n_nodes)
-    nb = _bucket(max(n, 1))
-    if isinstance(src, np.ndarray):
-        src, dst, emask, eb = stage_edges(src, dst, emask)
-    else:
-        eb = len(src)
-    smask = _pad(np.asarray(smask, bool), nb, False)
-    midmask = _pad(np.asarray(midmask, np.float32), nb, 0.0)
-    tmask = _pad(np.asarray(tmask, np.float32), nb, 0.0)
-    key = ("hops", hops, include_lower, edge_unique, need_rows,
-           need_distinct, eb, nb)
-    was = key in _PROGRAM_CACHE
-    fn = _get_program(key, _build_hops_program, hops, include_lower,
-                      edge_unique, need_rows, need_distinct, nb)
-    if not was:
-        LANE_REGISTRY.note_compiled(fingerprint)
-    mgstats.record_stage("lane_dispatch", time.perf_counter() - t0)
-    t0 = time.perf_counter()
-    raw = [np.asarray(x) for x in
-           fn(src, dst, emask, smask, midmask, tmask)]
-    mgstats.record_stage("lane_iterate", time.perf_counter() - t0)
+    with mgtrace.span("lane.dispatch"):     # padding + lookup (or build)
+        n = int(n_nodes)
+        nb = _bucket(max(n, 1))
+        if isinstance(src, np.ndarray):
+            src, dst, emask, eb = stage_edges(src, dst, emask)
+        else:
+            eb = len(src)
+        smask = _pad(np.asarray(smask, bool), nb, False)
+        midmask = _pad(np.asarray(midmask, np.float32), nb, 0.0)
+        tmask = _pad(np.asarray(tmask, np.float32), nb, 0.0)
+        key = ("hops", hops, include_lower, edge_unique, need_rows,
+               need_distinct, eb, nb)
+        was = key in _PROGRAM_CACHE
+        fn = _get_program(key, _build_hops_program, hops, include_lower,
+                          edge_unique, need_rows, need_distinct, nb)
+        if not was:
+            LANE_REGISTRY.note_compiled(fingerprint)
+    with mgtrace.span("lane.iterate"):      # call + readback: a block
+        raw = [np.asarray(x) for x in
+               fn(src, dst, emask, smask, midmask, tmask)]
     max1, max2, total_f = float(raw[0]), float(raw[1]), float(raw[2])
     if max1 >= _F24 or max2 >= _F24:
         raise LaneRefused("precision_overflow",
@@ -489,30 +484,29 @@ def masked_topk(preds: tuple, ascending: bool, vals: np.ndarray,
                 rhs: list, fingerprint: str | None = None):
     """Returns (order, n_included): row indices in final ORDER BY order
     (callers take the first min(k, n_included))."""
-    from ..observability import stats as mgstats
-    n = len(keyv)
-    nb = _bucket(max(n, 1))
-    key = ("topk", preds, ascending, vals.shape[0], nb)
-    was = key in _PROGRAM_CACHE
-    fn = _get_program(key, _build_topk_program, preds, ascending)
-    if not was:
-        LANE_REGISTRY.note_compiled(fingerprint)
-    t0 = time.perf_counter()
-    if n != nb:
-        vals = np.concatenate(
-            [vals, np.zeros((vals.shape[0], nb - n), np.int32)], axis=1)
-        present = np.concatenate(
-            [present, np.zeros((present.shape[0], nb - n), bool)], axis=1)
-        keyv = _pad(keyv, nb, np.int32(0))
-        keyp = _pad(keyp, nb, False)
-    rhs_arr = np.asarray(rhs, dtype=np.int32) if rhs else \
-        np.zeros(0, dtype=np.int32)
-    mgstats.record_stage("lane_dispatch", time.perf_counter() - t0)
-    t0 = time.perf_counter()
-    order, count = fn(vals, present, keyv, keyp, rhs_arr)
-    order = np.asarray(order)
-    count = int(count)
-    mgstats.record_stage("lane_iterate", time.perf_counter() - t0)
+    with mgtrace.span("lane.dispatch"):     # lookup (or build) + padding
+        n = len(keyv)
+        nb = _bucket(max(n, 1))
+        key = ("topk", preds, ascending, vals.shape[0], nb)
+        was = key in _PROGRAM_CACHE
+        fn = _get_program(key, _build_topk_program, preds, ascending)
+        if not was:
+            LANE_REGISTRY.note_compiled(fingerprint)
+        if n != nb:
+            vals = np.concatenate(
+                [vals, np.zeros((vals.shape[0], nb - n), np.int32)],
+                axis=1)
+            present = np.concatenate(
+                [present, np.zeros((present.shape[0], nb - n), bool)],
+                axis=1)
+            keyv = _pad(keyv, nb, np.int32(0))
+            keyp = _pad(keyp, nb, False)
+        rhs_arr = np.asarray(rhs, dtype=np.int32) if rhs else \
+            np.zeros(0, dtype=np.int32)
+    with mgtrace.span("lane.iterate"):      # call + readback: a block
+        order, count = fn(vals, present, keyv, keyp, rhs_arr)
+        order = np.asarray(order)
+        count = int(count)
     return order, count
 
 

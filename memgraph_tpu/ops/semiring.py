@@ -56,8 +56,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
-from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 
@@ -386,7 +384,6 @@ def fixpoint(sr, *, arrays, params=None, x0=None, n_out: int, epilogue,
     (algorithm hooks, shapes) — repeated calls pay tracing once.
     """
     from ..utils.jax_cache import ensure_compile_cache
-    from ..observability import stats as mgstats
     from ..observability import trace as mgtrace
     ensure_compile_cache()
     sr = resolve_semiring(sr)
@@ -407,15 +404,11 @@ def fixpoint(sr, *, arrays, params=None, x0=None, n_out: int, epilogue,
                     metric=metric, precision=precision, sorted=sorted,
                     sorted_backward=sorted_backward, direction=direction)
                 _FIXPOINT_CACHE[key] = fn
-    t0 = time.perf_counter()
-    with mgtrace.span("device.chunk") as sp:
+    # the jitted call returns at enqueue: the caller's readback blocks
+    with mgtrace.span("device.chunk", backend="segment") as sp:
         out = fn(arrays, params, x0)
         if sp:
-            sp.set(semiring=sr.name, precision=precision,
-                   backend="segment")
-    dt = time.perf_counter() - t0
-    mgstats.record_stage("device_iterate", dt)
-    mgstats.record_stage("semiring_segment", dt)
+            sp.set(semiring=sr.name, precision=precision)
     return out
 
 
@@ -474,22 +467,15 @@ def route_backend(graph, mesh=None, *, semiring="plus_times",
     return "segment", None
 
 
-@contextmanager
-def backend_extent(backend: str, record_iterate: bool = False):
-    """Attribute a backend dispatch to the active mgstat stage
-    accumulator (PROFILE of a core-routed query shows time per backend:
-    ``semiring_mesh`` / ``semiring_mxu`` / ``semiring_segment``).  The
-    segment fixpoint records its own extent; mesh/MXU call sites wrap
-    their dispatch with this."""
-    from ..observability import stats as mgstats
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        mgstats.record_stage(f"semiring_{backend}", dt)
-        if record_iterate:
-            mgstats.record_stage("device_iterate", dt)
+def backend_extent(backend: str):
+    """One mesh / streamed backend dispatch as a ``device.route`` span:
+    PROFILE of a core-routed query shows time per backend
+    (``semiring_mesh`` / ``semiring_streamed``; the chunks inside are
+    the checkpoint runner's, which records compile and iterate itself).
+    The segment and MXU dispatches are ``device.chunk`` spans at their
+    own sites."""
+    from ..observability import trace as mgtrace
+    return mgtrace.span("device.route", backend=backend)
 
 
 # --------------------------------------------------------------------------
@@ -551,7 +537,9 @@ def mxu_fixpoint(graph, *, epilogue, params, max_iterations, tol,
         x0_flat = np.zeros(len(plan.valid_out), dtype=np.float32)
         x0_flat[plan.out_relabel] = \
             np.asarray(x0, dtype=np.float32)[:graph.n_nodes]
-    with backend_extent("mxu", record_iterate=True):
+    from ..observability import trace as mgtrace
+    # the readback is inside: the chunk ends in a block
+    with mgtrace.span("device.chunk", backend="mxu"):
         x, err, iters = run(x0_flat, params, int(max_iterations),
                             np.float32(tol))
-    return np.asarray(x)[plan.out_relabel], float(err), int(iters)
+        return np.asarray(x)[plan.out_relabel], float(err), int(iters)

@@ -46,15 +46,11 @@ def _shard_traced(graph: DeviceGraph, ctx: MeshContext, by: str = "src",
     ~zero-duration spans, which is itself useful signal). The same
     extent attributes to the active mgstat stage accumulator, so a
     PROFILE-d query sees transfer seconds even with tracing disarmed."""
-    import time as _time
-    from ..observability import stats as mgstats
-    t0 = _time.perf_counter()
     with mgtrace.span("device.transfer") as sp:
         scsr = shard_csr(graph, ctx, by=by, doubled=doubled)
         if sp:
             sp.set(n_shards=ctx.n_shards, by=by,
                    n_nodes=int(graph.n_nodes))
-    mgstats.record_stage("device_transfer", _time.perf_counter() - t0)
     return scsr
 
 

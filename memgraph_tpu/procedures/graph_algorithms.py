@@ -20,9 +20,11 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 
 import numpy as np
 
+from ..observability import trace as mgtrace
 from . import mgp
 
 log = logging.getLogger(__name__)
@@ -38,10 +40,27 @@ _PPR_PUSHED_LOCK = threading.Lock()
 
 
 def _rank_results(ctx, graph, values, field_name):
-    for i in range(graph.n_nodes):
-        node = ctx.vertex_by_index(graph, i)
-        if node is not None:
-            yield {"node": node, field_name: float(values[i])}
+    """One row per vertex. Two phases, recorded once when the generator
+    ends: ``analytics.rows``, the time spent in here making the rows,
+    and ``analytics.consume``, the time the plan's operators above the
+    CALL took between two rows (Produce's expressions, OrderBy)."""
+    started = time.time()
+    inside = outside = 0.0
+    t0 = time.perf_counter()
+    try:
+        for i in range(graph.n_nodes):
+            node = ctx.vertex_by_index(graph, i)
+            if node is not None:
+                row = {"node": node, field_name: float(values[i])}
+                t1 = time.perf_counter()
+                inside += t1 - t0
+                yield row
+                t0 = time.perf_counter()
+                outside += t0 - t1
+        inside += time.perf_counter() - t0
+    finally:
+        mgtrace.record_span("analytics.rows", started, inside)
+        mgtrace.record_span("analytics.consume", started, outside)
 
 
 def _kernel_route_socket(ctx) -> str | None:

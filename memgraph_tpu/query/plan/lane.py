@@ -49,6 +49,7 @@ from typing import Optional
 
 import numpy as np
 
+from ...observability import trace as mgtrace
 from ...ops.columnar import COLUMNAR_CACHE
 from ..frontend import ast as A
 from . import operators as Op
@@ -83,6 +84,26 @@ def _note_fallback(fingerprint, reason: str, detail: str = "") -> None:
     """LOUD, typed: counted per fingerprint + debug-logged."""
     _registry().note_fallback(fingerprint, reason)
     log.debug("lane fallback (%s) fp=%s %s", reason, fingerprint, detail)
+
+
+def _attempt(op, ctx) -> dict:
+    """The device attempt of a lane operator. What ``_admit`` refuses
+    costs nothing and is no phase (a point read's hop pattern is
+    refused there on every request); past it the attempt is one
+    ``lane.query`` phase, later refusals included (``small_input`` is
+    known only once the snapshot is fetched). Its children
+    ``lane.snapshot`` / ``lane.stage`` / ``lane.dispatch`` /
+    ``lane.iterate`` are disjoint, so their seconds add up to it."""
+    from ...ops import pipeline as pl
+    op._admit(ctx)
+    with mgtrace.span("lane.query") as sp:
+        try:
+            return op._device_row(ctx)
+        except (pl.LaneRefused, _Unsupported) as e:
+            if sp:
+                sp.set(refused=getattr(e, "reason",
+                                       "columnar_unsupported"))
+            raise
 
 
 # --------------------------------------------------------------------------
@@ -170,7 +191,7 @@ class _LaneAggMixin:
         row = None
         ok = False
         try:
-            row = self._device_row(ctx)
+            row = _attempt(self, ctx)
             ok = True
         except pl.LaneRefused as e:
             _note_fallback(self.fingerprint, e.reason, str(e))
@@ -182,7 +203,7 @@ class _LaneAggMixin:
             return
         yield from super().cursor(ctx)
 
-    def _device_row(self, ctx) -> dict:
+    def _admit(self, ctx) -> None:
         from ...ops import pipeline as pl
         if self.group_by:
             raise pl.LaneRefused("group_by")
@@ -191,12 +212,30 @@ class _LaneAggMixin:
                 raise pl.LaneRefused(f"agg_{kind}")
         if not COLUMNAR_CACHE._cacheable(ctx.accessor):
             raise pl.LaneRefused("mvcc_private")
-        snap, base = self._snapshot_base(ctx)
+
+    def _device_row(self, ctx) -> dict:
+        from ...ops import pipeline as pl
+        with mgtrace.span("lane.snapshot"):
+            snap, base = self._snapshot_base(ctx)
         if snap.n < _lane_min_rows() and not self.hinted:
             raise pl.LaneRefused("small_input")
+        with mgtrace.span("lane.stage"):
+            preds, aggs, vals, present, base, rhs_values = \
+                self._stage(ctx, snap, base)
+        out = pl.masked_aggregate(preds, aggs, vals, present, base,
+                                  rhs_values,
+                                  fingerprint=self.fingerprint)
+        row = {}
+        for (kind, _prop, name), value in zip(self.aggregations, out):
+            if kind == "sum" and value is None:
+                value = 0
+            row[name] = value
+        return row
 
-        # admission first (host semantics decide the typed reason),
-        # then one fused device program over the stacked columns
+    def _stage(self, ctx, snap, base):
+        """Admission (host semantics decide the typed reason), then the
+        stacked columns of one fused device program."""
+        from ...ops import pipeline as pl
         rhs_values = []
         for prop, op, rhs_expr in self.predicates:
             rhs = ctx.evaluator.eval(rhs_expr, {})
@@ -237,15 +276,7 @@ class _LaneAggMixin:
                      for kind, prop, _ in self.aggregations)
         if base is None:
             base = np.ones(snap.n, dtype=bool)
-        out = pl.masked_aggregate(preds, aggs, vals, present, base,
-                                  rhs_values,
-                                  fingerprint=self.fingerprint)
-        row = {}
-        for (kind, _prop, name), value in zip(self.aggregations, out):
-            if kind == "sum" and value is None:
-                value = 0
-            row[name] = value
-        return row
+        return preds, aggs, vals, present, base, rhs_values
 
 
 @dataclass
@@ -296,7 +327,7 @@ class LaneHopCount(Op.LogicalOperator):
         row = None
         ok = False
         try:
-            row = self._device_row(ctx)
+            row = _attempt(self, ctx)
             ok = True
         except pl.LaneRefused as e:
             _note_fallback(self.fingerprint, e.reason, str(e))
@@ -319,36 +350,23 @@ class LaneHopCount(Op.LogicalOperator):
             return (np.ones(n, dtype=np.float32) if as_float
                     else np.ones(n, dtype=bool))
         props = tuple(sorted({p for p, _, _ in preds}))
-        snap = COLUMNAR_CACHE.get(ctx.accessor, label, props, ctx.view,
-                                  abort_check=ctx.check_abort)
-        mask = np.ones(snap.n, dtype=bool)
-        for prop, op, rhs_expr in preds:
-            mask &= _pred_mask(ctx, snap, prop, op, rhs_expr)
-        rows = _gid_rows(f_sorted, f_order, snap.gids)
-        sel = mask & (rows >= 0)
-        out = np.zeros(n, dtype=np.float32 if as_float else bool)
-        out[rows[sel]] = 1.0 if as_float else True
-        return out
+        with mgtrace.span("lane.snapshot"):
+            snap = COLUMNAR_CACHE.get(ctx.accessor, label, props, ctx.view,
+                                      abort_check=ctx.check_abort)
+        with mgtrace.span("lane.stage"):
+            mask = np.ones(snap.n, dtype=bool)
+            for prop, op, rhs_expr in preds:
+                mask &= _pred_mask(ctx, snap, prop, op, rhs_expr)
+            rows = _gid_rows(f_sorted, f_order, snap.gids)
+            sel = mask & (rows >= 0)
+            out = np.zeros(n, dtype=np.float32 if as_float else bool)
+            out[rows[sel]] = 1.0 if as_float else True
+            return out
 
-    def _device_row(self, ctx) -> dict:
-        from ...ops import pipeline as pl
-        if not COLUMNAR_CACHE._cacheable(ctx.accessor):
-            raise pl.LaneRefused("mvcc_private")
-        if self.source[0] == "label_prop_eq" and not self.hinted:
-            # a point source expands O(degree^2) rows; the device sweep
-            # is O(E) — the row path IS the fast path here
-            raise pl.LaneRefused("small_frontier")
-        acc = ctx.accessor
-        edges = COLUMNAR_CACHE.get_edges(acc, (), ctx.view,
-                                         abort_check=ctx.check_abort)
-        ctx.check_abort()
-        if edges.n < _lane_min_rows() and not self.hinted:
-            raise pl.LaneRefused("small_input")
-        full = COLUMNAR_CACHE.get(acc, None, (), ctx.view,
-                                  abort_check=ctx.check_abort)
-        ctx.check_abort()
-
-        # per-version staging, cached on the snapshots themselves
+    def _endpoints(self, ctx, full, edges):
+        """Per-version host staging, cached on the snapshots themselves:
+        the gid order of the vertices, the edges' endpoint rows, the
+        edge-type mask."""
         f_order = getattr(full, "_lane_order", None)
         if f_order is None:
             f_order = np.argsort(full.gids, kind="stable")
@@ -379,6 +397,32 @@ class LaneHopCount(Op.LogicalOperator):
                                   np.asarray(ids, dtype=np.int32))
                 cache[tkey] = tmask_e
             emask = emask & tmask_e
+        return f_sorted, f_order, s_idx, d_idx, emask, tkey
+
+    def _admit(self, ctx) -> None:
+        from ...ops import pipeline as pl
+        if not COLUMNAR_CACHE._cacheable(ctx.accessor):
+            raise pl.LaneRefused("mvcc_private")
+        if self.source[0] == "label_prop_eq" and not self.hinted:
+            # a point source expands O(degree^2) rows; the device sweep
+            # is O(E) — the row path IS the fast path here
+            raise pl.LaneRefused("small_frontier")
+
+    def _device_row(self, ctx) -> dict:
+        from ...ops import pipeline as pl
+        acc = ctx.accessor
+        with mgtrace.span("lane.snapshot"):
+            edges = COLUMNAR_CACHE.get_edges(acc, (), ctx.view,
+                                             abort_check=ctx.check_abort)
+            ctx.check_abort()
+            if edges.n < _lane_min_rows() and not self.hinted:
+                raise pl.LaneRefused("small_input")
+            full = COLUMNAR_CACHE.get(acc, None, (), ctx.view,
+                                      abort_check=ctx.check_abort)
+            ctx.check_abort()
+        with mgtrace.span("lane.stage"):
+            f_sorted, f_order, s_idx, d_idx, emask, tkey = \
+                self._endpoints(ctx, full, edges)
 
         src_preds = list(self.src_preds)
         if self.source[0] == "label_prop_eq":
@@ -408,7 +452,8 @@ class LaneHopCount(Op.LogicalOperator):
             skey = (tkey, self.direction)
             staged = staged_cache.get(skey)
             if staged is None:
-                staged = pl.stage_edges(s_idx, d_idx, emask)
+                with mgtrace.span("lane.stage"):    # the upload
+                    staged = pl.stage_edges(s_idx, d_idx, emask)
                 staged_cache[skey] = staged
             totals = pl.hop_counts(staged[0], staged[1], staged[2],
                                    smask, midmask, tmask, full.n,

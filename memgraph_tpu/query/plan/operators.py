@@ -16,6 +16,7 @@ from typing import Optional
 
 from ...exceptions import (HintedAbortError, QueryException, SemanticException,
                            TypeException)
+from ...observability import trace as mgtrace
 from ...storage.common import View
 from ...storage.objects import Vertex
 from ...storage.ordering import order_key
@@ -1368,7 +1369,10 @@ class OrderBy(LogicalOperator):
                     return 1 if asc else -1
             return 0
 
-        rows.sort(key=functools.cmp_to_key(compare))
+        # a phase: the sort runs after the input is exhausted, so no
+        # producer's span (a CALL's row generator) holds it
+        with mgtrace.span("query.sort"):
+            rows.sort(key=functools.cmp_to_key(compare))
         for _, frame in rows:
             yield frame
 

@@ -48,7 +48,7 @@ MIN_ROWS = int(os.environ.get("MEMGRAPH_TPU_PARALLEL_MIN_ROWS", 1024))
 
 
 class _Unsupported(Exception):
-    pass
+    span_status = "refused"     # mgtrace: a decline, not a span error
 
 
 @dataclass

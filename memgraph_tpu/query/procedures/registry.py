@@ -18,6 +18,8 @@ import threading
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Optional
 
+from ...observability import trace as mgtrace
+
 
 @dataclass
 class Procedure:
@@ -62,8 +64,12 @@ class ProcedureContext:
             etf = {self.storage.edge_type_mapper.maybe_name_to_id(t)
                    for t in edge_types}
             etf.discard(None)
-        return GLOBAL_GRAPH_CACHE.get(self.accessor, weight_property=wp,
-                                      label_filter=lf, edge_type_filter=etf)
+        # the device_put inside is asynchronous: its tail shows in the
+        # analytics CALL's device wait, not here
+        with mgtrace.span("analytics.export"):
+            return GLOBAL_GRAPH_CACHE.get(self.accessor, weight_property=wp,
+                                          label_filter=lf,
+                                          edge_type_filter=etf)
 
     def vertex_by_index(self, graph, idx: int):
         """Dense device index -> VertexAccessor."""

@@ -15,6 +15,7 @@ import asyncio
 import logging
 import os
 import struct
+import time
 
 from ..exceptions import MemgraphTpuError, QueryException
 from ..observability import trace as mgtrace
@@ -229,9 +230,11 @@ class BoltSession:
         self._prepared = None
         import uuid as _uuid
         self.session_id = str(_uuid.uuid4())
-        # mgtrace: the session-level root of the current RUN..PULL*
-        # exchange (None unless tracing is armed)
+        # mgtrace: the root of the current RUN..PULL* exchange, the
+        # ``bolt.run`` phase (disarmed it only accounts its seconds)
         self._bolt_trace = None
+        # (wall, perf) clocks at the decode of the message in hand
+        self._msg_decoded = (time.time(), time.perf_counter())
         # interpreter work (parse/plan/execute/pull) runs on this pool so
         # one session's long query never blocks the event loop — the
         # reference runs sessions on a work-stealing priority pool
@@ -280,9 +283,22 @@ class BoltSession:
 
     async def _offload(self, fn, *args):
         if self._executor is None:
-            return fn(*args)
+            return self._offloaded(fn, args)
         loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(self._executor, fn, *args)
+        return await loop.run_in_executor(self._executor, self._offloaded,
+                                          fn, args)
+
+    def _offloaded(self, fn, args):
+        """On the worker thread: close the message's ``bolt.wait`` (its
+        decode -> this thread starting on it: the executor's queue),
+        then run fn under the session's trace context (thread-local, so
+        the activation must happen ON this thread)."""
+        handle = self._bolt_trace
+        with mgtrace.activate(handle.ctx if handle is not None else None):
+            wall, t0 = self._msg_decoded
+            mgtrace.record_span("bolt.wait", wall,
+                                time.perf_counter() - t0)
+            return fn(*args)
 
     # --- wire framing -------------------------------------------------------
 
@@ -331,6 +347,7 @@ class BoltSession:
             while True:
                 data = await self.read_message()
                 msg = ps.unpack(data)
+                self._msg_decoded = (time.time(), time.perf_counter())
                 if not isinstance(msg, ps.Structure):
                     raise MemgraphTpuError("malformed bolt message")
                 if not await self.dispatch(msg):
@@ -527,15 +544,6 @@ class BoltSession:
         self.send_success()
         return True
 
-    def _traced_call(self, fn, *args):
-        """Run fn on the worker thread under the session's trace context
-        (thread-local, so the activation must happen ON that thread)."""
-        handle = self._bolt_trace
-        if handle is None:
-            return fn(*args)
-        with mgtrace.activate(handle.ctx):
-            return fn(*args)
-
     def _finish_bolt_trace(self, status: str = "ok") -> None:
         if self._bolt_trace is not None:
             self._bolt_trace.finish(status=status)
@@ -543,34 +551,32 @@ class BoltSession:
 
     async def on_run(self, query: str, parameters: dict = None,
                      extra: dict = None) -> bool:
+        # RUN received -> last PULL answered is the bolt.run phase. The
+        # Bolt extra-metadata field is the trace carrier across the
+        # client boundary: drivers propagate {"trace": {trace_id,
+        # span_id, sampled}} and the whole server-side trace joins the
+        # caller's (begin_trace reads it only when armed)
+        self._finish_bolt_trace("abandoned")
+        carrier = None
+        if isinstance(extra, dict):
+            carrier = extra.get("trace") or \
+                (extra.get("tx_metadata") or {}).get("trace")
+        self._bolt_trace = mgtrace.begin_trace(
+            "bolt.run", carrier if isinstance(carrier, dict) else None)
+        trace_id = self._bolt_trace.trace_id
         parameters = {k: bolt_to_value(v)
                       for k, v in (parameters or {}).items()}
-        if mgtrace.armed():
-            # the Bolt extra-metadata field is the trace carrier across
-            # the client boundary: drivers propagate {"trace":
-            # {trace_id, span_id, sampled}} and the whole server-side
-            # trace joins the caller's
-            self._finish_bolt_trace("abandoned")
-            carrier = None
-            if isinstance(extra, dict):
-                carrier = extra.get("trace") or \
-                    (extra.get("tx_metadata") or {}).get("trace")
-            self._bolt_trace = mgtrace.begin_trace(
-                "bolt.run", carrier if isinstance(carrier, dict) else None)
-        import time as _time
-        t0 = _time.perf_counter()
-        prepared = await self._offload(self._traced_call,
-                                       self.interpreter.prepare, query,
+        t0 = time.perf_counter()
+        prepared = await self._offload(self.interpreter.prepare, query,
                                        parameters)
         from ..observability.metrics import global_metrics
         global_metrics.observe(
-            "bolt.prepare_latency_sec", _time.perf_counter() - t0,
-            trace_id=self._bolt_trace.trace_id
-            if self._bolt_trace is not None else None)
+            "bolt.prepare_latency_sec", time.perf_counter() - t0,
+            trace_id=trace_id)
         self._prepared = prepared
         meta = {"fields": prepared.columns, "t_first": 0, "qid": 0}
-        if self._bolt_trace is not None:
-            meta["trace_id"] = self._bolt_trace.trace_id
+        if trace_id is not None:
+            meta["trace_id"] = trace_id
         self.send_success(meta)
         return True
 
@@ -593,10 +599,12 @@ class BoltSession:
             if stats and any(stats.values()):
                 meta["stats"] = {k.replace("_", "-"): v
                                  for k, v in stats.items() if v}
-            if self._bolt_trace is not None:
+            if self._bolt_trace is not None \
+                    and self._bolt_trace.trace_id is not None:
                 meta["trace_id"] = self._bolt_trace.trace_id
-                self._finish_bolt_trace("ok")
         self.send_success(meta)
+        if not has_more:
+            self._finish_bolt_trace("ok")
         return True
 
     async def on_discard(self, extra: dict) -> bool:

@@ -362,8 +362,12 @@ class Accessor:
         from ..observability import trace as mgtrace
         if self._finished:
             raise StorageError("transaction already finished")
+        # a read-only transaction has nothing to make durable: its end
+        # is no commit phase (mvcc.commit's seconds are the writers')
+        writes = bool(self.txn.deltas or self.txn.stream_offsets)
         try:
-            with mgtrace.span("mvcc.commit") as sp:
+            with (mgtrace.span("mvcc.commit") if writes
+                  else mgtrace.span("mvcc.release")) as sp:
                 commit_ts = self.storage._commit(self.txn)
                 if sp:
                     sp.set(txn_id=self.txn.id, commit_ts=commit_ts)
@@ -1502,6 +1506,12 @@ class InMemoryStorage:
         Reference analog: InMemoryStorage::CollectGarbage
         (inmemory/storage.cpp:573) + skip-list GC.
         """
+        from ..observability import trace as mgtrace
+        with mgtrace.span("storage.gc"):
+            return self._collect_garbage()
+
+    def _collect_garbage(self) -> dict:
+        from ..observability import trace as mgtrace
         oldest = self.oldest_active_start_ts()
         stats = {"deltas_freed": 0, "vertices_freed": 0, "edges_freed": 0}
         # bulk ingest freezes the heap (batch_insert) so cyclic GC stops
@@ -1532,16 +1542,19 @@ class InMemoryStorage:
                     prev = delta
                     delta = delta.next
 
+        # the O(V+E) sweep, apart from the thaw above and from what the
+        # cyclic collector does with the thawed heap afterwards
         dead_vertices = []
-        for gid, v in list(self._vertices.items()):
-            truncate(v)
-            if v.deleted and v.delta is None:
-                dead_vertices.append((gid, v))
         dead_edges = []
-        for gid, e in list(self._edges.items()):
-            truncate(e)
-            if e.deleted and e.delta is None:
-                dead_edges.append((gid, e))
+        with mgtrace.span("storage.gc.sweep"):
+            for gid, v in list(self._vertices.items()):
+                truncate(v)
+                if v.deleted and v.delta is None:
+                    dead_vertices.append((gid, v))
+            for gid, e in list(self._edges.items()):
+                truncate(e)
+                if e.deleted and e.delta is None:
+                    dead_edges.append((gid, e))
 
         for gid, v in dead_vertices:
             for label_id in list(v.labels):

@@ -27,35 +27,50 @@ _done = False
 _compile_listener = False
 
 
+#: jax.monitoring's names (jax 0.9: _src/compiler.py, compilation_cache.py)
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
 def install_compile_counter() -> bool:
-    """Runtime witness for the mgxla static compile budget: every XLA
-    backend compile in this process bumps the ``jit.compile_total``
-    counter (exported through SHOW METRICS INFO / ``GET /stats``), so a
-    silent recompile storm — the exact hazard mglint MG008 and the
-    lane-bucket contract check guard statically — shows up as a moving
-    counter in production. Idempotent; riding ``jax.monitoring``'s
-    backend-compile duration event keeps it zero-cost when nothing
-    compiles."""
+    """Runtime witness for the mgxla static compile budget, riding
+    ``jax.monitoring`` (zero-cost when nothing compiles). Every XLA
+    backend compile in this process — an executable load served from
+    the persistent cache too — bumps ``jit.compile_total`` and adds its
+    duration to ``jit.backend_seconds_total``; one the persistent cache
+    did not serve (compiled, then written to it) bumps
+    ``jit.cache_miss_total``. Exported through SHOW METRICS INFO /
+    ``GET /stats``, so a silent recompile storm — the exact hazard
+    mglint MG008 and the lane-bucket contract check guard statically —
+    shows up as moving counters in production, and a 0.5 s cache load
+    can be told from a 3 s compile. Idempotent."""
     global _compile_listener
     if _compile_listener:
         return True
     try:
         from jax import monitoring
     except Exception as e:  # noqa: BLE001 — the witness is optional
-        log.info("jax.monitoring unavailable; jit.compile_total "
+        log.info("jax.monitoring unavailable; jit.* counters "
                  "disabled: %s", e)
         return False
+    from ..observability.metrics import global_metrics
 
     def _on_duration(event: str, duration: float = 0.0, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            from ..observability.metrics import global_metrics
+        if event == _BACKEND_COMPILE_EVENT:
             global_metrics.increment("jit.compile_total")
+            global_metrics.increment("jit.backend_seconds_total",
+                                     float(duration))
+
+    def _on_event(event: str, **kw) -> None:
+        if event == _CACHE_MISS_EVENT:
+            global_metrics.increment("jit.cache_miss_total")
 
     try:
         monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
     except Exception as e:  # noqa: BLE001 — the witness is optional
-        log.info("could not register compile listener; "
-                 "jit.compile_total disabled: %s", e)
+        log.info("could not register compile listeners; jit.* "
+                 "counters disabled: %s", e)
         return False
     _compile_listener = True
     return True

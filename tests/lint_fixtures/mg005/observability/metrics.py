@@ -9,6 +9,7 @@ STAT_NAMES = (
     "dead.family.*",    # MG005: family with no dynamic site
     "dup.stat",         # emitted once ...
     "dup.stat",         # ... MG005: but declared twice
+    "span.*",           # emitted by trace.py's PHASES keys: silent
 )
 
 

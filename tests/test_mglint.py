@@ -142,7 +142,30 @@ def test_mg005_fires_on_coverage_gaps_only():
     assert "stat-dead:dead.stat" in msgs
     assert "stat-dead-family:dead.family.*" in msgs
     assert "stat-duplicate:dup.stat" in msgs
-    assert len(msgs) == 13, msgs             # OP_WIRED is fully covered
+    # PR 26 phase mark: a PHASES key that is no declared span fires; the
+    # span.* family is emitted by the phase registry itself, so it is
+    # not a dead family, and the wired phase stays silent
+    assert "phase-undeclared:ghost.phase" in msgs
+    assert "stat-dead-family:span.*" not in msgs
+    assert "phase-undeclared:wired.span" not in msgs
+    assert len(msgs) == 14, msgs             # OP_WIRED is fully covered
+
+
+def test_mg005_span_family_is_dead_without_a_phase_registry(tmp_path):
+    """span.* counts as emitted only while trace.py marks phases: with
+    no PHASES it is a dead family like any other."""
+    import shutil
+    tree = tmp_path / "pkg"
+    shutil.copytree(os.path.join(REPO, "tests", "lint_fixtures", "mg005"),
+                    tree)
+    trace = tree / "observability" / "trace.py"
+    text = trace.read_text()
+    head, _, rest = text.partition("PHASES = {")
+    trace.write_text(head + rest.split("}\n", 1)[1])
+    result = _run([str(tree)], only={"MG005"})
+    msgs = {f.fingerprint for f in result.findings}
+    assert "stat-dead-family:span.*" in msgs
+    assert not any(m.startswith("phase-undeclared") for m in msgs)
 
 
 def test_mg006_fires_on_unguarded_access_only():

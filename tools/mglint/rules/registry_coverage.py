@@ -36,7 +36,8 @@ registered op).
 
 Span names (r13, mgtrace): every literal span name opened in product
 code — ``span("x")`` / ``record_span("x", ...)`` / ``begin_trace("x")``
-— must be declared in observability/trace.py ``SPAN_NAMES`` (a typo'd
+— must be declared in observability/trace.py ``SPAN_NAMES``, and so
+must every key of its ``PHASES`` mark (a typo'd
 name silently fragments a trace), and every declared name must have at
 least one live open site. Spans may ONLY be opened through that
 context-manager API: any call to the private ``_begin_span``/
@@ -613,6 +614,23 @@ def _check_spmv_registry(project: Project):
 _SPAN_OPEN_FUNCS = ("span", "record_span", "begin_trace")
 
 
+def _collect_phase_marks(tr) -> dict[str, int]:
+    """{span name: lineno} of the module-level ``PHASES`` dict's literal
+    keys in observability/trace.py: the spans accounted on every close
+    (``span.<name>.seconds_total`` / ``.count``), armed or not."""
+    out: dict[str, int] = {}
+    for stmt in tr.tree.body:
+        if isinstance(stmt, ast.Assign) and len(stmt.targets) == 1 \
+                and isinstance(stmt.targets[0], ast.Name) \
+                and stmt.targets[0].id == "PHASES" \
+                and isinstance(stmt.value, ast.Dict):
+            for key in stmt.value.keys:
+                if isinstance(key, ast.Constant) and \
+                        isinstance(key.value, str):
+                    out[key.value] = key.lineno
+    return out
+
+
 def _check_span_registry(project: Project):
     tr = project.by_suffix("observability/trace.py")
     if tr is None:
@@ -622,6 +640,16 @@ def _check_span_registry(project: Project):
         return []
 
     findings = []
+    # the phase mark rides the span registry: a phase is a declared span
+    for phase, line in sorted(_collect_phase_marks(tr).items()):
+        if phase not in names:
+            findings.append(Finding(
+                rule="MG005", path=tr.rel_path, line=line, col=0,
+                symbol="PHASES",
+                message=f"phase {phase!r} is not declared in "
+                        "SPAN_NAMES — a phase is a span name marked as "
+                        "always accounted, not a second vocabulary",
+                fingerprint=f"phase-undeclared:{phase}"))
     opened: set[str] = set()
     for rel, sf in project.files.items():
         if sf is tr:
@@ -736,6 +764,11 @@ def _check_stat_registry(project: Project):
 
     used_exact: set[str] = set()
     used_family: set[str] = set()
+    # the span.* family's emit site is the phase registry itself: every
+    # PHASES key emits span.<name>.seconds_total / .count on close
+    tr = project.by_suffix("observability/trace.py")
+    if tr is not None and _collect_phase_marks(tr):
+        used_family.add("span.")
     for rel, sf in project.files.items():
         if sf is mx:
             continue
