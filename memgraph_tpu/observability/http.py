@@ -101,12 +101,13 @@ async def start_monitoring_server(host: str, port: int, ictx):
                             if name.startswith(
                                 ("ppr.", "kernel_server.daemon.ppr."))},
                     # the chip owner's plane: the compile witness
-                    # (jit.*), in-process fixpoint iterations (device.*)
+                    # (jit.*), the MXU program table's hits and misses
+                    # (mxu.*), in-process fixpoint iterations (device.*)
                     # and every phase span's seconds and closes (span.*)
                     "device": {name: value for name, _k, value
                                in global_metrics.snapshot()
                                if name.startswith(
-                                   ("jit.", "device.", "span."))},
+                                   ("jit.", "mxu.", "device.", "span."))},
                     # incremental analytics plane (r19, mgdelta):
                     # delta applies/compactions/fallbacks, warm-start
                     # counters, resident-generation gauge (local plus

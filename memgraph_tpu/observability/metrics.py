@@ -21,7 +21,7 @@ import re
 import time
 from collections import defaultdict
 
-from ..utils.locks import tracked_lock
+from ..utils.locks import tracked_rlock
 from ..utils.sanitize import shared_field, shared_read, shared_write
 
 _NAME_BAD = re.compile(r"[^a-zA-Z0-9_:]")
@@ -121,6 +121,10 @@ STAT_NAMES = (
     "jit.compile_total",
     "jit.backend_seconds_total",    # seconds of those compiles and loads
     "jit.cache_miss_total",         # compiles the persistent cache missed
+    # the MXU fixpoint's program table (ops/spmv_mxu.py _PROGRAMS): a
+    # kernel whose signature was there calls an already-traced program
+    "mxu.program_hit_total",
+    "mxu.program_miss_total",       # a first-seen signature: trace + load
     # phase spans (mgtrace PHASES): seconds and closes of every phase,
     # armed or not — span.<name>.seconds_total / span.<name>.count
     "span.*",
@@ -265,7 +269,12 @@ class Histogram:
 
 class Metrics:
     def __init__(self) -> None:
-        self._lock = tracked_lock("Metrics._lock")
+        # reentrant: a coroutine's `finally` that sets a gauge
+        # (server/bolt.py _handle) can be run by the garbage collector
+        # on a thread that is inside one of these methods; with a plain
+        # lock that thread waits for itself, and every other thread for
+        # it (a tier-1 run hung so, ROADMAP D12)
+        self._lock = tracked_rlock("Metrics._lock")
         self._counters: dict[str, float] = defaultdict(int)
         self._gauges: dict[str, float] = {}
         self._histograms: dict[str, Histogram] = {}

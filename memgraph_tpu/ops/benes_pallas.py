@@ -60,9 +60,13 @@ class BenesPallasSpec:
 
 
 def build_pallas_masks(masks_packed: np.ndarray, net_log2: int,
-                       K: int | None = None):
+                       K: int | None = None, keep_dead: bool = False):
     """Reorganize bit-packed stage masks (n_stages, N/8 uint8, packbits
     order) into per-element int32 bit-planes + static spec.
+
+    keep_dead: route all-zero-mask stages too, so the spec follows from
+    net_log2 alone and nets of one size share one compiled program
+    (ops/spmv_mxu.py: the delta net, whose masks change every CALL).
 
     Returns (spec, mid_words, outer_words):
       mid_words   (mid_planes, N/128, 128) int32
@@ -87,7 +91,7 @@ def build_pallas_masks(masks_packed: np.ndarray, net_log2: int,
     outer_bit = 0
     for s, d in enumerate(dists):
         row = masks_packed[s]
-        if not row.any():
+        if not keep_dead and not row.any():
             continue                   # dead stage: no swaps routed
         bits = np.unpackbits(row)[:N].astype(np.int64).reshape(rows, LANES)
         if d < (1 << K):

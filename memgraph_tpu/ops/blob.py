@@ -75,6 +75,21 @@ def pack_blob(arrays: dict):
     return np.concatenate(parts), segs
 
 
+def segs_key(segs: dict) -> tuple:
+    """A blob's segment table as a hashable key: two blobs with equal
+    keys are read by the same compiled program."""
+    return tuple((name, off, n_words, kind, tuple(int(d) for d in shape),
+                  np.dtype(dtype).str)
+                 for name, (off, n_words, kind, shape, dtype)
+                 in segs.items())
+
+
+def segs_from_key(key: tuple) -> dict:
+    """The segment table `unblob` reads, back from its `segs_key`."""
+    return {name: (off, n_words, kind, shape, np.dtype(dtype))
+            for name, off, n_words, kind, shape, dtype in key}
+
+
 def unblob(blob, segs, name):
     """Traced: reconstruct one array from the int32-word device blob.
 
@@ -127,10 +142,7 @@ def put_packed(arrays: dict) -> dict:
     ensure_compile_cache()
 
     blob_np, segs = pack_blob(arrays)
-    key = tuple(sorted(
-        (name, off, n_words, kind, tuple(int(s) for s in shape),
-         np.dtype(dtype).str)
-        for name, (off, n_words, kind, shape, dtype) in segs.items()))
+    key = segs_key(segs)
     prepare = _PREPARE_CACHE.get(key)
     if prepare is None:
         @jax.jit

@@ -139,7 +139,9 @@ def _try_delta_plan(graph: DeviceGraph):
         delta = spmv_mxu.build_delta_plan(base_plan, a_s, a_d, a_w,
                                           r_s, r_d, r_w)
     global_metrics.increment("delta.plan_applied_total")
-    with mgtrace.span("analytics.launch"):      # closure + blob upload
+    # the delta's blob alone is packed and uploaded; the base's stays
+    # resident with base_plan and the program comes from the table
+    with mgtrace.span("analytics.launch"):
         run = spmv_mxu.make_pagerank_kernel(base_plan, delta=delta)
     return (base_plan, run)
 
@@ -197,9 +199,9 @@ def _pagerank_via_mxu(graph: DeviceGraph, damping, max_iterations, tol,
         if np.isfinite(total) and total > 0.0:
             x0_flat = np.zeros(len(plan.valid_out), dtype=np.float32)
             x0_flat[plan.out_relabel] = x0 / np.float32(total)
-    # launch returns at enqueue (re-trace, lowering, executable load or
-    # compile included); the readback is the block. Together they are
-    # the device_iterate stage.
+    # launch returns at enqueue (a first-seen program signature traces,
+    # lowers and loads or compiles here; any other is a dispatch); the
+    # readback is the block. Together they are the device_iterate stage.
     with mgtrace.span("analytics.launch", backend="mxu"):
         # None = uniform start computed on-device (saves a transfer)
         rank, err, iters = run(x0_flat, np.float32(damping),

@@ -41,13 +41,17 @@ from __future__ import annotations
 
 import logging
 import os
-from dataclasses import dataclass
+import threading
+from collections import OrderedDict
+from dataclasses import dataclass, field
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
+from ..observability.metrics import global_metrics
 from .benes import benes_stage_distances, route_packed
+from .benes_pallas import BenesPallasSpec
 
 log = logging.getLogger(__name__)
 
@@ -88,6 +92,11 @@ class MXUPlan:
     # per-node out-weight sums (ORIGINAL ids) — the delta-refresh path
     # rescales stale w/wsum multipliers with these (see DeltaPlan)
     wsum: np.ndarray = None
+    # the plan's arrays packed and on the device, one _Resident per
+    # Benes backend: built by the first kernel over this plan, reused
+    # by every later one (_resident_base)
+    _resident: dict = field(default_factory=dict, repr=False,
+                            compare=False)
 
 
 def _relabel_by(key: np.ndarray, stripe_groups: int = 0) -> np.ndarray:
@@ -111,7 +120,8 @@ def _relabel_by(key: np.ndarray, stripe_groups: int = 0) -> np.ndarray:
     return relab
 
 
-def _gather_layout(src, w, relab_out, inv_wsum, G, force_R_G=None):
+def _gather_layout(src, w, relab_out, inv_wsum, G, force_R_G=None,
+                   pad_R_G=None):
     """Gather-side layout for an edge subset under a FIXED out labeling.
 
     Returns (R_G, rowid, mult, gp_by_edge): rows per supergroup, the
@@ -120,6 +130,8 @@ def _gather_layout(src, w, relab_out, inv_wsum, G, force_R_G=None):
 
     force_R_G: use this (>= required) row count so plans for different
     edge shards stack into uniform arrays.
+    pad_R_G: required row count -> the (>=) count to lay out with; the
+    delta plan's quantisation (build_delta_plan).
     """
     E = len(src)
     node_flat = G * SG_ROWS * LANES
@@ -131,6 +143,8 @@ def _gather_layout(src, w, relab_out, inv_wsum, G, force_R_G=None):
     H_out = deg_l.reshape(-1, LANES).max(axis=1)              # per src-row
     rows_per_sg = H_out.reshape(G, SG_ROWS).sum(axis=1)
     R_G = max(1, int(rows_per_sg.max()))
+    if pad_R_G is not None:
+        R_G = pad_R_G(R_G)
     if force_R_G is not None:
         if force_R_G < R_G:
             raise ValueError(f"force_R_G={force_R_G} < required {R_G}")
@@ -352,6 +366,20 @@ class DeltaPlan:
     wsum: np.ndarray           # updated per-node out-weight sums
 
 
+#: the delta net's shapes are quantised: the floor below, then steps of
+#: this factor, so that a delta growing by one burst a CALL against its
+#: base anchor keeps ONE compiled program (make_semiring_kernel keys its
+#: program table on these shapes) until it is 4x the floor
+DELTA_SHAPE_STEP = 4
+
+
+def _quantise(need: int, floor: int) -> int:
+    q = floor
+    while q < need:
+        q *= DELTA_SHAPE_STEP
+    return q
+
+
 def build_delta_plan(base: MXUPlan,
                      add_src, add_dst, add_w=None,
                      rem_src=None, rem_dst=None, rem_w=None,
@@ -359,8 +387,12 @@ def build_delta_plan(base: MXUPlan,
     """Build the O(delta) side-plan. All ids are ORIGINAL node ids and
     must be < base.n_nodes (node additions require a full replan).
 
-    bucket=True pads R_G / C to powers of two so growing deltas reuse
-    the same compiled kernel shapes (recompiles only on bucket jumps)."""
+    bucket=True quantises R_G / C so growing deltas reuse one compiled
+    program: R_G starts at one gather row per source row of a
+    supergroup (SG_ROWS), C at two chunks per output window (2 W: one is
+    the least the layout can need, a second is taken as soon as one
+    destination receives two delta edges), and both grow in steps of
+    DELTA_SHAPE_STEP. The padding is dead rows and dead chunks."""
     if base.wsum is None:
         raise ValueError("base plan predates delta support (no wsum)")
     n = base.n_nodes
@@ -393,18 +425,15 @@ def build_delta_plan(base: MXUPlan,
 
     G = base.G
     n_drows_p = base.W * K_C
-    R_G, rowid, mult, gp = _gather_layout(d_src, d_w, base.out_relabel,
-                                          inv_new, G)
-    if bucket and R_G & (R_G - 1):
-        R_G = 1 << R_G.bit_length()
-        R_G, rowid, mult, gp = _gather_layout(
-            d_src, d_w, base.out_relabel, inv_new, G, force_R_G=R_G)
+    R_G, rowid, mult, gp = _gather_layout(
+        d_src, d_w, base.out_relabel, inv_new, G,
+        pad_R_G=partial(_quantise, floor=SG_ROWS) if bucket else None)
     C, run_k, win_oh, sp, R_total = _scatter_layout(
         d_dst, base.in_relabel, n_drows_p)
-    if bucket and C & (C - 1):
+    C_pad = _quantise(C, 2 * base.W) if bucket else C
+    if C_pad != C:
         # pad with dead chunks: run_k=-1 rows extract nothing, zero
         # win_oh rows route no window
-        C_pad = 1 << C.bit_length()
         run_k = np.concatenate(
             [run_k, np.full((C_pad - C, R_C), -1, dtype=run_k.dtype)])
         win_oh = np.concatenate(
@@ -492,188 +521,238 @@ def pagerank_mxu_epilogue(rank, acc, env, P):
     return new_rank, err
 
 
-def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
-                         delta: "DeltaPlan" = None,
-                         x0_default: str = "uniform"):
-    """Returns jitted fn(x0_flat, params, max_iter, tol) ->
-    (x_flat, err, iters); state vectors are flat in OUT labeling,
-    length G*SG_ROWS*LANES.  The semiring-parameterized generalization
-    of the pagerank-only r5 kernel: the matvec (expand -> Benes route ->
-    MXU reduce/extract -> node relabel) is fixed ⊕ = sum machinery —
-    the one-hot extract matmul IS the sum — while the fused
-    ``epilogue(x, acc, env, params) -> (new_x, err)`` supplies the
-    algorithm (env carries valid / dangling / n_f; params is a dict of
-    traced scalars).  ⊗ is baked into the plan's multipliers
-    (build_plan(normalize=...)).
+class _Net(NamedTuple):
+    """What a program needs to know of one edge net (the base's or the
+    delta's) before it is traced. ``route`` is how the Benes network
+    runs: a BenesPallasSpec (Pallas passes), a tuple of bools (rolls,
+    dead stages skipped) or None (rolls, every stage)."""
+    R_G: int
+    C: int
+    net_log2: int
+    segs: tuple                # the blob's segment table (blob.segs_key)
+    route: object
 
-    route_dtype: dtype for the per-edge contributions through the big
-    Benes (the dominant HBM traffic). bfloat16 halves it; sums still
-    accumulate in f32 on the MXU, so each contribution carries one
-    0.4%-relative rounding — validated to preserve exact top-100 order
-    on the 10M-edge bench graph. float32 is the exact path.
 
-    delta: optional DeltaPlan — per iteration the base expand reads
-    rank pre-scaled by delta.scale_out, the delta edges route through
-    their own (small) net, and both accumulators sum before the node
-    relabel. Exact for edge additions AND removals.
+class _Signature(NamedTuple):
+    """Everything static in a fixpoint program, and nothing that is
+    data: two kernels with equal signatures run the same jitted
+    functions on different blobs."""
+    n_nodes: int
+    G: int
+    W: int
+    node_net_log2: int
+    node_route: object         # as _Net.route, for the node relabel net
+    base: _Net
+    delta: Optional[_Net]      # None: the full plan alone
+    route_dtype: str
+    epilogue: object           # the algorithm's fused update (a function)
+    x0_default: str
 
-    x0_default: the on-device start when x0 is None — "uniform"
-    (valid/n, pagerank) or "zeros" (katz)."""
+
+@dataclass(frozen=True)
+class _Resident:
+    """One side's arrays as one int32 blob on the device, with the
+    statics that read it."""
+    blob: object
+    net: _Net
+    node_route: object = None  # base side only
+
+
+def _pallas_or_bits(arrays: dict, prefix: str, masks_packed, net_log2,
+                    pallas: bool, keep_dead: bool):
+    """Add one Benes net's masks to a blob's arrays; returns its
+    ``route``."""
+    if pallas:
+        from .benes_pallas import build_pallas_masks
+        spec, mid, out = build_pallas_masks(masks_packed, net_log2,
+                                            keep_dead=keep_dead)
+        arrays[f"pb_{prefix}_mid"] = mid
+        if out is not None:
+            arrays[f"pb_{prefix}_out"] = out
+        return spec
+    arrays[f"{prefix}_masks"] = ("bits", masks_packed)
+    # all-zero-mask stages route nothing: skipped at trace time
+    return (None if keep_dead
+            else tuple(bool(row.any()) for row in masks_packed))
+
+
+_resident_lock = threading.Lock()
+
+
+def _resident_base(plan: MXUPlan, use_pallas: bool) -> _Resident:
+    """The base plan's blob: packed and uploaded by the first kernel
+    over ``plan``, kept with the plan for every later one (a CALL after
+    a write packs its delta alone)."""
+    import jax
+    from .blob import pack_blob, segs_key
+    with _resident_lock:
+        got = plan._resident.get(use_pallas)
+        if got is None:
+            arrays = {
+                "mult": plan.mult.astype(np.float32),
+                "rowid_i32": plan.rowid.astype(np.int32),
+                "run_k_i32": plan.run_k.astype(np.int32),
+                "win_oh": plan.win_oh.astype(np.float32),
+                "valid": plan.valid_out.astype(np.float32),
+                "dangling": plan.dangling_out.astype(np.float32),
+            }
+            # the base nets skip their dead stages: fixed per plan
+            big = _pallas_or_bits(arrays, "big", plan.masks_packed,
+                                  plan.net_log2, use_pallas, False)
+            node = _pallas_or_bits(arrays, "node", plan.node_masks_packed,
+                                   plan.node_net_log2, use_pallas, False)
+            blob_np, segs = pack_blob(arrays)
+            got = plan._resident[use_pallas] = _Resident(
+                jax.device_put(blob_np),
+                _Net(plan.R_G, plan.C, plan.net_log2, segs_key(segs),
+                     big), node)
+    return got
+
+
+def _upload_delta(delta: DeltaPlan, use_pallas: bool) -> _Resident:
+    """A CALL's own upload: the delta net and the vectors it replaces.
+    Every stage of the delta's Benes net is routed, dead or not: which
+    stages a burst leaves dead is data, and the program must not
+    follow it."""
+    import jax
+    from .blob import pack_blob, segs_key
+    arrays = {
+        "d_mult": delta.mult.astype(np.float32),
+        "d_rowid_i32": delta.rowid.astype(np.int32),
+        "d_run_k_i32": delta.run_k.astype(np.int32),
+        "d_win_oh": delta.win_oh.astype(np.float32),
+        "d_scale": delta.scale_out.astype(np.float32),
+        # the delta's dangling vector REPLACES the base one
+        "dangling": delta.dangling_out.astype(np.float32),
+    }
+    route = _pallas_or_bits(arrays, "d", delta.masks_packed,
+                            delta.net_log2,
+                            use_pallas and delta.net_log2 >= 12, True)
+    blob_np, segs = pack_blob(arrays)
+    return _Resident(
+        jax.device_put(blob_np),
+        _Net(delta.R_G, delta.C, delta.net_log2, segs_key(segs), route))
+
+
+# ---------------------------------------------------------------------------
+# the program table: one traced fixpoint per signature
+# ---------------------------------------------------------------------------
+
+#: jitted (run_impl, run_impl_default) by _Signature. A kernel whose
+#: signature is here calls functions JAX has already traced: no trace,
+#: no lowering, no executable load. LRU-bounded: a program holds its
+#: executables alive.
+_PROGRAMS: "OrderedDict[_Signature, tuple]" = OrderedDict()
+_PROGRAMS_MAX = 32
+_programs_lock = threading.Lock()
+
+
+def _program(sig: _Signature) -> tuple:
+    """Get-then-build-then-store under one lock, counted:
+    mxu.program_hit_total / mxu.program_miss_total."""
+    with _programs_lock:
+        pair = _PROGRAMS.get(sig)
+        if pair is not None:
+            _PROGRAMS.move_to_end(sig)
+            global_metrics.increment("mxu.program_hit_total")
+            return pair
+        pair = _PROGRAMS[sig] = _build_program(sig)
+        while len(_PROGRAMS) > _PROGRAMS_MAX:
+            _PROGRAMS.popitem(last=False)
+    global_metrics.increment("mxu.program_miss_total")
+    return pair
+
+
+def _build_program(sig: _Signature) -> tuple:
+    """Define (not yet trace) the jitted fixpoint of one signature:
+    ``run_impl(blob, x0, params, max_iterations, tol, dblob)`` and
+    ``run_impl_default(blob, params, max_iterations, tol, dblob)``.
+    Everything they close over comes from ``sig``; the plan's data
+    arrives in ``blob`` and the delta's in ``dblob`` (None without)."""
     import jax
     import jax.numpy as jnp
-    from ..utils.jax_cache import ensure_compile_cache
-    ensure_compile_cache()
+    from .benes_pallas import benes_apply_pallas
+    from .blob import segs_from_key, unblob
 
-    if route_dtype is None:
-        route_dtype = (jnp.bfloat16 if os.environ.get(
-            "MEMGRAPH_TPU_ROUTE_DTYPE", "f32") == "bf16" else jnp.float32)
-
-    G, R_G, C, W = plan.G, plan.R_G, plan.C, plan.W
-    N_net = 1 << plan.net_log2
-    N_nn = 1 << plan.node_net_log2
+    G = sig.G
+    base, delta = sig.base, sig.delta
+    N_net = 1 << base.net_log2
+    N_nn = 1 << sig.node_net_log2
     node_flat = G * SG_ROWS * LANES
-    n_f = float(plan.n_nodes)
+    n_f = float(sig.n_nodes)
+    route_dtype = jnp.dtype(sig.route_dtype)
+    epilogue, x0_default = sig.epilogue, sig.x0_default
 
-    # Benes backend: the pallas 3-pass formulation makes 3 HBM round
-    # trips where the roll path makes one per stage; the XLA roll path
-    # remains for CPU (tests / virtual meshes) and tiny nets
-    benes_mode = os.environ.get("MEMGRAPH_TPU_BENES", "auto")
-    use_pallas = (benes_mode == "pallas"
-                  or (benes_mode == "auto"
-                      and jax.default_backend() not in ("cpu",)
-                      and plan.net_log2 >= 12
-                      and plan.node_net_log2 >= 12))
-    # said once per kernel: which Benes formulation the device will run
-    log.info("MXU kernel: Benes backend %s (net 2^%d, node net 2^%d, "
-             "route %s)", "pallas" if use_pallas else "rolls",
-             plan.net_log2, plan.node_net_log2, jnp.dtype(route_dtype).name)
-
-    from .blob import pack_blob, unblob
-    blob_arrays = {
-        "mult": plan.mult.astype(np.float32),
-        "rowid_i32": plan.rowid.astype(np.int32),
-        "run_k_i32": plan.run_k.astype(np.int32),
-        "win_oh": plan.win_oh.astype(np.float32),
-        "valid": plan.valid_out.astype(np.float32),
-        "dangling": plan.dangling_out.astype(np.float32),
-    }
-    if use_pallas:
-        from .benes_pallas import build_pallas_masks
-        big_spec, big_mid, big_out = build_pallas_masks(
-            plan.masks_packed, plan.net_log2)
-        node_spec, node_mid, node_out = build_pallas_masks(
-            plan.node_masks_packed, plan.node_net_log2)
-        blob_arrays["pb_big_mid"] = big_mid
-        if big_out is not None:
-            blob_arrays["pb_big_out"] = big_out
-        blob_arrays["pb_node_mid"] = node_mid
-        if node_out is not None:
-            blob_arrays["pb_node_out"] = node_out
-    else:
-        blob_arrays["masks"] = ("bits", plan.masks_packed)
-        blob_arrays["node_masks"] = ("bits", plan.node_masks_packed)
+    segs = segs_from_key(base.segs)
+    d_segs = segs_from_key(delta.segs) if delta is not None else {}
+    # the three Benes nets: how each runs, and its size
+    nets = {"big": (base.route, base.net_log2),
+            "node": (sig.node_route, sig.node_net_log2)}
     if delta is not None:
-        N_dnet = 1 << delta.net_log2
-        blob_arrays["d_mult"] = delta.mult.astype(np.float32)
-        blob_arrays["d_rowid_i32"] = delta.rowid.astype(np.int32)
-        blob_arrays["d_run_k_i32"] = delta.run_k.astype(np.int32)
-        blob_arrays["d_win_oh"] = delta.win_oh.astype(np.float32)
-        blob_arrays["d_scale"] = delta.scale_out.astype(np.float32)
-        # the delta's dangling vector REPLACES the base one
-        blob_arrays["dangling"] = delta.dangling_out.astype(np.float32)
-        use_pallas_delta = use_pallas and delta.net_log2 >= 12
-        if use_pallas_delta:
-            d_spec, d_mid, d_out = build_pallas_masks(
-                delta.masks_packed, delta.net_log2)
-            blob_arrays["pb_d_mid"] = d_mid
-            if d_out is not None:
-                blob_arrays["pb_d_out"] = d_out
-        else:
-            blob_arrays["d_masks"] = ("bits", delta.masks_packed)
-        live_delta = [bool(r.any()) for r in delta.masks_packed]
-    blob_np, segs = pack_blob(blob_arrays)
+        nets["d"] = (delta.route, delta.net_log2)
+    # said once per program: which Benes formulation the device will run
+    log.info("MXU program: Benes backend %s (net 2^%d, node net 2^%d, "
+             "route %s, delta net %s)",
+             "pallas" if isinstance(base.route, BenesPallasSpec)
+             else "rolls", base.net_log2, sig.node_net_log2,
+             route_dtype.name,
+             "none" if delta is None else f"2^{delta.net_log2}")
 
-    def _unblob(blob, name):
-        return unblob(blob, segs, name)
+    def _masks(blob, table, net, dv):
+        """One Benes net's masks out of its blob, as its route reads
+        them."""
+        route, net_log2 = nets[net]
+        if isinstance(route, BenesPallasSpec):
+            dv[f"pb_{net}_mid"] = unblob(blob, table, f"pb_{net}_mid")
+            if f"pb_{net}_out" in table:
+                dv[f"pb_{net}_out"] = unblob(blob, table, f"pb_{net}_out")
+        else:
+            dv[f"{net}_masks2"] = _unpack_mask_words(
+                unblob(blob, table, f"{net}_masks"), net_log2)
+
+    def _route(x2, dv, net):
+        route, net_log2 = nets[net]
+        if isinstance(route, BenesPallasSpec):
+            return benes_apply_pallas(x2, dv[f"pb_{net}_mid"],
+                                      dv.get(f"pb_{net}_out"), route)
+        return _benes_apply_rolls(x2, dv[f"{net}_masks2"], net_log2,
+                                  live_stages=route)
 
     @jax.jit
-    def prepare(blob):
+    def prepare(blob, dblob):
         """One compiled pass: slice, bitcast, unpack masks, build one-hots."""
         iota_sg = jnp.arange(SG_ROWS, dtype=jnp.int32)
         iota_kc = jnp.arange(K_C, dtype=jnp.int32)
         # keep int32 on device (ops/blob.py: whole 4-byte words only)
-        rowid = _unblob(blob, "rowid_i32")
-        run_k = _unblob(blob, "run_k_i32")
+        rowid = unblob(blob, segs, "rowid_i32")
+        run_k = unblob(blob, segs, "run_k_i32")
         oh = (rowid[:, :, None] == iota_sg[None, None, :]
               ).astype(jnp.float32)                        # (G, R_G, 128)
         ohe = ((run_k[:, :, None] == iota_kc[None, None, :])
                & (run_k[:, :, None] >= 0)).astype(route_dtype)
         dv = dict(
             oh=oh,
-            mult=_unblob(blob, "mult"),
-            valid=_unblob(blob, "valid"),
-            dangling=_unblob(blob, "dangling"),
+            mult=unblob(blob, segs, "mult"),
+            valid=unblob(blob, segs, "valid"),
+            dangling=(unblob(blob, segs, "dangling") if delta is None
+                      else unblob(dblob, d_segs, "dangling")),
             ohe=ohe,
-            win_oh=_unblob(blob, "win_oh"),
+            win_oh=unblob(blob, segs, "win_oh"),
         )
-        if use_pallas:
-            for name in ("pb_big_mid", "pb_big_out", "pb_node_mid",
-                         "pb_node_out"):
-                if name in segs:
-                    dv[name] = _unblob(blob, name)
-        else:
-            dv["masks2"] = _unpack_mask_words(_unblob(blob, "masks"),
-                                              plan.net_log2)
-            dv["node_masks2"] = _unpack_mask_words(
-                _unblob(blob, "node_masks"), plan.node_net_log2)
+        _masks(blob, segs, "big", dv)
+        _masks(blob, segs, "node", dv)
         if delta is not None:
-            d_rowid = _unblob(blob, "d_rowid_i32")
-            d_run_k = _unblob(blob, "d_run_k_i32")
+            d_rowid = unblob(dblob, d_segs, "d_rowid_i32")
+            d_run_k = unblob(dblob, d_segs, "d_run_k_i32")
             dv["d_oh"] = (d_rowid[:, :, None] == iota_sg[None, None, :]
                           ).astype(jnp.float32)
             dv["d_ohe"] = ((d_run_k[:, :, None] == iota_kc[None, None, :])
                            & (d_run_k[:, :, None] >= 0)).astype(route_dtype)
-            dv["d_mult"] = _unblob(blob, "d_mult")
-            dv["d_win_oh"] = _unblob(blob, "d_win_oh")
-            dv["d_scale"] = _unblob(blob, "d_scale")
-            if use_pallas_delta:
-                dv["pb_d_mid"] = _unblob(blob, "pb_d_mid")
-                if "pb_d_out" in segs:
-                    dv["pb_d_out"] = _unblob(blob, "pb_d_out")
-            else:
-                dv["d_masks2"] = _unpack_mask_words(
-                    _unblob(blob, "d_masks"), delta.net_log2)
+            dv["d_mult"] = unblob(dblob, d_segs, "d_mult")
+            dv["d_win_oh"] = unblob(dblob, d_segs, "d_win_oh")
+            dv["d_scale"] = unblob(dblob, d_segs, "d_scale")
+            _masks(dblob, d_segs, "d", dv)
         return dv
-
-    blob_dev = jax.device_put(blob_np)
-    # all-zero-mask stages route nothing: skip them at trace time
-    live_big = [bool(row.any()) for row in plan.masks_packed]
-    live_node = [bool(row.any()) for row in plan.node_masks_packed]
-
-    def _route_big(x2, dv):
-        if use_pallas:
-            from .benes_pallas import benes_apply_pallas
-            return benes_apply_pallas(x2, dv["pb_big_mid"],
-                                      dv.get("pb_big_out"), big_spec)
-        return _benes_apply_rolls(x2, dv["masks2"], plan.net_log2,
-                                  live_stages=live_big)
-
-    def _route_node(xa, dv):
-        if use_pallas:
-            from .benes_pallas import benes_apply_pallas
-            return benes_apply_pallas(xa, dv["pb_node_mid"],
-                                      dv.get("pb_node_out"), node_spec)
-        return _benes_apply_rolls(xa, dv["node_masks2"],
-                                  plan.node_net_log2,
-                                  live_stages=live_node)
-
-    def _route_delta(x2, dv):
-        if use_pallas_delta:
-            from .benes_pallas import benes_apply_pallas
-            return benes_apply_pallas(x2, dv["pb_d_mid"],
-                                      dv.get("pb_d_out"), d_spec)
-        return _benes_apply_rolls(x2, dv["d_masks2"], delta.net_log2,
-                                  live_stages=live_delta)
 
     def _delta_acc(rank_planes, dv):
         """Expand + route + extract the delta edges; (W, K_C, 128) f32."""
@@ -683,7 +762,7 @@ def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
         N_rows = max((1 << delta.net_log2) // LANES, 1)
         x2 = jnp.zeros((N_rows, LANES), route_dtype
                        ).at[:contrib.shape[0]].set(contrib)
-        x2 = _route_delta(x2, dv)
+        x2 = _route(x2, dv, "d")
         xc = x2[:delta.C * R_C].reshape(delta.C, R_C, LANES)
         per_chunk = jnp.einsum("cik,cil->ckl", dv["d_ohe"], xc,
                                preferred_element_type=jnp.float32)
@@ -704,8 +783,8 @@ def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
                                           ).reshape(-1, LANES)
         x2 = jnp.zeros((N_net // LANES, LANES), route_dtype
                        ).at[:contrib.shape[0]].set(contrib)
-        x2 = _route_big(x2, dv)
-        xc = x2[:C * R_C].reshape(C, R_C, LANES)
+        x2 = _route(x2, dv, "big")
+        xc = x2[:base.C * R_C].reshape(base.C, R_C, LANES)
         # full-run one-hot reduce+extract on the MXU (no roll-tree);
         # f32 accumulation regardless of the routed dtype
         per_chunk = jnp.einsum("cik,cil->ckl", dv["ohe"], xc,
@@ -718,7 +797,7 @@ def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
         acc_in2 = accw.reshape(-1, LANES)                  # (W*K_C, 128)
         xa = jnp.zeros((N_nn // LANES, LANES), jnp.float32
                        ).at[:acc_in2.shape[0]].set(acc_in2)
-        return _route_node(xa, dv).reshape(-1)[:node_flat]
+        return _route(xa, dv, "node").reshape(-1)[:node_flat]
 
     def _loop(x0, params, max_iterations, tol, dv):
         env = {"valid": dv["valid"], "dangling": dv["dangling"],
@@ -742,24 +821,93 @@ def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
     # prepare + loop fused into ONE jit call: the cold path is then a
     # single blob transfer + one compile-cached dispatch + one readback
     @partial(jax.jit, static_argnames=("max_iterations",))
-    def run_impl(blob, x0, params, max_iterations: int, tol):
-        return _loop(x0, params, max_iterations, tol, prepare(blob))
+    def run_impl(blob, x0, params, max_iterations: int, tol, dblob=None):
+        return _loop(x0, params, max_iterations, tol, prepare(blob, dblob))
 
     @partial(jax.jit, static_argnames=("max_iterations",))
-    def run_impl_default(blob, params, max_iterations: int, tol):
-        dv = prepare(blob)
+    def run_impl_default(blob, params, max_iterations: int, tol,
+                         dblob=None):
+        dv = prepare(blob, dblob)
         if x0_default == "zeros":
             x0 = jnp.zeros_like(dv["valid"])
         else:
             x0 = dv["valid"] * jnp.float32(1.0 / n_f)
         return _loop(x0, params, max_iterations, tol, dv)
 
+    return run_impl, run_impl_default
+
+
+def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
+                         delta: "DeltaPlan" = None,
+                         x0_default: str = "uniform"):
+    """Returns fn(x0_flat, params, max_iter, tol) ->
+    (x_flat, err, iters); state vectors are flat in OUT labeling,
+    length G*SG_ROWS*LANES.  The semiring-parameterized generalization
+    of the pagerank-only r5 kernel: the matvec (expand -> Benes route ->
+    MXU reduce/extract -> node relabel) is fixed ⊕ = sum machinery —
+    the one-hot extract matmul IS the sum — while the fused
+    ``epilogue(x, acc, env, params) -> (new_x, err)`` supplies the
+    algorithm (env carries valid / dangling / n_f; params is a dict of
+    traced scalars).  ⊗ is baked into the plan's multipliers
+    (build_plan(normalize=...)).
+
+    The jitted program is looked up by what is static (_Signature, in
+    the process-wide _PROGRAMS table); the arrays are its arguments.
+    The base plan's are packed and uploaded once per plan, a delta's per
+    kernel, so a CALL after a write costs the delta's upload and a
+    dispatch of a program that is already traced.
+
+    route_dtype: dtype for the per-edge contributions through the big
+    Benes (the dominant HBM traffic). bfloat16 halves it; sums still
+    accumulate in f32 on the MXU, so each contribution carries one
+    0.4%-relative rounding — validated to preserve exact top-100 order
+    on the 10M-edge bench graph. float32 is the exact path.
+
+    delta: optional DeltaPlan — per iteration the base expand reads
+    rank pre-scaled by delta.scale_out, the delta edges route through
+    their own (small) net, and both accumulators sum before the node
+    relabel. Exact for edge additions AND removals.
+
+    x0_default: the on-device start when x0 is None — "uniform"
+    (valid/n, pagerank) or "zeros" (katz)."""
+    import jax
+    import jax.numpy as jnp
+    from ..utils.jax_cache import ensure_compile_cache
+    ensure_compile_cache()
+
+    if route_dtype is None:
+        route_dtype = (jnp.bfloat16 if os.environ.get(
+            "MEMGRAPH_TPU_ROUTE_DTYPE", "f32") == "bf16" else jnp.float32)
+
+    # Benes backend: the pallas 3-pass formulation makes 3 HBM round
+    # trips where the roll path makes one per stage; the XLA roll path
+    # remains for CPU (tests / virtual meshes) and tiny nets
+    benes_mode = os.environ.get("MEMGRAPH_TPU_BENES", "auto")
+    use_pallas = (benes_mode == "pallas"
+                  or (benes_mode == "auto"
+                      and jax.default_backend() not in ("cpu",)
+                      and plan.net_log2 >= 12
+                      and plan.node_net_log2 >= 12))
+
+    resident = _resident_base(plan, use_pallas)
+    fresh = _upload_delta(delta, use_pallas) if delta is not None else None
+    run_impl, run_impl_default = _program(_Signature(
+        n_nodes=plan.n_nodes, G=plan.G, W=plan.W,
+        node_net_log2=plan.node_net_log2, node_route=resident.node_route,
+        base=resident.net, delta=fresh.net if fresh else None,
+        route_dtype=jnp.dtype(route_dtype).name, epilogue=epilogue,
+        x0_default=x0_default))
+    blob_dev = resident.blob
+    dblob_dev = fresh.blob if fresh else None
+
     def run(x0, params, max_iterations, tol):
         """x0 = None starts from the on-device default state (uniform
         distribution or zeros; saves the x0 host->device transfer)."""
         if x0 is None:
-            return run_impl_default(blob_dev, params, max_iterations, tol)
-        return run_impl(blob_dev, x0, params, max_iterations, tol)
+            return run_impl_default(blob_dev, params, max_iterations, tol,
+                                    dblob_dev)
+        return run_impl(blob_dev, x0, params, max_iterations, tol,
+                        dblob_dev)
 
     # mgxla contract-checker hooks: the inner jitted programs + the
     # device blob, so tools/mgxla can abstractly .lower() the compiled
@@ -768,6 +916,7 @@ def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
     run.jitted = run_impl
     run.jitted_default = run_impl_default
     run.blob = blob_dev
+    run.delta_blob = dblob_dev
     return run
 
 

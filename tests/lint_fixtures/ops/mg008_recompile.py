@@ -56,3 +56,52 @@ def unhashable(x, opts=[1, 2]):     # MG008 unhashable-static (line 52)
 @partial(jax.jit, static_argnames=("k",))
 def hashable_static_is_silent(x, k=3):
     return x * k
+
+
+# --- jit-per-object: the shape ops/spmv_mxu.make_semiring_kernel had ----
+
+def make_kernel(plan, delta=None):
+    scale = plan.scale
+
+    @jax.jit
+    def run_impl(blob):             # MG008 jit-per-object (line 67)
+        return blob * scale
+
+    return run_impl
+
+
+def kernel_of_snapshot(graph):
+    """Memoised, but on the snapshot: the next write brings a new one."""
+    cached = getattr(graph, "_state", None)
+    if cached is None:
+        cached = make_kernel(graph.plan, delta=graph.delta)
+        object.__setattr__(graph, "_state", cached)
+    return cached
+
+
+_PROGRAMS = {}
+
+
+def _build_program(sig):
+    @jax.jit
+    def run_impl(blob):             # keyed by a table: silent
+        return blob * sig[0]
+
+    return run_impl
+
+
+def _program(sig):
+    fn = _PROGRAMS.get(sig)
+    if fn is None:
+        fn = _PROGRAMS[sig] = _build_program(sig)
+    return fn
+
+
+def kernel_of_shapes(graph):
+    """The repair: the program by what is static, the arrays as
+    arguments; the snapshot keeps only its data."""
+    cached = getattr(graph, "_state", None)
+    if cached is None:
+        cached = (_program((graph.plan.scale,)), graph.blob)
+        object.__setattr__(graph, "_state", cached)
+    return cached

@@ -199,11 +199,29 @@ def test_mg008_fires_on_recompile_hazards_only():
     assert ("mg008_recompile.py", 19) in hits   # per-call jit
     assert ("mg008_recompile.py", 37) in hits   # traced branch
     assert ("mg008_recompile.py", 52) in hits   # unhashable static
-    # the cached builder, structural branches (is None / .ndim) and the
-    # hashable static stay silent; the suppressed rebuild counts
+    assert ("mg008_recompile.py", 67) in hits   # memo on a snapshot
+    # the cached builder, structural branches (is None / .ndim), the
+    # hashable static and the table-keyed program stay silent; the
+    # suppressed rebuild counts
     assert len([h for h in hits
-                if h[0] == "mg008_recompile.py"]) == 3, hits
+                if h[0] == "mg008_recompile.py"]) == 4, hits
     assert all(p == "mg008_recompile.py" for p, _l in hits), hits
+
+
+def test_mg008_tells_an_object_memo_from_a_keyed_table():
+    """jit-per-object: the shape ops/spmv_mxu.make_semiring_kernel had
+    before PR 27 (a closure jitted per kernel, memoised on the graph
+    snapshot) is a finding of its own kind; the same builder behind a
+    module-level table keyed by its statics is not."""
+    result = _run(["tests/lint_fixtures"], only={"MG008"})
+    kinds = {f.fingerprint for f in result.findings if f.rule == "MG008"}
+    assert "jit-per-object@make_kernel.run_impl" in kinds, kinds
+    assert not any("_build_program" in k for k in kinds), kinds
+    # and the package's own fixpoint builder goes through its table
+    package = _run(["memgraph_tpu"], only={"MG008"})
+    flagged = {f.symbol for f in package.findings
+               if f.path.endswith("ops/spmv_mxu.py")}
+    assert not flagged, flagged
 
 
 def test_mg009_fires_on_hot_path_syncs_only():

@@ -117,6 +117,37 @@ def test_mxu_pagerank_fixpoint_compiles(sds, mxu_plan, monkeypatch, route):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
 
 
+def test_mxu_delta_fixpoint_compiles(sds, mxu_plan, pokec, monkeypatch):
+    """The program a CALL after a write runs: the base blob and the
+    delta's blob as two arguments, the delta net (2^18 at the floors of
+    build_delta_plan) through its own Pallas passes with every stage
+    routed. A later burst of the same shapes compiles nothing."""
+    from memgraph_tpu.ops import spmv_mxu
+    monkeypatch.setenv("MEMGRAPH_TPU_BENES", "pallas")
+    rng = np.random.default_rng(3)
+    n = pokec["n"]
+
+    def kernel(edges):
+        delta = spmv_mxu.build_delta_plan(
+            mxu_plan, rng.integers(0, n, edges),
+            (rng.random(edges) ** 2 * n).astype(np.int64))
+        assert (delta.R_G, delta.C) == (spmv_mxu.SG_ROWS, 2 * mxu_plan.W)
+        return spmv_mxu.make_semiring_kernel(
+            mxu_plan, spmv_mxu.pagerank_mxu_epilogue, delta=delta)
+
+    run, later = kernel(64), kernel(640)
+    assert later.jitted_default is run.jitted_default
+    assert later.delta_blob.shape == run.delta_blob.shape
+    compiled = run.jitted_default.lower(
+        sds(run.blob.shape, run.blob.dtype),
+        {"damping": sds((), "float32")}, 100, sds((), "float32"),
+        sds(run.delta_blob.shape, run.delta_blob.dtype)).compile()
+    # the base's four passes, and the delta net's outer-down + middle +
+    # outer-up
+    assert _kernel_calls(compiled) >= 7
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
 @pytest.mark.parametrize("net_log2,dtype", [(21, "float32"),
                                             (24, "bfloat16")])
 def test_benes_pallas_compiles_alone(sds, net_log2, dtype):

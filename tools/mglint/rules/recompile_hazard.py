@@ -18,6 +18,16 @@ serving plane's latency budget (the static half of the
     ``getattr`` + ``object.__setattr__``); or the enclosing function
     being a builder that such a memo function calls / receives as an
     argument (``_pc_cached``, ``_FIXPOINT_CACHE``, plan caches).
+  * ``jit-per-object`` — the jit is memoised, but only on an OBJECT
+    (``getattr`` + ``object.__setattr__``, or a dict that hangs off
+    one): the jitted closure lives as long as that object, and the
+    objects of this plane are graph snapshots, replaced by every
+    committed write. So a CALL after a write re-traces and lowers the
+    whole program although nothing static changed (S15: 3.5-3.8 s of an
+    8 s CALL went there). A builder is safe when some path to it goes
+    through a keyed TABLE: a module-level (or ``self.``) dict read
+    with ``.get``/``setdefault`` and written by subscript, keyed by
+    what is static, with the arrays passed as arguments.
   * ``traced-branch`` — Python ``if``/``while``/ternary on a traced
     parameter of a jit root: either a trace-time concretization error,
     or (once someone "fixes" it by making the arg static) one compiled
@@ -59,24 +69,54 @@ def _enclosing_funcs(node: ast.AST):
         cur = getattr(cur, "_mglint_parent", None)
 
 
-def _has_memo_idiom(fn: ast.AST) -> bool:
-    """The get-then-build-then-store caching idiom."""
+def _module_names(tree: ast.Module) -> set[str]:
+    """Names bound at module level: where a keyed table lives."""
+    names: set[str] = set()
+    for stmt in tree.body:
+        targets = (stmt.targets if isinstance(stmt, ast.Assign)
+                   else [stmt.target] if isinstance(stmt, ast.AnnAssign)
+                   else [])
+        names.update(t.id for t in targets if isinstance(t, ast.Name))
+    return names
+
+
+def _is_table(node: ast.AST, tables: set[str]) -> bool:
+    """A container that outlives any one snapshot: a module-level name
+    or an attribute of ``self``."""
+    if isinstance(node, ast.Name):
+        return node.id in tables
+    return (isinstance(node, ast.Attribute)
+            and isinstance(node.value, ast.Name)
+            and node.value.id == "self")
+
+
+def _has_memo_idiom(fn: ast.AST, tables: set[str] | None = None) -> bool:
+    """The get-then-build-then-store caching idiom. With ``tables``
+    (the module-level names of fn's file) only a memo in a keyed table
+    counts: one that hangs off an object (``getattr`` +
+    ``object.__setattr__``, a local dict) does not."""
     has_get = has_store = has_getattr = has_setattr = False
     for node in ast.walk(fn):
         if isinstance(node, ast.Call):
             callee = dotted(node.func) or ""
             short = callee.split(".")[-1]
-            if short == "get" and isinstance(node.func, ast.Attribute):
+            keyed = isinstance(node.func, ast.Attribute) and (
+                tables is None or _is_table(node.func.value, tables))
+            if short == "get" and keyed:
                 has_get = True
-            if short == "setdefault":
+            if short == "setdefault" and keyed:
                 has_get = has_store = True
             if callee == "getattr":
                 has_getattr = True
             if callee == "object.__setattr__":
                 has_setattr = True
         if isinstance(node, ast.Assign):
-            if any(isinstance(t, ast.Subscript) for t in node.targets):
+            if any(isinstance(t, ast.Subscript)
+                   and (tables is None or _is_table(t.value, tables))
+                   for t in node.targets):
                 has_store = True
+    if tables is not None:
+        return has_get and has_store
     return (has_get and has_store) or (has_getattr and has_setattr)
 
 
@@ -97,20 +137,23 @@ def _stored_in_subscript(call: ast.Call) -> bool:
     return False
 
 
-def _collect_cached_builders(project: Project) -> set[str]:
+def _collect_cached_builders(project: Project,
+                             tables_only: bool = False) -> set[str]:
     """Names exempt from jit-per-call because a memo-idiom function
     calls them or receives them as call arguments (the builder half of
-    the get-then-build-then-store pattern), computed project-wide."""
+    the get-then-build-then-store pattern), computed project-wide.
+    ``tables_only``: count keyed-table memos alone (jit-per-object)."""
     memo_funcs: set[str] = set()
     infos = []          # (fn node, sf)
     for rel, sf in project.files.items():
         if not rel.endswith(".py"):
             continue
         sf.ensure_parents()
+        tables = _module_names(sf.tree) if tables_only else None
         for node in ast.walk(sf.tree):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
                 infos.append(node)
-                if _has_memo_idiom(node):
+                if _has_memo_idiom(node, tables):
                     memo_funcs.add(node.name)
     exempt: set[str] = set()
     for fn in infos:
@@ -142,10 +185,12 @@ def check(project: Project):
     """Per-call jit, traced-value branching, unhashable static args."""
     findings: list[Finding] = []
     cached_builders: set[str] | None = None
+    table_builders: set[str] | None = None
     for rel, sf in sorted(project.files.items()):
         if not _in_scope(rel):
             continue
         sf.ensure_parents()
+        tables = _module_names(sf.tree)
 
         # --- jit-per-call --------------------------------------------
         for node in ast.walk(sf.tree):
@@ -172,14 +217,33 @@ def check(project: Project):
                 hit_line = node.lineno
             if hit_line is None:
                 continue
-            if any(_has_memo_idiom(fn) for fn in builder_chain):
-                continue
-            if cached_builders is None:
-                cached_builders = _collect_cached_builders(project)
-            if any(fn.name in cached_builders for fn in builder_chain):
-                continue
             sym = qualname_of(node if isinstance(node, ast.FunctionDef)
                               else builder_chain[0])
+            if cached_builders is None:
+                cached_builders = _collect_cached_builders(project)
+            if any(_has_memo_idiom(fn) for fn in builder_chain) or any(
+                    fn.name in cached_builders for fn in builder_chain):
+                # memoised somewhere: in a keyed table, or on an object
+                # that the next write replaces?
+                if table_builders is None:
+                    table_builders = _collect_cached_builders(
+                        project, tables_only=True)
+                if any(_has_memo_idiom(fn, tables) for fn in builder_chain) \
+                        or any(fn.name in table_builders
+                               for fn in builder_chain):
+                    continue
+                findings.append(Finding(
+                    rule="MG008", path=rel, line=hit_line,
+                    col=getattr(node, "col_offset", 0), symbol=sym,
+                    message="jax.jit memoised only on an object (getattr/"
+                            "object.__setattr__ or a dict hanging off "
+                            "it): every new object — a graph snapshot "
+                            "after a write — re-traces and lowers the "
+                            "program; look the jitted fn up in a "
+                            "module-level table keyed by what is static "
+                            "and pass the arrays as arguments",
+                    fingerprint=f"jit-per-object@{sym}"))
+                continue
             findings.append(Finding(
                 rule="MG008", path=rel, line=hit_line,
                 col=getattr(node, "col_offset", 0), symbol=sym,
