@@ -14,11 +14,9 @@ the metric out of the line; it never returns 0 for a share.
 from __future__ import annotations
 
 import fnmatch
-import importlib.util
-import os
 import statistics
 
-HERE = os.path.dirname(os.path.abspath(__file__))
+import seams
 
 
 def _matching(ops: dict, patterns: list) -> dict:
@@ -62,10 +60,8 @@ def trace_roofline(params, ctx):
     iterations = sum(r["count"] for r in ticks.values())
     if not seconds or not iterations:
         return None
-    path = os.path.join(HERE, "rooflines", params["roofline"] + ".py")
-    spec = importlib.util.spec_from_file_location(params["roofline"], path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
+    module = seams.load_module(ctx.get("dirs"), "rooflines",
+                               params["roofline"])
     least = module.least_seconds(ctx["n_nodes"], ctx["n_edges"], iterations,
                                  ctx["peak"])
     return 100.0 * least["seconds"] / seconds
@@ -82,8 +78,9 @@ def stats_delta(params, ctx):
         return None
 
     def delta(names):
-        if isinstance(names, str):
-            return float(ctx.get(names) or 0)        # "cycles", "requests"
+        if isinstance(names, str):                   # "cycles", "requests"
+            value = ctx.get(names) or 0
+            return float(len(value) if isinstance(value, list) else value)
         return _counter_sum(after, names) - _counter_sum(before, names)
 
     bottom = delta(params["denominator"])

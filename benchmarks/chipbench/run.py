@@ -4,15 +4,16 @@
 
 This parent never imports jax. It reads the cell's files (deployment,
 traffic mix, per-layer metrics: all data, found by the names in
-BENCHMARK.json), makes the deployment's graph (its ``graph_seed``: one
-data set for every run) and the traffic (``--seed``), starts the one
-chip owner (``owner.py``: the program's Bolt server,
-untouched), refuses to go on unless that process reports a TPU with
-the cell's chip count, loads over Bolt, warms what the window will
-use, measures for ``--seconds``, stops the owner, and only then
-computes the plain reference (``reference.py``) and compares. The last
-line of stdout is the result; without a chip there is none and the
-exit code is not 0.
+BENCHMARK.json) and the three modules those files name (``seams_of``:
+the deployment's owner layout and data set, the mix's semantics), makes
+the deployment's data (its ``graph_seed``: one data set for every run)
+and the traffic (``--seed``), has the layout start the deployment's
+processes, refuses to go on unless the one that holds the chip reports
+a TPU with the cell's chip count, loads over Bolt, warms what the
+window will use, measures for ``--seconds``, stops the processes, and
+only then computes the plain reference (the semantics on the data
+set's state) and compares. The last line of stdout is the result;
+without a chip there is none and the exit code is not 0.
 
 ``--trace 0`` reports the cell's end-to-end metrics with no profiler
 started. ``--trace 1`` has the owner trace a short slice at the start
@@ -30,13 +31,11 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import signal  # noqa: E402
-import socket  # noqa: E402
 import statistics  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import threading  # noqa: E402
-import urllib.request  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -47,19 +46,16 @@ for _p in (HERE, REPO):
         sys.path.insert(0, _p)
 
 import layers  # noqa: E402
-import reference  # noqa: E402
+import procs  # noqa: E402
+import seams  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
+from procs import RunFailure, connect, flat_stats, say, stop_all  # noqa: E402,F401
 
 MASTER_TIMEOUT_S = 350          # the driver allows a run 360
 TRACE_STOP_TIMEOUT_S = 120
 
-
-class RunFailure(Exception):
-    """The run cannot give a result (no chip, a child that died, ...)."""
-
-
-def say(msg: str) -> None:
-    print(msg, flush=True)
+_CHILDREN = procs.CHILDREN      # what the layouts have started
+_free_port = procs.free_port
 
 
 # --------------------------------------------------------------------------
@@ -71,9 +67,11 @@ def _load_json(*parts):
         return json.load(f)
 
 
-def load_cell(workload: str) -> dict:
-    """Everything BENCHMARK.json and the data files say about one cell."""
-    bench = _load_json(REPO, "BENCHMARK.json")
+def load_cell(workload: str, root: str = REPO) -> dict:
+    """Everything BENCHMARK.json and the data files say about one cell.
+    `root` holds the BENCHMARK.json; its first `paths` entry is the
+    benchmark's directory, searched before this one."""
+    bench = _load_json(root, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise RunFailure(f"BENCHMARK.json has no workload {workload!r}; "
@@ -81,6 +79,16 @@ def load_cell(workload: str) -> dict:
     cell = cells[workload]
     config_entry = next(c for c in bench["configs"]
                         if c["name"] == cell["config"])
+    dirs = list(dict.fromkeys(
+        [os.path.abspath(os.path.join(root, bench["paths"][0])), HERE]))
+
+    def data(sub, name, needed=True):
+        path = seams.find(dirs, sub, name, ".json")
+        if path is None:
+            if needed:
+                raise RunFailure(f"no {sub}/{name}.json under {dirs}")
+            return None
+        return _load_json(path)
 
     def of_cell(metric):
         return workload in metric.get("workloads", [workload])
@@ -88,147 +96,36 @@ def load_cell(workload: str) -> dict:
     layer_metrics = []
     for metric in bench["per_layer"]:
         if of_cell(metric):
-            spec = _load_json(HERE, "layer_metrics", metric["name"] + ".json")
-            layer_metrics.append(dict(spec, name=metric["name"],
-                                      unit=metric["unit"]))
-    limits_path = os.path.join(HERE, "cells", workload + ".json")
+            layer_metrics.append(dict(
+                data("layer_metrics", metric["name"]), name=metric["name"],
+                unit=metric["unit"]))
     return {
         "name": workload,
         "chips": cell["chips"],
-        "config": _load_json(REPO, config_entry["file"]),
-        "mix": _load_json(HERE, "traffic", cell["traffic"] + ".json"),
+        "dirs": dirs,
+        "config": _load_json(root, config_entry["file"]),
+        "mix": data("traffic", cell["traffic"]),
         "end_to_end": [m for m in bench["end_to_end"] if of_cell(m)],
         "per_layer": layer_metrics,
-        "limits": _load_json(limits_path)["limits"]
-        if os.path.exists(limits_path) else {},
+        "limits": (data("cells", workload, needed=False)
+                   or {"limits": {}})["limits"],
     }
 
 
-# --------------------------------------------------------------------------
-# the chip owner (copied from chip_smoke.py: _spawn, _connect, _stop)
-# --------------------------------------------------------------------------
-
-_CHILDREN: list = []
-
-
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
-def _tail(path: str, n: int = 3000) -> str:
+def seams_of(cell: dict):
+    """(owner layout, data set, semantics): the three modules that the
+    deployment's and the mix's files name, each ``<kind>/<name>.py`` of
+    the benchmark's directory. A file that names none gets today's."""
+    config, mix = cell["config"], cell["mix"]
+    names = {"owners": config.get("owner", {}).get("kind"),
+             "datasets": config.get("dataset"),
+             "semantics": mix.get("semantics")}
     try:
-        with open(path, "rb") as f:
-            f.seek(0, os.SEEK_END)
-            f.seek(max(0, f.tell() - n))
-            return f.read().decode(errors="replace")
-    except OSError:
-        return ""
-
-
-def spawn_owner(workdir: str, bolt: int, metrics: int, config: dict,
-                extra_env: dict | None = None):
-    """Start the one chip owner. Its environment is the one given (JAX
-    picks its default backend; the compile cache goes where
-    JAX_COMPILATION_CACHE_DIR says, else <checkout>/.jax_cache), minus
-    the switch that would route analytics to a daemon."""
-    env = dict(os.environ)
-    env.pop("MEMGRAPH_TPU_ANALYTICS_KERNEL_SERVER", None)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.update(config["owner"].get("env", {}))
-    env.update(extra_env or {})
-    ctl = os.path.join(workdir, "ctl")
-    os.makedirs(ctl, exist_ok=True)
-    args = [sys.executable, os.path.join(HERE, "owner.py"), "--ctl", ctl,
-            "--", "--bolt-port", str(bolt), "--metrics-port", str(metrics),
-            "--data-directory", os.path.join(workdir, "data")] \
-        + list(config["owner"]["server_flags"])
-    with open(os.path.join(workdir, "owner.log"), "ab") as log:
-        p = subprocess.Popen(args, cwd=REPO, env=env, stdout=log,
-                             stderr=subprocess.STDOUT,
-                             start_new_session=True)
-    _CHILDREN.append(p)
-    return p
-
-
-def connect(port: int, child, timeout_s: float = 180.0):
-    from memgraph_tpu.server.client import BoltClient
-    deadline = time.monotonic() + timeout_s
-    while True:
-        try:
-            return BoltClient(port=port, timeout=900.0)
-        except OSError:
-            if child.poll() is not None or time.monotonic() > deadline:
-                raise RunFailure("the Bolt server did not come up")
-            time.sleep(0.1)
-
-
-def stop_child(p, grace_s: float = 60.0) -> int:
-    """SIGTERM, wait, then kill the group; returns the exit code."""
-    if p.poll() is None:
-        p.terminate()
-        try:
-            p.wait(grace_s)
-        except subprocess.TimeoutExpired:
-            pass
-    try:
-        os.killpg(p.pid, signal.SIGKILL)    # stragglers of the group
-    except (ProcessLookupError, PermissionError):
-        pass
-    rc = p.wait(30)
-    if p in _CHILDREN:
-        _CHILDREN.remove(p)
-    return rc
-
-
-def stop_all() -> None:
-    for p in list(_CHILDREN):
-        try:
-            stop_child(p, grace_s=5.0)
-        except (OSError, subprocess.TimeoutExpired):
-            pass
-
-
-class Owner:
-    """The parent's side of owner.py's request files."""
-
-    def __init__(self, workdir: str, child):
-        self.ctl = os.path.join(workdir, "ctl")
-        self.child = child
-        self.seq = 0
-
-    def ask(self, op: str, timeout_s: float = 60.0, **fields) -> dict:
-        self.seq += 1
-        tmp = os.path.join(self.ctl, ".req.tmp")
-        with open(tmp, "w") as f:
-            json.dump(dict(fields, seq=self.seq, op=op), f)
-        os.replace(tmp, os.path.join(self.ctl, "req.json"))
-        ack_path = os.path.join(self.ctl, f"ack_{self.seq}.json")
-        deadline = time.monotonic() + timeout_s
-        while not os.path.exists(ack_path):
-            if self.child.poll() is not None:
-                raise RunFailure(f"the owner exited before answering {op}")
-            if time.monotonic() > deadline:
-                raise RunFailure(f"the owner did not answer {op} "
-                                 f"within {timeout_s:.0f} s")
-            time.sleep(0.005)
-        with open(ack_path) as f:
-            ack = json.load(f)
-        if "error" in ack:
-            raise RunFailure(f"the owner could not {op}: {ack['error']}")
-        return ack
-
-
-def build_info(client) -> dict:
-    _, rows, _ = client.execute("SHOW BUILD INFO")
-    return {k: v for k, v in rows}
-
-
-def device_of(info: dict) -> dict:
-    return {"platform": info.get("device_platform"),
-            "kind": info.get("device_kind"),
-            "count": info.get("device_count")}
+        return tuple(seams.load_module(
+            cell.get("dirs"), sub, names[sub] or seams.DEFAULTS[sub])
+            for sub in ("owners", "datasets", "semantics"))
+    except LookupError as e:
+        raise RunFailure(str(e)) from e
 
 
 def require_tpu(device: dict, chips: int) -> None:
@@ -238,59 +135,22 @@ def require_tpu(device: dict, chips: int) -> None:
                          f"reports {device}")
 
 
-def flat_stats(metrics_port: int) -> dict:
-    """GET /stats, flattened to {"section/.../name": number}."""
-    with urllib.request.urlopen(
-            f"http://127.0.0.1:{metrics_port}/stats", timeout=60) as r:
-        stats = json.load(r)
-    flat: dict = {}
-
-    def walk(prefix, node):
-        if isinstance(node, dict):
-            for key, value in node.items():
-                walk(f"{prefix}/{key}" if prefix else key, value)
-        elif isinstance(node, (int, float)) and not isinstance(node, bool):
-            flat[prefix] = float(node)
-
-    for section in ("device", "delta", "lane", "ppr"):
-        walk(section, stats.get(section, {}))
-    return flat
-
-
 # --------------------------------------------------------------------------
-# set-up: load, warm
+# set-up: warm (the load is the data set's)
 # --------------------------------------------------------------------------
-
-def load_graph(client, config: dict, src, dst) -> float:
-    """Index, then UNWIND batches on one connection, as the smoke loads.
-    Returns the seconds it took."""
-    load, n_nodes = config["load"], config["nodes"]
-    batch = int(load["batch"])
-    t0 = time.perf_counter()
-    client.execute(config["index"])
-    for start in range(0, n_nodes, batch):
-        client.execute(load["nodes_query"],
-                       {"ids": list(range(start,
-                                          min(start + batch, n_nodes)))})
-    pairs = np.stack([src, dst], axis=1)
-    for start in range(0, len(pairs), batch):
-        client.execute(load["edges_query"],
-                       {"pairs": pairs[start:start + batch].tolist()})
-    return time.perf_counter() - t0
-
 
 def per_cycle_of(mix: dict) -> int:
     """Requests in one cycle: a pass over a sequence, else one request."""
     return len(mix["classes"]) if mix["schedule"] == "sequence" else 1
 
 
-def apply_acknowledged(state, requests) -> None:
+def apply_acknowledged(sem, state, requests) -> None:
     for req in requests:
         if req.cls["kind"] == "write" and req.ok:
-            state.apply(req.cls["reference"], req.params)
+            sem.apply(req.cls["reference"], state, req.params)
 
 
-def warm_up(mix: dict, plan, transport, state) -> list:
+def warm_up(mix: dict, plan, transport, state, sem) -> list:
     """Every shape the window will use, once, on the first connection.
     The reference's state follows the writes."""
     done = []
@@ -304,7 +164,7 @@ def warm_up(mix: dict, plan, transport, state) -> list:
         if not req.ok:
             raise RunFailure(f"warm-up request {req.name} failed: "
                              f"{req.error}")
-    apply_acknowledged(state, done)
+    apply_acknowledged(sem, state, done)
     return done
 
 
@@ -354,24 +214,30 @@ def run_window(mix: dict, plans, transports, seconds: float, owner,
 # after the window: read back, then compare with the reference
 # --------------------------------------------------------------------------
 
-def added_pairs(state) -> list:
-    return sorted({(a, b) for a, b in state.added})
+def mode_of(sem, cls: dict) -> str:
+    """How a class's responses are held to the reference: its semantics'
+    word for the name the class gives as its `reference`."""
+    try:
+        return sem.MODES[cls["reference"]]
+    except KeyError:
+        raise RunFailure(f"class {cls['name']!r}: the semantics have no "
+                         f"{cls['reference']!r}; they have "
+                         f"{sorted(sem.MODES)}") from None
 
 
-def read_back(mix: dict, client, plan, final_state) -> dict:
+def read_back(mix: dict, client, plan, final_state, sem) -> dict:
     """Quiesced, after the window: the rows the comparison will hold
     against the reference's final state. Only collected here; the
     reference runs once the owner is gone."""
     got = {"readback": {}, "quiesced": []}
     for item in mix.get("readback", []):
-        params = {}
-        if item.get("params") == "added_pairs":
-            params = {"pairs": [list(p) for p in added_pairs(final_state)]}
+        params = sem.readback_params(item["params"], final_state) \
+            if item.get("params") else {}
         _, rows, _ = client.execute(item["query"], params)
         got["readback"][item["name"]] = rows
     per_class = int(mix.get("quiesced_reads", {}).get("per_class", 0))
     for cls in mix["classes"]:
-        if cls["kind"] != "read" or cls["reference"] == "pagerank_top":
+        if mode_of(sem, cls) != "between":
             continue
         for _ in range(per_class if cls["params"] else min(per_class, 1)):
             req = plan.request(cls["name"])
@@ -380,24 +246,13 @@ def read_back(mix: dict, client, plan, final_state) -> dict:
     return got
 
 
-def reference_readback(name: str, state) -> list:
-    if name == "age_rows":
-        return state.age_rows()
-    if name == "out_degree_rows":
-        return state.out_degree_rows()
-    if name == "added_edge_rows":
-        pairs = added_pairs(state)
-        if not pairs:
-            return []
-        src, dst = state.edge_arrays()
-        big = int(max(src.max(), dst.max())) + 1
-        codes = src * big + dst
-        wanted = np.asarray([a * big + b for a, b in pairs], dtype=np.int64)
-        hit = codes[np.isin(codes, wanted)]
-        uniq, counts = np.unique(hit, return_counts=True)
-        return [[int(c // big), int(c % big), int(n)]
-                for c, n in zip(uniq, counts)]
-    raise ValueError(f"no read-back reference named {name!r}")
+def reference_readback(name: str, state, sem=None) -> list:
+    """The rows a read-back item should give on `state`, by the given
+    semantics or the default ones."""
+    if sem is None:
+        sem = seams.load_module(None, "semantics",
+                                seams.DEFAULTS["semantics"])
+    return sem.readback(name, state)
 
 
 def compare_ranks(rows, want: np.ndarray, top: int) -> dict:
@@ -422,52 +277,90 @@ def compare_ranks(rows, want: np.ndarray, top: int) -> dict:
             "gap": float(max(0.0, (cut - ref.min()) / cut))}
 
 
-def compare(mix: dict, state0, final, window: list,
-            collected: dict) -> dict:
+def modes_of(mix: dict, sem) -> dict:
+    """{class name: mode}. The modes that hold a read to the state as
+    of that request need a mix of one client, or one that never writes."""
+    modes = {c["name"]: mode_of(sem, c) for c in mix["classes"]}
+    ordered = sorted(n for n, m in modes.items()
+                     if m in ("exact_in_order", "vector_top"))
+    if ordered and int(mix["clients"]) != 1 and "write" in modes.values():
+        raise RunFailure(f"classes {ordered} are held to the state as of "
+                         f"each request, which under writes only one "
+                         f"client's order gives; the mix has "
+                         f"{mix['clients']} clients")
+    return modes
+
+
+def compare(mix: dict, state0, final, window: list, collected: dict,
+            sem) -> dict:
     """The numbers that decide `correct`, each to be held to its limit.
 
     `window` is every request of the window, by client; `state0` and
-    `final` are the reference's state before and after it. With one client
-    the order is known and every read has one right answer; with more,
-    a read of the window is held between the window's first and last
-    state, and exactness is for the quiesced reads after it."""
+    `final` are the reference's state before and after it; `sem` gives
+    each class's answer and the mode it is held by. With one client
+    the order is known and every read has one right answer
+    (`exact_in_order`, `vector_top`); with more, a read of the window is
+    held between the window's first and last state, and exactness is for
+    the quiesced reads after it (`between`); where no class writes,
+    every client's reads are held to the one state there is."""
     numbers: dict = {}
-    exact = len(window) == 1
+    modes = modes_of(mix, sem)
 
-    if exact and any(c["reference"] == "pagerank_top"
-                     for c in mix["classes"]):
-        # every CALL of the window, against the reference for the graph
-        # with every burst acknowledged before it, and against the one
-        # without the last of them: a CALL at least as near to that one
-        # has not seen its write
+    if {"exact_in_order", "vector_top"} & set(modes.values()):
+        # the one client's requests in order (every client's, where
+        # nothing writes), the state following every acknowledged
+        # write. A vector read is held against the
+        # reference for the state as of that read, and against the one
+        # for the state before the writes since the last vector read: a
+        # read at least as near to that one has not seen its write
         worst = {"fault": 0, "rel_err": 0.0, "gap": 0.0}
-        compared = stale = 0
+        compared = stale = exact_compared = exact_wrong = 0
         stale_sep = float("inf")
-        state = state0.copy()
-        rank, _ = reference.pagerank(*state.edge_arrays(), state.n_loaded)
-        without = None
-        for req in window[0]:
-            if req.cls["kind"] == "write":
+        state, version = state0.copy(), 0
+        before = None           # (state, version) ahead of those writes
+        newest: dict = {}       # vector key -> (version, vector)
+
+        def vector_at(key, req, at_state, at_version):
+            held = newest.get(key)
+            if held is None or held[0] != at_version:
+                held = (at_version, sem.vector(
+                    req.cls["reference"], at_state, req.params,
+                    x0=None if held is None else held[1]))
+                newest[key] = held
+            return held[1]
+
+        for req in (r for out in window for r in out):
+            mode = modes[req.name]
+            if mode == "write":
                 if req.ok:
-                    apply_acknowledged(state, [req])
-                    without = rank
+                    if before is None:
+                        before = (state.copy(), version)
+                    apply_acknowledged(sem, state, [req])
+                    version += 1
                 continue
-            if not req.ok or req.cls["reference"] != "pagerank_top":
+            if not req.ok:
                 continue
-            if without is rank:         # a burst since the last CALL
-                rank, _ = reference.pagerank(*state.edge_arrays(),
-                                             state.n_loaded, x0=rank)
-            top = int(req.cls["top"])
-            one = compare_ranks(req.rows, rank, top)
-            worst = {k: max(worst[k], one[k]) if k != "fault"
-                     else worst[k] + one[k] for k in worst}
-            compared += 1
-            if without is not None and not one["fault"]:
-                old = compare_ranks(req.rows, without, top)
-                sep = max(old["rel_err"], old["gap"])
-                stale += sep <= max(one["rel_err"], one["gap"])
-                stale_sep = min(stale_sep, sep)
-            without = None
+            if mode == "exact_in_order":
+                exact_compared += 1
+                exact_wrong += req.rows != sem.answer(
+                    req.cls["reference"], state, req.params)
+            elif mode == "vector_top":
+                key = (req.cls["reference"],
+                       json.dumps(req.params, sort_keys=True))
+                without = None if before is None \
+                    else vector_at(key, req, *before)
+                rank = vector_at(key, req, state, version)
+                top = int(req.cls["top"])
+                one = compare_ranks(req.rows, rank, top)
+                worst = {k: max(worst[k], one[k]) if k != "fault"
+                         else worst[k] + one[k] for k in worst}
+                compared += 1
+                if without is not None and not one["fault"]:
+                    old = compare_ranks(req.rows, without, top)
+                    sep = max(old["rel_err"], old["gap"])
+                    stale += sep <= max(one["rel_err"], one["gap"])
+                    stale_sep = min(stale_sep, sep)
+                before = None
         if compared:
             # one number: right values for the ids returned, and the
             # right ids; the parts are shown beside it
@@ -478,29 +371,30 @@ def compare(mix: dict, state0, final, window: list,
             numbers["stale_calls"] = stale
             numbers["_stale_sep_min"] = stale_sep
             numbers["_rank_calls_compared"] = compared
+        if exact_compared:
+            numbers["exact_mismatches"] = exact_wrong
+            numbers["_exact_reads_compared"] = exact_compared
 
-    if any(c["kind"] == "read" and c["reference"] != "pagerank_top"
-           for c in mix["classes"]):
+    if "between" in modes.values():
         outside = 0
         bounds: dict = {}
         for out in window:
             for req in out:
-                if req.cls["kind"] != "read" or not req.ok \
-                        or req.cls["reference"] == "pagerank_top":
+                if modes[req.name] != "between" or not req.ok:
                     continue
                 key = (req.name, tuple(sorted(req.params.items())))
                 if key not in bounds:
-                    bounds[key] = reference.read_bounds(
+                    bounds[key] = sem.bounds(
                         req.cls["reference"], req.params, state0, final)
-                outside += not reference.within(req.rows, *bounds[key])
+                outside += not sem.within(req.rows, *bounds[key])
         numbers["reads_out_of_bounds"] = outside
         numbers["quiesced_mismatches"] = sum(
-            req.rows != getattr(final, req.cls["reference"])(req.params)
+            req.rows != sem.answer(req.cls["reference"], final, req.params)
             for req in collected["quiesced"])
 
     mismatches = 0
     for item in mix.get("readback", []):
-        want = reference_readback(item["reference"], final)
+        want = sem.readback(item["reference"], final)
         got = [list(r) for r in collected["readback"][item["name"]]]
         if got != want:
             as_set = {tuple(r) for r in got}
@@ -563,31 +457,34 @@ def completed_cycles(reqs: list, per_cycle: int) -> int:
 
 
 def reduce_trace(trace_dir: str, workdir: str) -> dict | None:
-    """trace_reduce.py in a process of its own, held to the CPU, after
-    the chip owner has exited."""
+    """gap_spans.py in a process of its own, held to the CPU, after the
+    chip's holder has exited: trace_reduce.py's summary of the device
+    planes, and under "gaps" the host spans beneath their idle gaps."""
     out_path = os.path.join(workdir, "trace_summary.json")
+    gaps_path = os.path.join(workdir, "trace_gaps.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
-        [sys.executable, os.path.join(HERE, "trace_reduce.py"), trace_dir,
-         out_path], env=env, cwd=REPO, capture_output=True, text=True,
-        timeout=200)
+        [sys.executable, os.path.join(HERE, "gap_spans.py"), trace_dir,
+         gaps_path, out_path], env=env, cwd=REPO, capture_output=True,
+        text=True, timeout=200)
     if proc.returncode != 0:
-        say(f"trace_reduce failed: {proc.stderr[-2000:]}")
+        say(f"gap_spans failed: {proc.stderr[-2000:]}")
         return None
-    return _load_json(out_path)
+    return dict(_load_json(out_path), gaps=_load_json(gaps_path)["gaps"])
 
 
 def breakdown_of(trace: dict) -> dict:
+    """The ten device ops that took most time, and the ten longest
+    stretches of the idle gaps by the program's span over them (a gap
+    is split among the innermost spans; `unattributed` is what no span
+    covers)."""
     ops = sorted(trace["ops"].items(), key=lambda kv: -kv[1]["seconds"])
-    gaps = []
-    for plane in trace["planes"].values():
-        gaps.extend(plane["idle_gaps"])
-    gaps.sort(key=lambda g: -g[1])
-    # the device's clock and the client's are not aligned in this PR:
-    # a gap is not attributed to the request in flight
+    parts = [[name, seconds] for gap in trace["gaps"]
+             for name, seconds in gap["parts"]]
+    parts.sort(key=lambda part: -part[1])
     return {"device_ops": [[name, row["seconds"]] for name, row in ops[:10]],
-            "idle_gaps": [["unattributed", dur] for _, dur in gaps[:10]]}
+            "idle_gaps": parts[:10]}
 
 
 # --------------------------------------------------------------------------
@@ -605,10 +502,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     `t_start`, which is the start of this process."""
     from memgraph_tpu.server.client import BoltClientError
     config, mix = cell["config"], cell["mix"]
-    n_nodes, n_edges = int(config["nodes"]), int(config["edges"])
-    say(f"cell {cell['name']}: {config['name']} ({n_nodes:,} / "
-        f"{n_edges:,}) under {mix['name']}, seed {seed}, "
-        f"{seconds:g} s, trace {int(trace)}"
+    layout, dataset, sem = seams_of(cell)
+    modes_of(mix, sem)          # a mix its semantics cannot hold: now
+    say(f"cell {cell['name']}: {config['name']} under {mix['name']}, "
+        f"seed {seed}, {seconds:g} s, trace {int(trace)}"
         + (f", CONTROL {control}" if control else ""))
 
     extra_env, drop_every = {}, 0
@@ -623,75 +520,72 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         if spec.get("harness") == "drop_every_nth_write":
             drop_every = int(spec["n"])
 
-    # the dataset is one graph, as Pokec is one file: every seed shares
-    # it (and so the shapes of the programs compiled for it), and draws
+    # the data set is one, as Pokec is one file: every seed shares it
+    # (and so the shapes of the programs compiled for it), and draws
     # its own traffic
-    src, dst = reference.make_graph(int(config["graph_seed"]), n_nodes,
-                                    n_edges)
-    state0 = reference.GraphState(n_nodes, src, dst)
-    keys = traffic_mod.Keys(mix["keys"], n_nodes, seed) \
+    state0 = dataset.make(config)
+    say(f"data set: {dataset.sizes(state0)}")
+    n_ids = dataset.key_space(config)
+    keys = traffic_mod.Keys(mix["keys"], n_ids, seed) \
         if "keys" in mix else None
-    plans = [traffic_mod.Plan(mix, n_nodes, seed, i, keys)
+    plans = [traffic_mod.Plan(mix, n_ids, seed, i, keys, dataset)
              for i in range(int(mix["clients"]))]
 
-    bolt, metrics_port = _free_port(), _free_port()
-    child = spawn_owner(workdir, bolt, metrics_port, config, extra_env)
-    owner = Owner(workdir, child)
+    owner = layout.start(config, cell["chips"], workdir, extra_env)
     clients = []
     try:
-        clients.append(connect(bolt, child))
-        info = build_info(clients[0])
-        device = device_of(info)
-        say(f"SHOW BUILD INFO: {info}")
+        clients.append(connect(owner.port(0), owner.alive))
+        device = owner.device(clients[0])
         device_check(device, cell["chips"])
 
-        load_s = load_graph(clients[0], config, src, dst)
+        load_s, records = dataset.load(clients[0], config, state0)
         say(f"loaded over Bolt in {load_s:.3f} s: "
-            f"{(n_nodes + n_edges) / load_s:,.0f} records/s")
-        for _ in plans[1:]:
-            clients.append(connect(bolt, child))
+            f"{records / load_s:,.0f} records/s")
+        for i in range(1, len(plans)):
+            clients.append(connect(owner.port(i), owner.alive))
         transports = [traffic_mod.Transport(
             c, int(mix.get("retries", 0)), BoltClientError, drop_every)
             for c in clients]
         if transport_hook is not None:
             transports = [transport_hook(t) for t in transports]
         t_warm = time.perf_counter()
-        warm = warm_up(mix, plans[0], transports[0], state0)
+        warm = warm_up(mix, plans[0], transports[0], state0, sem)
         for t in transports[1:]:
             t.client.execute("RETURN 1")
         say(f"warmed {len(warm)} requests in "
             f"{time.perf_counter() - t_warm:.3f} s: "
             + ", ".join(f"{r.name} {r.end - r.start:.3f}" for r in warm[:12]))
 
-        stats_before = flat_stats(metrics_port)
+        stats_before = owner.stats()
         trace_dir = os.path.join(workdir, "trace") if trace else None
         setup_s = time.perf_counter() - t_start
         t0, outs, trace_info = run_window(mix, plans, transports, seconds,
                                           owner, trace_dir)
         t_end = time.perf_counter()
-        stats_after = flat_stats(metrics_port)
+        stats_after = owner.stats()
         memory = owner.ask("memory")
         # quiesced: the window's clients have all returned
         final_state = state0.copy()
         for out in outs:
-            apply_acknowledged(final_state, out)
-        check_plan = traffic_mod.Plan(mix, n_nodes, seed, len(plans), keys)
-        collected = read_back(mix, clients[0], check_plan, final_state)
+            apply_acknowledged(sem, final_state, out)
+        check_plan = traffic_mod.Plan(mix, n_ids, seed, len(plans), keys,
+                                      dataset)
+        collected = read_back(mix, clients[0], check_plan, final_state, sem)
         t_readback = time.perf_counter()
     except RunFailure:
         raise
     except Exception as e:
         raise RunFailure(
             f"{type(e).__name__}: {e}\n--- owner log ---\n"
-            f"{_tail(os.path.join(workdir, 'owner.log'))}") from e
+            f"{owner.log_tail()}") from e
     finally:
         for c in clients:
             try:
                 c.close()
             except OSError:
                 pass
-        rc = stop_child(child)
-        say(f"owner exited with code {rc}")
+        say(f"owner exited with code "
+            f"{', '.join(str(rc) for rc in owner.stop())}")
 
     # the window has closed, the peak is read, the program is gone:
     # now the reference
@@ -701,7 +595,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     cycles = completed_cycles(outs[0], per_cycle) \
         if mix["schedule"] == "sequence" else sum(r.ok for r in reqs)
     t_ref = time.perf_counter()
-    numbers = compare(mix, state0, final_state, outs, collected)
+    numbers = compare(mix, state0, final_state, outs, collected, sem)
     rows, correct = judge(numbers, mix, cell["limits"])
     ref_s = time.perf_counter() - t_ref
 
@@ -715,9 +609,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                          f"{device['kind']!r}")
     ctx = {
         "requests": reqs, "t0": t0, "seconds": seconds, "setup_s": setup_s,
-        "load_s": load_s, "records": n_nodes + n_edges, "cycles": cycles,
-        "per_cycle": per_cycle, "n_nodes": n_nodes,
-        "n_edges": n_edges + len(final_state.added),
+        "load_s": load_s, "records": records, "cycles": cycles,
+        "per_cycle": per_cycle, "dirs": cell.get("dirs"),
+        **dataset.sizes(final_state),
         "stats_before": stats_before, "stats_after": stats_after,
         "trace": trace_summary, "peak": peaks.get(device["kind"]),
         "trace_window_s": (trace_info or {}).get("window_s"),
@@ -731,6 +625,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         f"{sum(r.tries > 1 for r in reqs)} retried, "
         f"{sum(not r.ok for r in reqs)} failed; read-back "
         f"{t_readback - t_end:.3f} s; reference {ref_s:.3f} s")
+    for r in [r for r in reqs if not r.ok][:5]:
+        say(f"  failed {r.name} (client {r.client}, {r.tries} tries): "
+            f"{r.error}")
     for name, times in sorted(by_class.items()):
         say(f"  {name:<18} n {len(times):>6}  p50 "
             f"{1000 * statistics.median(times):9.3f} ms  max "

@@ -52,7 +52,11 @@ def short_name(name: str) -> str:
 
 def extract(xplane_path: str) -> dict:
     from jax.profiler import ProfileData
-    data = ProfileData.from_file(xplane_path)
+    return planes_of(ProfileData.from_file(xplane_path))
+
+
+def planes_of(data) -> dict:
+    """The device planes of a ProfileData that has been read."""
     planes: dict = {}
     stand_in: list = []
     for plane in data.planes:

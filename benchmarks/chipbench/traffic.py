@@ -7,8 +7,10 @@ its next class (``weighted``: every block of sum-of-shares requests
 holds each class exactly ``share`` times, shuffled by the seed, so every
 seed offers the same work in another order; ``sequence``: the classes
 in order, one pass being one cycle), the key distribution, and for
-each class its Cypher text, what draws each parameter, and the name of
-its semantics in ``reference.py``.
+each class its Cypher text, what draws each parameter (a generator of
+the deployment's data set, ``datasets/<name>.py`` ``GENERATORS``, or
+one of the three here), and the name of its semantics
+(``semantics/<name>.py``).
 """
 
 from __future__ import annotations
@@ -68,8 +70,9 @@ class Plan:
     """One client's requests, drawn from (seed, client index) alone."""
 
     def __init__(self, mix: dict, n_ids: int, seed: int, client: int,
-                 keys: Keys | None):
+                 keys: Keys | None, dataset=None):
         self.mix, self.n_ids, self.client, self.keys = mix, n_ids, client, keys
+        self.generators = getattr(dataset, "GENERATORS", {})
         self.rng = np.random.default_rng([seed, 1 + client])
         self.by_name = {c["name"]: c for c in mix["classes"]}
         self._new_ids = 0
@@ -77,6 +80,8 @@ class Plan:
 
     def _param(self, spec: dict):
         gen = spec["gen"]
+        if gen in self.generators:
+            return self.generators[gen](self, spec)
         if gen == "key":
             return self.keys.draw(self.rng)
         if gen == "new_id":

@@ -563,6 +563,10 @@ def test_stats_delta_and_client_class_readers():
     assert layers.client_class({"classes": ["a"]}, ctx) \
         == pytest.approx(3.0)
     assert layers.client_class({"classes": ["b"]}, ctx) is None
+    # "requests" is the count of the window's requests, not the list
+    ctx = {"stats_before": before, "stats_after": after, "requests": reqs}
+    assert layers.stats_delta({"numerator": ["device/jit.compile_total"],
+                               "denominator": "requests"}, ctx) == 0.8
 
 
 def test_roofline_and_peaks():
@@ -650,3 +654,27 @@ def test_benchmark_json_is_well_formed():
         for name, spec in cell["mix"]["compare"].items():
             assert cell["limits"].get(name, spec.get("limit")) is not None, \
                 (w["name"], name)
+        # the deployment's owner layout and data set, the mix's
+        # semantics and every generator a class names are files that
+        # are there; every class has an answer and a mode
+        layout, dataset, sem = run.seams_of(cell)
+        assert callable(layout.start)
+        for needed in ("make", "load", "key_space", "sizes"):
+            assert callable(getattr(dataset, needed)), needed
+        for cls in cell["mix"]["classes"]:
+            mode = run.mode_of(sem, cls)
+            assert mode in ("write", "exact_in_order", "between",
+                            "vector_top"), (cls["name"], mode)
+            assert (mode == "write") == (cls["kind"] == "write")
+            assert callable({"write": sem.apply, "vector_top": getattr(
+                sem, "vector", None)}.get(mode, sem.answer))
+            if mode == "between":
+                assert callable(sem.bounds) and callable(sem.within)
+            for spec in cls["params"].values():
+                assert spec["gen"] in dataset.GENERATORS or \
+                    spec["gen"] in ("key", "new_id", "edge_burst"), spec
+        for item in cell["mix"].get("readback", []):
+            assert callable(sem.readback)
+            if item.get("params"):
+                assert callable(sem.readback_params)
+        assert run.modes_of(cell["mix"], sem)
