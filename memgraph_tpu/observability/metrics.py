@@ -128,7 +128,8 @@ STAT_NAMES = (
     # phase spans (mgtrace PHASES): seconds and closes of every phase,
     # armed or not — span.<name>.seconds_total / span.<name>.count
     "span.*",
-    # in-process device fixpoints (ops/pagerank.py): iterations run
+    # device fixpoints (ops/pagerank.py; the partition-centric PageRank
+    # of parallel/distributed.py, the daemon's): iterations run
     "device.fixpoint_iterations_total",
     # compiled Cypher read lane (r20, mglane)
     "lane.compiled_total",          # lane programs compiled (per shape)

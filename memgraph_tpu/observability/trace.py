@@ -113,6 +113,8 @@ SPAN_NAMES = (
     "lane.iterate",        # program call + readback: ends in a block
     "kernel.request",      # client->kernel-server round trip
     "kernel.dispatch",     # server-side supervised dispatch
+    "kernel.generation",   # its resident generation: delta decode, splice
+    "analytics.route_meta",  # change log -> what a routed CALL sends
     "device.transfer",     # partition-centric blocking + device_put
     "device.chunk",        # one compiled chunk of device iterations
     "device.route",        # one mesh/streamed dispatch (chunks inside)
@@ -158,6 +160,10 @@ PHASES = {
     "lane.compile": ("lane_compile",),
     "lane.dispatch": ("lane_dispatch",),
     "lane.iterate": ("lane_iterate",),
+    "kernel.request": (),
+    "kernel.dispatch": (),
+    "kernel.generation": (),
+    "analytics.route_meta": (),
     "device.transfer": ("device_transfer",),
     "device.chunk": ("device_iterate", "semiring_{backend}"),
     "device.route": ("semiring_{backend}",),

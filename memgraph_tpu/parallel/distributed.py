@@ -617,6 +617,8 @@ def pagerank_partition_centric(scsr: ShardedCSR, ctx: MeshContext,
         carry0=carry0, iter_index=3, max_iterations=max_iterations,
         checkpoint_every=checkpoint_every, job=job, store=store,
         retry=retry, chunk_deadline_s=chunk_deadline_s, report=report)
+    global_metrics.increment("device.fixpoint_iterations_total",
+                             int(iters))
     return rank[:scsr.n_nodes], float(err[0]), int(iters)
 
 

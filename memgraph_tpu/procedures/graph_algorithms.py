@@ -110,43 +110,44 @@ def _serving_delta_meta(ctx, graph, sock: str, graph_key: str):
     re-shipped. ``send_graph`` says whether the edge arrays must ride
     along (server behind with no usable delta, or never fed)."""
     from ..ops.delta import incident_edges
-    storage = ctx.storage
-    version = getattr(ctx.accessor, "topology_snapshot",
-                      storage.topology_version)
-    meta = {"graph_key": graph_key, "graph_version": version,
-            "base_version": None, "ids_stable": True,
-            "send_graph": True}
-    with _PPR_PUSHED_LOCK:
-        prev = _PPR_PUSHED.get((sock, graph_key))
-    if prev is None:
+    with mgtrace.span("analytics.route_meta"):
+        storage = ctx.storage
+        version = getattr(ctx.accessor, "topology_snapshot",
+                          storage.topology_version)
+        meta = {"graph_key": graph_key, "graph_version": version,
+                "base_version": None, "ids_stable": True,
+                "send_graph": True}
+        with _PPR_PUSHED_LOCK:
+            prev = _PPR_PUSHED.get((sock, graph_key))
+        if prev is None:
+            return meta
+        prev_version, prev_gids = prev
+        ids_stable = prev_gids is graph.node_gids or \
+            np.array_equal(prev_gids, graph.node_gids)
+        meta["ids_stable"] = ids_stable
+        if not ids_stable:
+            return meta
+        if prev_version == version:
+            meta["send_graph"] = False
+            meta["base_version"] = version
+            return meta
+        if prev_version < version and graph.host_coo is not None:
+            gids = storage.changes_between(prev_version, version)
+            # typed wrap verdict (ChangeLogUnknowable) → full re-ship: the
+            # gap is unreconstructable and a partial delta would corrupt
+            # the resident generation
+            if isinstance(gids, frozenset):
+                changed_idx = [graph.gid_to_idx[g] for g in gids
+                               if g in graph.gid_to_idx]
+                bitmap = np.zeros(graph.n_nodes, dtype=bool)
+                if changed_idx:
+                    bitmap[np.asarray(changed_idx, dtype=np.int64)] = True
+                inc_src, inc_dst, inc_w = incident_edges(
+                    *graph.host_coo, bitmap)
+                meta.update(base_version=prev_version, changed=changed_idx,
+                            inc_src=inc_src, inc_dst=inc_dst, inc_w=inc_w,
+                            send_graph=False)
         return meta
-    prev_version, prev_gids = prev
-    ids_stable = prev_gids is graph.node_gids or \
-        np.array_equal(prev_gids, graph.node_gids)
-    meta["ids_stable"] = ids_stable
-    if not ids_stable:
-        return meta
-    if prev_version == version:
-        meta["send_graph"] = False
-        meta["base_version"] = version
-        return meta
-    if prev_version < version and graph.host_coo is not None:
-        gids = storage.changes_between(prev_version, version)
-        # typed wrap verdict (ChangeLogUnknowable) → full re-ship: the
-        # gap is unreconstructable and a partial delta would corrupt
-        # the resident generation
-        if isinstance(gids, frozenset):
-            changed_idx = [graph.gid_to_idx[g] for g in gids
-                           if g in graph.gid_to_idx]
-            bitmap = np.zeros(graph.n_nodes, dtype=bool)
-            if changed_idx:
-                bitmap[np.asarray(changed_idx, dtype=np.int64)] = True
-            inc_src, inc_dst, inc_w = incident_edges(
-                *graph.host_coo, bitmap)
-            meta.update(base_version=prev_version, changed=changed_idx,
-                        inc_src=inc_src, inc_dst=inc_dst, inc_w=inc_w,
-                        send_graph=False)
-    return meta
 
 
 def _drop_pushed(sock: str, graph_key: str) -> None:

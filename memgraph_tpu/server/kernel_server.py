@@ -1068,8 +1068,12 @@ class KernelServer:
             return time.monotonic() - self._last_activity
 
     def _warm(self) -> None:
-        """Touch the device so the first client request pays no init."""
+        """Touch the device so the first client request pays no init.
+        The compile cache and its witness (``jit.*``) are switched on
+        first: no op on the pagerank path does it for this process."""
+        from ..utils.jax_cache import ensure_compile_cache
         from ..utils.sanitize import shared_write
+        ensure_compile_cache()
         _, platform = probe_device()
         with self._stats_lock:
             shared_write(self, "_platform")
@@ -1400,7 +1404,8 @@ class KernelServer:
                     in global_metrics.snapshot()
                     if name.startswith(("kernel_server.", "analytics.",
                                         "ppr.", "delta.", "lane.",
-                                        "tier."))}
+                                        "tier.", "span.", "jit.", "mxu.",
+                                        "device."))}
         return {"ok": True, "pid": os.getpid(),
                 "uptime_s": round(now - self._started, 3),
                 "in_flight": len(entries),
@@ -1466,59 +1471,61 @@ class KernelServer:
         from ..ops import delta as mgdelta
         from ..ops.csr import from_coo
         from ..utils.sanitize import shared_write
-        key = header.get("graph_key")
-        want = header.get("graph_version")
-        # mglint: disable=MG006 — the dispatcher (_supervised worker) holds _dispatch_lock across this whole handler; intraprocedural analysis cannot see caller locks
-        gen = self._graphs.pop(key, None) if key else None
-        if gen is not None:
-            self._graphs[key] = gen            # re-insert: LRU refresh
-        if gen is not None and want is not None \
-                and int(want) > gen.version:
-            base = header.get("base_version")
-            applied = False
-            if header.get("has_delta") \
-                    and header.get("ids_stable", True) \
-                    and base is not None and int(base) == gen.version \
-                    and "changed" in arrays and "inc_src" in arrays:
-                d = mgdelta.diff_incident(
-                    gen.coo, arrays["changed"],
-                    arrays["inc_src"], arrays["inc_dst"],
-                    arrays.get("inc_w"), gen.n_nodes,
-                    int(base), int(want))
-                applied = gen.apply(d)
-                if applied:
-                    # the spliced edge set resizes the generation's
-                    # modeled footprint even though the LRU is unchanged
+        with mgtrace.span("kernel.generation"):
+            key = header.get("graph_key")
+            want = header.get("graph_version")
+            # mglint: disable=MG006 — the dispatcher (_supervised worker) holds _dispatch_lock across this whole handler; intraprocedural analysis cannot see caller locks
+            gen = self._graphs.pop(key, None) if key else None
+            if gen is not None:
+                self._graphs[key] = gen            # re-insert: LRU refresh
+            if gen is not None and want is not None \
+                    and int(want) > gen.version:
+                base = header.get("base_version")
+                applied = False
+                if header.get("has_delta") \
+                        and header.get("ids_stable", True) \
+                        and base is not None and int(base) == gen.version \
+                        and "changed" in arrays and "inc_src" in arrays:
+                    d = mgdelta.diff_incident(
+                        gen.coo, arrays["changed"],
+                        arrays["inc_src"], arrays["inc_dst"],
+                        arrays.get("inc_w"), gen.n_nodes,
+                        int(base), int(want))
+                    applied = gen.apply(d)
+                    if applied:
+                        # the spliced edge set resizes the generation's
+                        # modeled footprint though the LRU is unchanged
+                        self._update_memory_gauge()
+                if not applied:
+                    # stale resident and no usable delta: a full
+                    # re-import (below) is the only honest path — serving
+                    # the old generation would return pre-commit results
+                    # as fresh
+                    self._graphs.pop(key, None)  # mglint: disable=MG006,MG007 — under caller's _dispatch_lock
+                    gen = None
                     self._update_memory_gauge()
-            if not applied:
-                # stale resident and no usable delta: a full re-import
-                # (below) is the only honest path — serving the old
-                # generation would return pre-commit results as fresh
-                self._graphs.pop(key, None)  # mglint: disable=MG006,MG007 — under caller's _dispatch_lock
-                gen = None
-                self._update_memory_gauge()
-        if gen is None:
-            if "src" not in arrays:
-                return None
-            g = from_coo(arrays["src"].astype(np.int64),
-                         arrays["dst"].astype(np.int64),
-                         arrays.get("weights"),
-                         n_nodes=header.get("n_nodes"))
-            if place:
-                g = g.to_device()
-            gen = mgdelta.ResidentGraph(key, int(want or 0), g)
-            if key:
-                # mglint: disable=MG006,MG007 — same _dispatch_lock contract as above: the LRU insert+evict runs under the dispatcher's lock
-                self._graphs[key] = gen
-                while len(self._graphs) > self.MAX_CACHED_GRAPHS:  # mglint: disable=MG006 — under caller's _dispatch_lock
-                    self._graphs.pop(next(iter(self._graphs)))  # mglint: disable=MG006,MG007 — under caller's _dispatch_lock
-                global_metrics.set_gauge("delta.resident_generations",
-                                         float(len(self._graphs)))  # mglint: disable=MG006 — len snapshot under caller's _dispatch_lock
-                self._update_memory_gauge()
-                with self._stats_lock:
-                    shared_write(self, "_graphs_cached")
-                    self._graphs_cached = len(self._graphs)  # mglint: disable=MG006 — len snapshot for health; insert path holds _dispatch_lock
-        return gen
+            if gen is None:
+                if "src" not in arrays:
+                    return None
+                g = from_coo(arrays["src"].astype(np.int64),
+                             arrays["dst"].astype(np.int64),
+                             arrays.get("weights"),
+                             n_nodes=header.get("n_nodes"))
+                if place:
+                    g = g.to_device()
+                gen = mgdelta.ResidentGraph(key, int(want or 0), g)
+                if key:
+                    # mglint: disable=MG006,MG007 — same _dispatch_lock contract as above: the LRU insert+evict runs under the dispatcher's lock
+                    self._graphs[key] = gen
+                    while len(self._graphs) > self.MAX_CACHED_GRAPHS:  # mglint: disable=MG006 — under caller's _dispatch_lock
+                        self._graphs.pop(next(iter(self._graphs)))  # mglint: disable=MG006,MG007 — under caller's _dispatch_lock
+                    global_metrics.set_gauge("delta.resident_generations",
+                                             float(len(self._graphs)))  # mglint: disable=MG006 — len snapshot under caller's _dispatch_lock
+                    self._update_memory_gauge()
+                    with self._stats_lock:
+                        shared_write(self, "_graphs_cached")
+                        self._graphs_cached = len(self._graphs)  # mglint: disable=MG006 — len snapshot for health; insert path holds _dispatch_lock
+            return gen
 
     def _resolve_graph(self, header, arrays):
         """Back-compat DeviceGraph view of :meth:`_resolve_generation`
