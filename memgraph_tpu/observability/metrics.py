@@ -67,6 +67,9 @@ STAT_NAMES = (
     "operator.*",                  # per-operator completion counters
     "storage.*",                   # per-query write-stat counters
     "mgstat.evictions_total",      # space-saving top-K evictions
+    # ORDER BY, by the operator that ran it (plan/operators.py)
+    "query.topk_total",            # a TopK cursor ran: ORDER BY … LIMIT
+    "query.sort_full_total",       # an OrderBy sorted its whole input
     # bolt session pool
     "bolt.prepare_latency_sec",
     "bolt.connections_rejected_total",

@@ -91,7 +91,8 @@ SPAN_NAMES = (
     "query.plan",          # AST -> operator tree (cache-aware)
     "query.execute",       # stream drain: first pull -> exhaustion
     "query.commit",        # autocommit finalization (interpreter side)
-    "query.sort",          # ORDER BY's sort of the rows it collected
+    "query.sort",          # ORDER BY's sort: of every row (OrderBy), or
+    #                        of the rows its bounded selection held (TopK)
     "mvcc.begin",          # storage transaction begin
     "mvcc.commit",         # storage engine commit of a writing txn
     #                        (durability: WAL append/fsync, + repl)

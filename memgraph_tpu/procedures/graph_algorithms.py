@@ -43,7 +43,8 @@ def _rank_results(ctx, graph, values, field_name):
     """One row per vertex. Two phases, recorded once when the generator
     ends: ``analytics.rows``, the time spent in here making the rows,
     and ``analytics.consume``, the time the plan's operators above the
-    CALL took between two rows (Produce's expressions, OrderBy)."""
+    CALL took between two rows (a TopK's selection on the sort keys;
+    without a LIMIT, Produce's expressions and OrderBy's collecting)."""
     started = time.time()
     inside = outside = 0.0
     t0 = time.perf_counter()

@@ -302,6 +302,7 @@ class LaneHopCount(Op.LogicalOperator):
     where every aggregation is a path/row count — lowered to a masked
     frontier SpMV chain with the self-loop edge-uniqueness correction
     (count(DISTINCT target) is the reachability popcount epilogue)."""
+    reads_only = True
     input: Op.LogicalOperator            # Once
     fallback: Op.LogicalOperator         # the original Aggregate subplan
     source: tuple                        # ("label", l) | ("all",) |

@@ -10,6 +10,7 @@ incrementally — the same contract as the reference's Cursor::Pull
 
 from __future__ import annotations
 
+import heapq
 import itertools
 from dataclasses import dataclass, field
 from typing import Optional
@@ -17,6 +18,7 @@ from typing import Optional
 from ...exceptions import (HintedAbortError, QueryException, SemanticException,
                            TypeException)
 from ...observability import trace as mgtrace
+from ...observability.metrics import global_metrics
 from ...storage.common import View
 from ...storage.objects import Vertex
 from ...storage.ordering import order_key
@@ -76,9 +78,19 @@ class ExecutionContext:
         return self.accessor.storage
 
 
+#: every attribute that may hold a child operator (kept in sync with
+#: profile_rows' walk and the planner's tree shapes)
+CHILD_ATTRS = ("input", "subplan", "match_plan", "create_plan",
+               "update_plan", "left", "right")
+
+
 class LogicalOperator:
     """Base: single-input operators hold `input` (no default here — a base
     class attribute would leak a dataclass default into every subclass)."""
+
+    #: True on an operator whose cursor writes nothing to the graph
+    #: (planner._reads_only; a CALL answers by its procedure)
+    reads_only = False
 
     def cursor(self, ctx: ExecutionContext):
         raise NotImplementedError
@@ -92,6 +104,7 @@ class LogicalOperator:
 
 
 class Once(LogicalOperator):
+    reads_only = True
     input = None
 
     def cursor(self, ctx):
@@ -122,6 +135,7 @@ class Eager(LogicalOperator):
 
 @dataclass
 class ScanAll(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     symbol: str
 
@@ -136,6 +150,7 @@ class ScanAll(LogicalOperator):
 
 @dataclass
 class ScanAllByLabel(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     symbol: str
     label: str
@@ -154,6 +169,7 @@ class ScanAllByLabel(LogicalOperator):
 
 @dataclass
 class ScanAllByLabelPropertyValue(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     symbol: str
     label: str
@@ -181,6 +197,7 @@ class ScanAllByLabelPropertyValue(LogicalOperator):
 
 @dataclass
 class ScanAllByLabelPropertyRange(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     symbol: str
     label: str
@@ -215,6 +232,7 @@ class ScanAllByLabelPropertyRange(LogicalOperator):
 
 @dataclass
 class ScanAllById(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     symbol: str
     id_expr: A.Expr
@@ -256,6 +274,7 @@ class Expand(LogicalOperator):
     edge symbols of the same MATCH for relationship-uniqueness filtering
     (reference: EdgeUniquenessFilter, plan/operator.hpp).
     """
+    reads_only = True
     input: LogicalOperator
     from_symbol: str
     edge_symbol: str
@@ -348,6 +367,7 @@ class ExpandVariable(LogicalOperator):
     Binds edge_symbol to the list of edges. Counterpart of the reference's
     ExpandVariable (plan/operator.hpp:1140).
     """
+    reads_only = True
     input: LogicalOperator
     from_symbol: str
     edge_symbol: str
@@ -462,6 +482,7 @@ class ExpandShortest(LogicalOperator):
     point-query regime); whole-graph distances run on device via
     ops/traversal.py.
     """
+    reads_only = True
     input: LogicalOperator
     from_symbol: str
     edge_symbol: str
@@ -632,6 +653,7 @@ class ExpandShortest(LogicalOperator):
 class ExpandKShortest(LogicalOperator):
     """*KSHORTEST: Yen's algorithm over the Dijkstra base (reference:
     the KSHORTEST mode of ExpandVariable). Requires a bound target."""
+    reads_only = True
     input: LogicalOperator
     from_symbol: str
     edge_symbol: str
@@ -754,6 +776,7 @@ def _chain_edges(edge_list, start_node):
 @dataclass
 class ConstructNamedPath(LogicalOperator):
     """Bind a path variable from matched pattern symbols."""
+    reads_only = True
     input: LogicalOperator
     path_symbol: str
     element_symbols: list[str]   # node, edge, node, edge, ...
@@ -796,6 +819,7 @@ class ConstructNamedPath(LogicalOperator):
 
 @dataclass
 class Filter(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     expr: A.Expr
 
@@ -808,6 +832,7 @@ class Filter(LogicalOperator):
 
 @dataclass
 class Produce(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     items: list[tuple[A.Expr, str]]   # (expr, output name)
 
@@ -1082,6 +1107,7 @@ class Delete(LogicalOperator):
 
 @dataclass
 class SetHopsLimit(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     limit: int
 
@@ -1119,6 +1145,7 @@ def _run_subplan(subplan: LogicalOperator, ctx, frame) -> list:
 @dataclass
 class Optional_(LogicalOperator):
     """OPTIONAL MATCH: run subplan per input row; null-fill on no match."""
+    reads_only = True
     input: LogicalOperator
     subplan: LogicalOperator
     optional_symbols: list[str]
@@ -1168,6 +1195,7 @@ AGGREGATE_FUNCTIONS = {"count", "sum", "avg", "min", "max", "collect",
 class Aggregate(LogicalOperator):
     """Hash aggregation. group_by: (expr, name); aggregations:
     (kind, expr|None, distinct, output name)."""
+    reads_only = True
     input: LogicalOperator
     group_by: list[tuple[A.Expr, str]]
     aggregations: list[tuple[str, Optional[A.Expr], bool, str]]
@@ -1345,6 +1373,7 @@ class _AggState:
 
 @dataclass
 class OrderBy(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     items: list[tuple[A.Expr, bool]]   # (expr, ascending)
 
@@ -1371,36 +1400,203 @@ class OrderBy(LogicalOperator):
 
         # a phase: the sort runs after the input is exhausted, so no
         # producer's span (a CALL's row generator) holds it
+        global_metrics.increment("query.sort_full_total")
         with mgtrace.span("query.sort"):
             rows.sort(key=functools.cmp_to_key(compare))
         for _, frame in rows:
             yield frame
 
 
+def _skip_count(ctx, expr) -> int:
+    n = ctx.evaluator.eval(expr, {})
+    if not isinstance(n, int) or isinstance(n, bool) or n < 0:
+        raise TypeException("SKIP must be a non-negative integer")
+    return n
+
+
+def _limit_count(ctx, expr) -> int:
+    n = ctx.evaluator.eval(expr, {})
+    if not isinstance(n, int) or isinstance(n, bool):
+        raise TypeException("LIMIT must be a non-negative integer")
+    # negative literals fail at compile time; a negative PARAMETER
+    # "should not generate errors" (TCK OrderByAcceptance) — clamp
+    return max(n, 0)
+
+
+def _order(keys, other_keys, ascending) -> int:
+    """Negative where ``keys`` sort before ``other_keys`` under the sort
+    items' directions, positive where after, 0 on a tie."""
+    for ka, kb, asc in zip(keys, other_keys, ascending):
+        if ka < kb:
+            return -1 if asc else 1
+        if ka > kb:
+            return 1 if asc else -1
+    return 0
+
+
+class _Held:
+    """One row a TopK holds. Ordered for ``heapq`` so that the row
+    which sorts LAST is the root; ``seq`` (arrival) breaks ties, which
+    is what keeps the result that of a stable sort."""
+    __slots__ = ("keys", "ascending", "seq", "frame", "eager")
+
+    def __init__(self, keys, ascending, seq, frame, eager):
+        self.keys = keys
+        self.ascending = ascending
+        self.seq = seq
+        self.frame = frame
+        self.eager = eager
+
+    def __lt__(self, other):
+        return (_order(self.keys, other.keys, self.ascending)
+                or self.seq - other.seq) > 0
+
+
+#: what a property lookup reads without raising (None apart):
+#: Evaluator.get_property's first three cases
+_PROPERTY_BASES = (dict, VertexAccessor, EdgeAccessor)
+_NOT_GIVEN = object()
+
+
+def topk_slot(name: str) -> str:
+    """The frame key under which a TopK's sort items find the value of
+    the projected column ``name``: no variable's, and no column's."""
+    return f"__topk_{name}__"
+
+
+@dataclass
+class TopK(LogicalOperator):
+    """``ORDER BY … [SKIP s] LIMIT k`` in one operator: row for row what
+    ``Limit(Skip(OrderBy(input)))`` yields, selected with a heap of
+    ``s + k`` rows on the sort keys alone (a list until it holds that
+    many). A row that cannot enter is dropped where it stands: nothing
+    of it is kept or accounted.
+
+    With ``projection`` set the operator is the RETURN/WITH's ``Produce``
+    too (planner.topk_rewrite): ``items`` are then read against the
+    INPUT frame, and ``out``/``__row__`` are built for the survivors
+    only, all of them before the first leaves. Each entry is ``(expr, name, slot)``. ``slot`` None: the item
+    is deferred, because it cannot raise on a row that is dropped (an
+    identifier, a literal, a parameter, or a property of one whose
+    value is null, a map, a vertex or an edge: looked at per row, and
+    evaluated there and then where it is anything else). Otherwise the
+    item is evaluated for every row as Produce does, and ``items`` read
+    its value from the frame under the private name ``slot``."""
+    reads_only = True
+    input: LogicalOperator
+    items: list[tuple[A.Expr, bool]]    # (expr, ascending)
+    limit: A.Expr
+    skip: Optional[A.Expr] = None
+    projection: Optional[list[tuple[A.Expr, str, Optional[str]]]] = None
+
+    def cursor(self, ctx):
+        global_metrics.increment("query.topk_total")
+        evaluate = ctx.evaluator.eval
+        limit = _limit_count(ctx, self.limit)
+        if limit == 0:
+            # islice(…, 0) never starts its input: Limit(Skip(…)) reads
+            # neither SKIP nor a row
+            return
+        skip = 0 if self.skip is None else _skip_count(ctx, self.skip)
+        bound = skip + limit
+        exprs = [expr for expr, _ in self.items]
+        ascending = tuple(asc for _, asc in self.items)
+        projection = self._projection_of_this_run(ctx)
+        eager = [(expr, slot) for expr, _, slot in projection
+                 if slot is not None]
+        guarded = [(expr, expr.expr.name) for expr, _, slot in projection
+                   if slot is None and isinstance(expr, A.PropertyLookup)
+                   and isinstance(expr.expr, A.Identifier)]
+        held: list[_Held] = []
+        heaped = False      # from the bound-th row on ``held`` is a heap
+        for seq, frame in enumerate(self.input.cursor(ctx)):
+            ctx.check_abort()
+            for expr, base in guarded:
+                value = frame.get(base)
+                if value is not None and not isinstance(
+                        value, _PROPERTY_BASES):
+                    evaluate(expr, frame)       # raises what Produce did
+            scope, values = frame, None
+            if eager:
+                values = {slot: evaluate(expr, frame)
+                          for expr, slot in eager}
+                scope = {**frame, **values}
+            keys = [order_key(evaluate(expr, scope)) for expr in exprs]
+            if not heaped:
+                # input that never reaches the bound costs an append a
+                # row and the one sort below, as OrderBy does
+                ctx.memory.add_value(frame)
+                held.append(_Held(keys, ascending, seq, frame, values))
+                if len(held) == bound:
+                    heapq.heapify(held)
+                    heaped = True
+            elif _order(keys, held[0].keys, ascending) < 0:
+                # (a tie stays out: it came later)
+                ctx.memory.add_value(frame)
+                heapq.heapreplace(
+                    held, _Held(keys, ascending, seq, frame, values))
+        # the phase OrderBy's sort closes, here over the rows held
+        with mgtrace.span("query.sort"):
+            held.sort(reverse=True)
+        del held[:skip]
+        if self.projection is None:
+            for survivor in held:
+                yield survivor.frame
+            return
+        # every survivor's items before the first row leaves, as OrderBy
+        # drained Produce: a writer above reads no deferred item late
+        rows = []
+        for survivor in held:
+            out = dict(survivor.frame)
+            row = {}
+            for expr, name, slot in projection:
+                value = evaluate(expr, survivor.frame) if slot is None \
+                    else survivor.eager[slot]
+                row[name] = value
+                out[name] = value
+            out["__row__"] = row
+            rows.append(out)
+        yield from rows
+
+    def _projection_of_this_run(self, ctx):
+        """``projection`` with the parameters known. A deferred item
+        that reads a parameter the query was not given, or a property
+        of one that has none, raised on the first row: it is evaluated
+        for every row after all (under a slot nothing reads)."""
+        projection = []
+        for expr, name, slot in self.projection or ():
+            lookup = isinstance(expr, A.PropertyLookup)
+            leaf = expr.expr if lookup else expr
+            if slot is None and isinstance(leaf, A.Parameter):
+                value = ctx.parameters.get(leaf.name, _NOT_GIVEN)
+                if value is _NOT_GIVEN or (
+                        lookup and value is not None
+                        and not isinstance(value, _PROPERTY_BASES)):
+                    slot = topk_slot(name)
+            projection.append((expr, name, slot))
+        return projection
+
+
 @dataclass
 class Skip(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     expr: A.Expr
 
     def cursor(self, ctx):
-        n = ctx.evaluator.eval(self.expr, {})
-        if not isinstance(n, int) or isinstance(n, bool) or n < 0:
-            raise TypeException("SKIP must be a non-negative integer")
+        n = _skip_count(ctx, self.expr)
         yield from itertools.islice(self.input.cursor(ctx), n, None)
 
 
 @dataclass
 class Limit(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     expr: A.Expr
 
     def cursor(self, ctx):
-        n = ctx.evaluator.eval(self.expr, {})
-        if not isinstance(n, int) or isinstance(n, bool):
-            raise TypeException("LIMIT must be a non-negative integer")
-        # negative literals fail at compile time; a negative PARAMETER
-        # "should not generate errors" (TCK OrderByAcceptance) — clamp
-        yield from itertools.islice(self.input.cursor(ctx), max(n, 0))
+        n = _limit_count(ctx, self.expr)
+        yield from itertools.islice(self.input.cursor(ctx), n)
 
 
 @dataclass
@@ -1408,6 +1604,7 @@ class ScopeBarrier(LogicalOperator):
     """WITH scope close: prune frames to the projected columns so stale
     pre-WITH bindings never leak into later clauses (reference: symbol
     table scoping in semantic/symbol_generator.cpp)."""
+    reads_only = True
     input: LogicalOperator
     columns: list[str]
 
@@ -1419,6 +1616,7 @@ class ScopeBarrier(LogicalOperator):
 
 @dataclass
 class Distinct(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     symbols: list[str]
 
@@ -1435,6 +1633,7 @@ class Distinct(LogicalOperator):
 
 @dataclass
 class Unwind(LogicalOperator):
+    reads_only = True
     input: LogicalOperator
     expr: A.Expr
     symbol: str
@@ -1563,6 +1762,7 @@ class Apply(LogicalOperator):
     must not carry graph values (their accessors die with the committed
     transaction); the operator enforces this with a clear error.
     """
+    reads_only = True
     input: LogicalOperator
     subplan: LogicalOperator
     columns: list[str]
@@ -1631,6 +1831,7 @@ class Apply(LogicalOperator):
 
 @dataclass
 class Union(LogicalOperator):
+    reads_only = True
     left: LogicalOperator
     right: LogicalOperator
     symbols: list[str]

@@ -54,6 +54,7 @@ class _Unsupported(Exception):
 @dataclass
 class ParallelScanAggregate(Op.LogicalOperator):
     """Single-operator columnar scan+filter+aggregate with row fallback."""
+    reads_only = True
     input: Op.LogicalOperator          # Once
     fallback: Op.LogicalOperator       # the original Aggregate subplan
     symbol: str
@@ -707,6 +708,7 @@ class ParallelOrderedScan(Op.LogicalOperator):
     frames in final order; the original Produce sits above unchanged.
     Falls back to the row-at-a-time OrderBy on anything the columnar
     engine cannot express (mixed-type columns, temporal keys, ...)."""
+    reads_only = True
     input: Op.LogicalOperator          # Once
     fallback: Op.LogicalOperator       # OrderBy over the original tail
     symbol: str

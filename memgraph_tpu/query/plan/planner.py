@@ -11,6 +11,7 @@ statistics (approx counts) for choosing the cheapest start.
 from __future__ import annotations
 
 import itertools
+from dataclasses import fields, replace
 from typing import Optional
 
 from ...exceptions import SemanticException
@@ -336,6 +337,8 @@ class Planner:
         # device programs in ops/pipeline.py (query/plan/lane.py)
         from .lane import lane_rewrite
         plan = lane_rewrite(plan, hinted=parallel_hint)
+        # what the two left of ORDER BY … LIMIT: one bounded selection
+        plan = topk_rewrite(plan)
         return plan, columns
 
     def _call_fields(self, clause: A.CallProcedure) -> list[str]:
@@ -1344,6 +1347,117 @@ class Planner:
             clone.list_expr = rw(expr.list_expr)
             clone.expr = rw(expr.expr, (expr.acc, expr.var))
         return clone
+
+
+def topk_rewrite(plan):
+    """``Limit(Skip?(OrderBy(x)))`` -> ``TopK(x)``, and where ``x`` is the
+    projection's own ``Produce``, the Produce folded in (see Op.TopK).
+    Chosen by the plan's shape alone. Runs after parallel_rewrite and
+    lane_rewrite: a shape they claimed has no OrderBy left, and their
+    ``fallback`` subplans stay as they were planned."""
+    if type(plan) is Op.Limit:
+        inner, skip = plan.input, None
+        if type(inner) is Op.Skip:
+            inner, skip = inner.input, inner.expr
+        if type(inner) is Op.OrderBy:
+            plan = _fold_projection(
+                Op.TopK(inner.input, inner.items, plan.expr, skip))
+    for name, child in _child_operators(plan):   # (no ``fallback`` there)
+        setattr(plan, name, topk_rewrite(child))
+    return plan
+
+
+def _fold_projection(topk: "Op.TopK") -> "Op.TopK":
+    """Give the TopK its input Produce's items, so that they are
+    evaluated for the survivors; the sort items are rewritten to read
+    the INPUT frame (a column's name means the column, as it does in
+    the frame Produce builds, so it shadows a variable). Unchanged
+    where a sort item binds names of its own."""
+    produce = topk.input
+    if type(produce) is not Op.Produce:
+        return topk
+    reads_only = _reads_only(produce.input)
+    projection, columns = [], {}
+    for expr, name in produce.items:
+        base = expr.expr if isinstance(expr, A.PropertyLookup) else expr
+        if isinstance(expr, (A.Identifier, A.Literal, A.Parameter)) or (
+                reads_only and base is not expr and
+                isinstance(base, (A.Identifier, A.Parameter))):
+            projection.append((expr, name, None))
+            columns[name] = expr
+        else:
+            slot = Op.topk_slot(name)
+            projection.append((expr, name, slot))
+            columns[name] = A.Identifier(slot)
+    items = [(_inline_columns(expr, columns), asc)
+             for expr, asc in topk.items]
+    if any(expr is None for expr, _ in items):
+        return topk
+    return Op.TopK(produce.input, items, topk.limit, topk.skip, projection)
+
+
+#: expressions that bind no name of their own: an identifier under one
+#: of them means what it means beside it
+_SCOPELESS = (A.Literal, A.Parameter, A.PropertyLookup, A.LabelsTest,
+              A.Unary, A.Binary, A.IsNull, A.Subscript, A.Slice,
+              A.ListLiteral, A.MapLiteral, A.FunctionCall, A.CountStar,
+              A.CaseExpr)
+
+
+def _inline_columns(expr, columns: dict):
+    """``expr`` with every identifier that names a column replaced by
+    ``columns[name]``; None where a node in it is not _SCOPELESS (a
+    comprehension's variable may hide a column)."""
+    if isinstance(expr, A.Identifier):
+        return columns.get(expr.name, expr)
+    if not isinstance(expr, _SCOPELESS):
+        return None
+
+    def inline(value):
+        if isinstance(value, A.Expr):
+            value = _inline_columns(value, columns)
+            if value is None:
+                raise LookupError
+            return value
+        if isinstance(value, (list, tuple)):
+            return type(value)(inline(v) for v in value)
+        if isinstance(value, dict):
+            return {k: inline(v) for k, v in value.items()}
+        return value
+
+    try:
+        return replace(expr, **{f.name: inline(getattr(expr, f.name))
+                                for f in fields(expr)})
+    except LookupError:
+        return None
+
+
+def _child_operators(plan):
+    for name in Op.CHILD_ATTRS:
+        child = getattr(plan, name, None)
+        if isinstance(child, Op.LogicalOperator):
+            yield name, child
+
+
+def _reads_only(plan) -> bool:
+    """No operator below writes: every one says so (Op.reads_only).
+    (Under an update a property lookup may raise on a deleted entity, so
+    a TopK defers none there; nor in a subquery, whose Argument brings
+    rows from a plan that is not walked here.)"""
+    from ..procedures.registry import global_registry
+
+    def reads(op, fed_here):
+        if isinstance(op, Op.Argument):
+            return fed_here         # by an Optional_ or Apply walked here
+        if isinstance(op, Op.CallProcedureOp):
+            proc = global_registry.find(op.proc_name)
+            known = proc is not None and not proc.is_write
+        else:
+            known = op.reads_only
+        return known and all(reads(child, fed_here or name == "subplan")
+                             for name, child in _child_operators(op))
+
+    return reads(plan, False)
 
 
 def _literal_matches_type(value, type_decl: str) -> bool:

@@ -35,12 +35,7 @@ from __future__ import annotations
 import copy
 import time
 
-from .operators import LogicalOperator
-
-#: every attribute that may hold a child operator (kept in sync with
-#: profile_rows' walk and the planner's tree shapes)
-CHILD_ATTRS = ("input", "subplan", "match_plan", "create_plan",
-               "update_plan", "left", "right")
+from .operators import CHILD_ATTRS, LogicalOperator
 
 #: frame-size sampling cadence: the first _MEM_SAMPLE_HEAD frames are
 #: always measured, then every _MEM_SAMPLE_EVERY-th
