@@ -52,8 +52,7 @@ import numpy as np
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-#: mgbench Pokec medium (BASELINE.md §Datasets; benchmarks/mgbench.py is
-#: the loader this copies)
+#: mgbench Pokec medium (BASELINE.md §Datasets)
 NODES = 100_000
 EDGES = 1_768_515
 REDUCED = ("Pokec medium (100,000 / 1,768,515), not Pokec large "

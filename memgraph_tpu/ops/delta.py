@@ -278,80 +278,6 @@ def diff_changed_coo(prev_coo, cur_coo, changed_idx, n_nodes: int,
                          n_nodes, base_version, version)
 
 
-def incident_from_storage(accessor, gid_to_idx, changed_gids,
-                          weight_property=None):
-    """CURRENT visible edges incident to the changed vertices, read
-    straight from MVCC in O(changed x degree) — the serving-plane delta
-    payload without any snapshot export (the same per-vertex read
-    export_csr_delta does, permission-free). Dense-index (src, dst, w)
-    arrays, or None when the node set moved (a changed vertex joined or
-    left the view: dense ids shifted, full re-export required)."""
-    from ..storage.common import View
-    from ..storage.storage import EdgeAccessor, VertexAccessor
-    from .csr import _coerce_weight
-    storage = accessor.storage
-    changed = list(changed_gids)
-    changed_set = set(changed)
-    has_w = weight_property is not None
-    out_s: list = []
-    out_d: list = []
-    out_w: list = []
-    def _edge_visible(edge) -> bool:
-        # fast path first (same contract as export_csr): an object with
-        # no delta chain needs no MVCC materialization
-        if edge.delta is None:
-            return not edge.deleted
-        return EdgeAccessor(edge, accessor).is_visible(View.OLD)
-
-    def _edge_weight(edge) -> float:
-        if not has_w:
-            return 1.0
-        if edge.delta is None:
-            props = edge.properties
-        else:
-            props = EdgeAccessor(edge, accessor).properties(View.OLD)
-        return _coerce_weight(props.get(weight_property))
-
-    for gid in changed:
-        idx = gid_to_idx.get(gid)
-        vertex = storage._vertices.get(gid)
-        if idx is None or vertex is None:
-            return None
-        if vertex.delta is None:
-            if vertex.deleted:
-                return None
-            v_out, v_in = vertex.out_edges, vertex.in_edges
-        else:
-            va = VertexAccessor(vertex, accessor)
-            if not va.is_visible(View.OLD):
-                return None
-            st = accessor._vertex_state(vertex, View.OLD)
-            v_out, v_in = st.out_edges, st.in_edges
-        for (_etype, _other, edge) in v_out:
-            if not _edge_visible(edge):
-                continue
-            di = gid_to_idx.get(edge.to_vertex.gid)
-            if di is None:
-                return None
-            out_s.append(idx)
-            out_d.append(di)
-            out_w.append(_edge_weight(edge))
-        for (_etype, _other, edge) in v_in:
-            if edge.from_vertex.gid in changed_set:
-                continue               # its changed src emitted it above
-            if not _edge_visible(edge):
-                continue
-            si = gid_to_idx.get(edge.from_vertex.gid)
-            if si is None:
-                return None
-            out_s.append(si)
-            out_d.append(idx)
-            out_w.append(_edge_weight(edge))
-    return (np.asarray(out_s, dtype=np.int64),
-            np.asarray(out_d, dtype=np.int64),
-            np.asarray(out_w, dtype=np.float32))
-
-
 def compile_edge_delta(storage, prev_graph: DeviceGraph,
                        cur_graph: DeviceGraph, base_version: int,
                        version: int):

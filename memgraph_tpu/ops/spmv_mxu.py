@@ -860,8 +860,7 @@ def make_semiring_kernel(plan: MXUPlan, epilogue, route_dtype=None,
     route_dtype: dtype for the per-edge contributions through the big
     Benes (the dominant HBM traffic). bfloat16 halves it; sums still
     accumulate in f32 on the MXU, so each contribution carries one
-    0.4%-relative rounding — validated to preserve exact top-100 order
-    on the 10M-edge bench graph. float32 is the exact path.
+    0.4%-relative rounding. float32 is the exact path.
 
     delta: optional DeltaPlan — per iteration the base expand reads
     rank pre-scaled by delta.scale_out, the delta edges route through
@@ -947,46 +946,3 @@ def pagerank_mxu(src, dst, weights, n_nodes, damping=0.85,
                            max_iterations, jnp.float32(tol))
     rank = np.asarray(rank)
     return rank[plan.out_relabel], float(err), int(iters)
-
-
-# ---------------------------------------------------------------------------
-# plan persistence (bench reuse: routing a 10M-edge graph costs ~35s host-side)
-# ---------------------------------------------------------------------------
-
-_PLAN_VERSION = 4
-
-
-def save_plan(plan: MXUPlan, path: str) -> None:
-    np.savez_compressed(
-        path, version=_PLAN_VERSION, n_nodes=plan.n_nodes, G=plan.G,
-        R_G=plan.R_G, rowid=plan.rowid, mult=plan.mult,
-        out_relabel=plan.out_relabel, valid_out=plan.valid_out,
-        dangling_out=plan.dangling_out, net_log2=plan.net_log2,
-        masks_packed=plan.masks_packed, C=plan.C, run_k=plan.run_k,
-        win_oh=plan.win_oh, W=plan.W, in_relabel=plan.in_relabel,
-        node_net_log2=plan.node_net_log2,
-        node_masks_packed=plan.node_masks_packed,
-        wsum=plan.wsum if plan.wsum is not None else np.zeros(0))
-
-
-def load_plan(path: str) -> Optional[MXUPlan]:
-    try:
-        z = np.load(path)
-        if int(z["version"]) != _PLAN_VERSION:
-            return None
-        return MXUPlan(
-            n_nodes=int(z["n_nodes"]), G=int(z["G"]), R_G=int(z["R_G"]),
-            rowid=z["rowid"], mult=z["mult"], out_relabel=z["out_relabel"],
-            valid_out=z["valid_out"], dangling_out=z["dangling_out"],
-            net_log2=int(z["net_log2"]), masks_packed=z["masks_packed"],
-            C=int(z["C"]), run_k=z["run_k"],
-            win_oh=z["win_oh"], W=int(z["W"]), in_relabel=z["in_relabel"],
-            node_net_log2=int(z["node_net_log2"]),
-            node_masks_packed=z["node_masks_packed"],
-            wsum=z["wsum"] if z["wsum"].size else None)
-    except Exception:  # noqa: BLE001 — any cache damage means "rebuild"
-        import logging
-        log.debug(
-            "MXU plan cache at %s unreadable; rebuilding", path,
-            exc_info=True)
-        return None

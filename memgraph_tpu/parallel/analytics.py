@@ -25,7 +25,7 @@ to host memory every k iterations and a device fault resumes from the
 last checkpoint, bit-exact, instead of restarting. The
 MEMGRAPH_TPU_CHECKPOINT_EVERY env var sets the default k for callers
 that do not pass one (0 = single full-budget chunk, no host round
-trips); the kernel server and bench.py pass it explicitly.
+trips); the kernel server passes it explicitly.
 """
 
 from __future__ import annotations

@@ -144,8 +144,8 @@ def analytics_mesh() -> MeshContext | None:
     """Process-default mesh for `ops/` analytics, or None (single-chip).
 
     MEMGRAPH_TPU_MESH_DEVICES = "all" | "<int>" opts the whole analytics
-    layer into mesh execution; unset keeps the classic single-chip
-    kernels as the default (they are the measured bench path).
+    layer into mesh execution; unset keeps the single-chip
+    kernels as the default.
     """
     spec = os.environ.get("MEMGRAPH_TPU_MESH_DEVICES", "").strip()
     if not spec:

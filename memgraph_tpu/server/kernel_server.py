@@ -275,9 +275,9 @@ def _tier_precision(precision) -> str:
 
 def probe_device():
     """Tiny end-to-end device check: a compiled matmul with a host
-    transfer forcing completion. Shared by the server warm-up, the
-    ``probe`` op, and bench.py's probe stage — and guarded by the
-    device fault point so probe failures are injectable too.
+    transfer forcing completion. Shared by the server warm-up and the
+    ``probe`` op — and guarded by the device fault point so probe
+    failures are injectable too.
     Returns (checksum, platform)."""
     device_fault_point()
     import jax
@@ -1612,8 +1612,8 @@ class KernelServer:
     def _op_semiring(self, header, arrays):
         """Semiring-core dispatch: run a named core-routed algorithm at
         a requested precision through the resident runtime.  Serves
-        `pagerank` (plus-times, any precision — the bench's
-        stage_semiring sweep), `katz`, `wcc`, `labelprop` — all four
+        `pagerank` (plus-times, any precision), `katz`, `wcc`,
+        `labelprop` — all four
         riding the resident-generation warm-start layer (r19 mgdelta,
         per-algorithm contracts in ops/delta.py) — and `bfs` (min-plus
         levels via the GENERIC mesh semiring kernel; source-dependent,

@@ -1,7 +1,7 @@
 """mgshard: shard-per-process OLTP execution plane (r18).
 
 The Bolt worker pool gives concurrency, not CPU parallelism — the GIL
-caps aggregate multi-client OLTP at ~1.2x (OLTP_r05/r06). This package
+caps aggregate multi-client OLTP. This package
 promotes the mp-executor experiment to the architecture: storage is
 hash-sharded across N long-lived worker processes, each owning a full
 Storage engine with its own WAL directory and per-shard crash recovery;

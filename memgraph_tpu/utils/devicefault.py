@@ -1,7 +1,7 @@
 """Device-plane fault boundary: typed errors, injection, classification.
 
 Every accelerator dispatch the resilience plane supervises (kernel-server
-requests, resumable mesh-analytics chunks, the bench/health device probe)
+requests, resumable mesh-analytics chunks, the kernel server's probe)
 calls :func:`device_fault_point` first. Unarmed it costs one module-flag
 read per point; armed (via ``utils/faultinject``) it turns into the four
 canonical device failures:
@@ -26,7 +26,7 @@ canonical device failures:
 
 :func:`classify_device_error` is the shared classification: it maps real AND
 injected device exceptions onto {"oom", "device_lost", "device_error"}
-so the kernel server, the checkpoint runner, and bench's probe all
+so the kernel server, the checkpoint runner and the probe op all
 report the same typed outcome for the same failure.
 """
 

@@ -11,8 +11,8 @@ module sets no directory; otherwise the cache is the fixed
 ``<checkout>/.jax_cache`` (the directory is part of the cache key, so
 it must not move between runs).
 
-Called lazily from every kernel entry point (bench stages, GraphCache,
-module procedures). Safe to call multiple times; must run before the
+Called lazily from every kernel entry point (GraphCache, module
+procedures). Safe to call multiple times; must run before the
 first jit compile to be effective for it.
 """
 

@@ -17,8 +17,7 @@ Four layers of coverage:
    identity, full (op x context) matrix coverage, and — device_chaos
    marked — the 10-seed sweep of the whole matrix plus the real
    subprocess kill/respawn path.
-4. RetryPolicy deadline semantics (utils/retry.py) and bench.py's typed
-   probe classification.
+4. RetryPolicy deadline semantics (utils/retry.py).
 """
 
 import os
@@ -528,7 +527,7 @@ def test_probe_device_fault_injectable():
 
 
 # ==========================================================================
-# 4. RetryPolicy deadlines + bench probe classification
+# 4. RetryPolicy deadlines
 # ==========================================================================
 
 
@@ -566,32 +565,6 @@ def test_retry_call_honors_deadline():
         p.call(boom)
     assert len(calls) == 1                   # no 10s sleep happened
     assert time.monotonic() - t0 < 1.0
-
-
-def test_bench_probe_classification():
-    import bench
-    assert bench._classify_probe(0) == "ok"
-    assert bench._classify_probe(None) == "probe_timeout"
-    assert bench._classify_probe(137) == "probe_killed"
-    assert bench._classify_probe(2) == "probe_error_rc_2"
-
-
-def test_bench_resident_probe_consults_server(server):
-    """bench's probe consult reads the resident daemon's health and
-    typed probe — here against the in-thread server's socket."""
-    import bench
-    _, _, sock = server
-    monkey_sock = sock
-
-    import memgraph_tpu.server.kernel_server as ks
-    old = ks.DEFAULT_SOCKET
-    ks.DEFAULT_SOCKET = monkey_sock
-    try:
-        health, probe_reply = bench._resident_probe(timeout=10.0)
-    finally:
-        ks.DEFAULT_SOCKET = old
-    assert health is not None and health["wedged"] is False
-    assert probe_reply is not None and probe_reply["ok"] is True
 
 
 # ==========================================================================

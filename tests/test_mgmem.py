@@ -26,8 +26,8 @@ from tools.mgmem.admission import (
     CHECK_SHAPES, Estimators, check_ppr, check_resident,
     check_streamed, product_estimators)
 from tools.mgmem.check import (
-    CheckReport, Violation, canonical_record, memory_envelope_from,
-    run_check)
+    CheckReport, Violation, _check_envelopes, _check_kernel,
+    memory_envelope_from, run_check)
 from tools.mgmem.facts import MemFacts
 from tools.mgmem.model import FIT_TOLERANCE, FootprintModel, fit
 
@@ -269,7 +269,7 @@ def test_admission_verdict_matrix_from_streamed_model():
     assert verdict == "shed"
 
 
-# --- the check driver + record + perf gate ----------------------------------
+# --- the check driver and its envelopes -------------------------------------
 
 
 def test_run_check_partial_reports_build_violation():
@@ -279,43 +279,52 @@ def test_run_check_partial_reports_build_violation():
     assert report.violations[0].check == "build"
 
 
-def test_donation_violations_surface_with_bytes():
+def test_donation_violations_surface_with_bytes(monkeypatch):
+    from tools.mgmem import facts as F
+    fl = _facts("tier:pagerank_epilogue", [(64, 256, 1024)],
+                donation_dropped=1, dropped_bytes=256)
+    monkeypatch.setattr(F, "extract_all", lambda kernel: fl)
     report = CheckReport()
-    report.facts["tier:pagerank_epilogue"] = _facts(
-        "tier:pagerank_epilogue", [(64, 256, 1024)],
-        donation_dropped=1, dropped_bytes=256)
-    rec = canonical_record(report)
-    entry = rec["kernels"]["tier:pagerank_epilogue"]
-    assert entry["donation_dropped"] == 1
-    assert entry["dropped_bytes"] == 256
+    _check_kernel("tier:pagerank_epilogue", report)
+    dropped = [v for v in report.violations
+               if v.check == "donation-dropped"]
+    assert [(v.kernel, v.detail) for v in dropped] \
+        == [("tier:pagerank_epilogue", "256B")]
 
 
-def test_perf_gate_check_memory_pass_and_fail(capsys):
-    from tools.perf_gate import check_memory
-    env = {"memory": {"max_growth": 0.10,
-                      "kernels": {"segment:pagerank": 9_676,
-                                  "tier:pagerank_epilogue": 1_024}}}
-    clean = {"kernels": {
-        "segment:pagerank": {"peak_bytes": 9_676,
-                             "donation_dropped": 0},
-        "tier:pagerank_epilogue": {"peak_bytes": 1_024,
-                                   "donation_dropped": 0}}}
-    assert check_memory(clean, env) == 0
-    broken = {"kernels": {
-        "segment:pagerank": {"peak_bytes": 19_352,
-                             "donation_dropped": 0},
-        "tier:pagerank_epilogue": {"peak_bytes": 1_024,
-                                   "donation_dropped": 1,
-                                   "dropped_bytes": 256}}}
-    assert check_memory(broken, env) == 1
-    cap = capsys.readouterr()
-    out = cap.out + cap.err
-    assert "segment:pagerank" in out and "+100.0%" in out
-    assert "256" in out and "dropped donation" in out
-    # an envelope without a record is a FAIL, not a silent pass
-    assert check_memory(None, env) == 1
-    # no envelope -> the gate has nothing to enforce yet
-    assert check_memory(None, {}) == 0
+_ENVELOPE = {"max_growth": 0.10,
+             "kernels": {"segment:pagerank": 9_676,
+                         "tier:pagerank_epilogue": 1_024}}
+
+
+@pytest.mark.parametrize("peaks,envelope,want", [
+    # every kernel at its reference: nothing to report
+    ({"segment:pagerank": 9_676, "tier:pagerank_epilogue": 1_024},
+     _ENVELOPE, []),
+    # a peak 100% past the envelope names the kernel and the growth
+    ({"segment:pagerank": 19_352, "tier:pagerank_epilogue": 1_024},
+     _ENVELOPE,
+     [("segment:pagerank", "peak=19352B>ceiling=10643B", "+100.0%")]),
+    # a manifest kernel nobody wrote an envelope for
+    ({"segment:pagerank": 9_676, "tier:pagerank_epilogue": 1_024,
+      "segment:katz": 512}, _ENVELOPE,
+     [("segment:katz", "missing", "512B")]),
+    # an envelope for a kernel the manifest no longer has
+    ({"segment:pagerank": 9_676}, _ENVELOPE,
+     [("tier:pagerank_epilogue", "stale", "envelopes --write")]),
+    # no envelope written yet: nothing is checked
+    ({"segment:pagerank": 19_352}, None, []),
+], ids=["clean", "grown", "missing", "stale", "no-envelope"])
+def test_check_envelopes(peaks, envelope, want):
+    report = CheckReport()
+    for kernel, peak in peaks.items():
+        report.facts[kernel] = _facts(kernel, [(64, 256, peak)])
+    _check_envelopes(report, envelope)
+    assert all(v.check == "envelope" for v in report.violations)
+    assert [(v.kernel, v.detail) for v in report.violations] \
+        == [(k, d) for k, d, _ in want]
+    for v, (_, _, said) in zip(report.violations, want):
+        assert said in v.snippet
 
 
 def test_envelope_roundtrip_shapes():

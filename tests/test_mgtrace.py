@@ -341,8 +341,8 @@ def test_disarmed_overhead_under_two_percent(interp):
     assert T.span("query.parse") is T._NOOP
     assert "mvcc.commit" in T.PHASES
 
-    # per-query cost of the micro-benchmark (min over runs: the same
-    # estimator bench.py uses against scheduler noise)
+    # per-query cost of the micro-benchmark (min over runs, against
+    # scheduler noise)
     query = "MATCH (b:B) WHERE b.v > 100 RETURN count(b)"
     interp.execute(query)                   # warm plan cache
 

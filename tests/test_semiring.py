@@ -15,8 +15,7 @@ Covers the ISSUE-10 acceptance criteria:
     exactness on BFS);
   * per-backend mgstat stage attribution of the core dispatch;
   * the extended mglint MG005 sub-checks (core declarations, residual
-    hand-rolled pipelines) with TP fixtures;
-  * tools/perf_gate.py semiring ratio-envelope logic.
+    hand-rolled pipelines) with TP fixtures.
 
 Mesh-of-1 / 8-device uneven-shard equivalence for the core-routed
 algorithms piggybacks tests/test_sharded_analytics.py (its single-chip
@@ -728,45 +727,6 @@ def test_mg005_clean_core_module_passes(tmp_path):
           "def run(x, src, dst, n):\n"
           "    return S.spmv('min_plus', x, src, dst, n_out=n)\n")])
     assert not _check_spmv_registry(project)
-
-
-# --------------------------------------------------------------------------
-# perf gate: semiring ratio envelopes
-# --------------------------------------------------------------------------
-
-_ENVELOPES = {
-    "semiring_pagerank_f32_parity": {"min_fraction_of_headline": 0.25},
-    "semiring_bf16_speedup": {"min": 1.02},
-}
-
-
-def _record(sem):
-    return {"extra": {"semiring": sem}} if sem is not None \
-        else {"extra": {}}
-
-
-def test_perf_gate_semiring_checks():
-    from tools.perf_gate import check_semiring
-    ref = 3.03e9
-    good = {"backend": "tpu", "degraded": False,
-            "f32_eps": 1.0e9, "bf16_speedup": 1.4}
-    assert check_semiring(_record(good), _ENVELOPES, ref) == 0
-    # missing sweep
-    assert check_semiring(_record(None), _ENVELOPES, ref) == 1
-    # untagged CPU fallback
-    bad = dict(good, backend="cpu", degraded=False)
-    assert check_semiring(_record(bad), _ENVELOPES, ref) == 1
-    # degraded sweep under a non-degraded headline
-    bad = dict(good, backend="cpu", degraded=True)
-    assert check_semiring(_record(bad), _ENVELOPES, ref) == 1
-    # f32 fell off the fast path
-    bad = dict(good, f32_eps=0.1e9)
-    assert check_semiring(_record(bad), _ENVELOPES, ref) == 1
-    # bf16 no longer faster
-    bad = dict(good, bf16_speedup=0.97)
-    assert check_semiring(_record(bad), _ENVELOPES, ref) == 1
-    # no envelopes declared -> nothing to check
-    assert check_semiring(_record(None), {}, ref) == 0
 
 
 # --------------------------------------------------------------------------

@@ -677,48 +677,6 @@ def test_saturation_shard_queue_trips_and_recovers():
 
 
 # --------------------------------------------------------------------------
-# perf gate: the shard_scaling envelope semantics
-# --------------------------------------------------------------------------
-
-
-def _oltp_record(speedup=3.4, degraded=False, oracle=True,
-                 tagged=True, with_group=True):
-    rec = {"groups": []}
-    if tagged:
-        rec["degraded"] = degraded
-        rec["cores"] = 1 if degraded else 8
-    if with_group:
-        rec["groups"].append({"name": "point_read_sharded_4w",
-                              "workers": 4,
-                              "aggregate_qps": 6000.0,
-                              "speedup_vs_single_process": speedup})
-    rec["groups"].append({"name": "cross_shard_write_2pc",
-                          "iterations": 30,
-                          "oracle_match": oracle})
-    return rec
-
-
-def test_perf_gate_check_sharding():
-    from tools.perf_gate import check_sharding
-    env = {"shard_scaling": {"workers": 4, "min_speedup": 3.0}}
-    assert check_sharding(_oltp_record(), env) == 0
-    # no envelope declared -> nothing to enforce
-    assert check_sharding(None, {}) == 0
-    # envelope declared but no record -> fail
-    assert check_sharding(None, env) == 1
-    # untagged record (pre-r18 format) -> fail
-    assert check_sharding(_oltp_record(tagged=False), env) == 1
-    # honest degraded record can never be the headline -> fail
-    assert check_sharding(_oltp_record(degraded=True), env) == 1
-    # under the scaling floor -> fail
-    assert check_sharding(_oltp_record(speedup=2.1), env) == 1
-    # missing sharded group -> fail
-    assert check_sharding(_oltp_record(with_group=False), env) == 1
-    # 2PC oracle mismatch -> fail even with good scaling
-    assert check_sharding(_oltp_record(oracle=False), env) == 1
-
-
-# --------------------------------------------------------------------------
 # shard chaos: tier-1 smoke + the -m chaos sweep
 # --------------------------------------------------------------------------
 

@@ -6,9 +6,8 @@ reference; then assert the warm-start contracts — pagerank warm on
 repeat, WCC warm on an adds-only delta, the LOUD typed cold after a
 removal — and the change-log-wrap typed fallback.
 
-Functional counterpart of bench.py --stage delta sized for the dev gate
-(~seconds, CPU-safe): this proves the delta plane WORKS on every host;
-the bench proves it is FAST on accelerator hosts.
+Sized for the dev gate (~seconds, CPU-safe): this proves the delta
+plane WORKS on every host; it measures no speed.
 
 Usage: python -m tools.delta_smoke
 """

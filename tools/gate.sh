@@ -125,7 +125,7 @@ stage "mgchaos device nemesis smoke (supervised kernel plane)" \
 # 4c. PPR serving-plane smoke: spawn the kernel server, fire 64
 #     concurrent requests from threads, assert the coalescing ratio
 #     beats 1 (requests really shared batches), cache hit on repeat,
-#     clean shutdown. Functional on every host; perf is the bench's job.
+#     clean shutdown. Functional on every host.
 stage "ppr-smoke (coalesced PPR serving plane)" \
     python -m tools.ppr_smoke
 
@@ -134,7 +134,7 @@ stage "ppr-smoke (coalesced PPR serving plane)" \
 #      resident generation O(delta) with a warm-started, residual-
 #      equivalent reply; WCC monotone gate (warm on adds-only, LOUD
 #      typed cold on removal); change-log-wrap typed fallback.
-#      Functional on every host; delta_speedup is the bench's job.
+#      Functional on every host.
 stage "delta-smoke (incremental resident analytics plane)" \
     python -m tools.delta_smoke
 
@@ -150,8 +150,7 @@ stage "lane-smoke (compiled Cypher read lane)" \
 #     shard), routed point reads/writes, scatter-gather merge, a
 #     cross-shard 2PC transaction, one LIVE shard-move (epoch bump +
 #     cutover), a worker kill with typed-error respawn + per-shard WAL
-#     recovery, clean shutdown. Functional on every host; scaling is
-#     the bench's job (mgbench --shards -> OLTP_r*.json).
+#     recovery, clean shutdown. Functional on every host.
 stage "shard-smoke (sharded OLTP execution plane)" \
     python -m tools.shard_smoke
 
@@ -168,20 +167,11 @@ stage "tier-smoke (out-of-core streamed edge blocks)" \
 #     cold restart resuming exactly-once from the durable offset,
 #     poison-batch dead-letter quarantine with the loop alive, the
 #     AFTER-COMMIT trigger metered, backpressure probe + the
-#     stream_lag health flip. Functional on every host; sustained
-#     throughput is the bench's job (stream_ingest -> BENCH_r*.json).
+#     stream_lag health flip. Functional on every host.
 stage "stream-smoke (crash-safe exactly-once ingestion plane)" \
     python -m tools.stream_smoke
 
-# 5. perf-regression gate: the newest BENCH_r*.json record must be
-#    non-degraded and within BASELINE.json's envelope (>15% regression
-#    fails). Hosts without an accelerator skip LOUDLY (exit 0): the
-#    gate defends the trajectory on real hardware, it does not punish
-#    CPU-only dev boxes — but it never silently passes either.
-stage "perf regression gate (BASELINE.json envelopes)" \
-    python -m tools.perf_gate --latest
-
-# 6. tier-1 tests: arms the lock-order witness (MG_TRACK_LOCKS=1, from
+# 5. tier-1 tests: arms the lock-order witness (MG_TRACK_LOCKS=1, from
 #    conftest) and the vector-clock race detector (MG_SAN=1) suite-wide;
 #    the session fails on any witnessed lock cycle or data race.
 #    Optional-dep suites (hypothesis, cryptography) self-skip.
@@ -190,7 +180,7 @@ stage "tier-1 tests (MG_SAN=1)" \
         -m "not slow and not crash and not sanitize"
 
 if [ "$FULL" = 1 ]; then
-    # 7. the full seeded sweeps: 25 mgsan seeds per scenario + 5
+    # 6. the full seeded sweeps: 25 mgsan seeds per scenario + 5
     #    workload seeds, and the 10-seed mgchaos nemesis sweep
     stage "mgsan full seeded sweep (-m sanitize)" \
         env MG_SAN=1 python -m pytest tests/test_mgsan.py -q -m sanitize

@@ -3,10 +3,9 @@ invalidation round trip, end to end through the interpreter.
 
     python -m tools.lane_smoke
 
-Functional on every host (CPU jax included) — the perf claim is the
-bench's job (mgbench lane groups + perf_gate.check_lane); this gate
-proves the MACHINERY: a lane-eligible query compiles once and serves
-from the compiled program, refusal shapes fall back loudly with their
+Functional on every host (CPU jax included); it measures no speed.
+This gate proves the MACHINERY: a lane-eligible query compiles once and
+serves from the compiled program, refusal shapes fall back loudly with their
 typed reason while answering identically, and index DDL drops every
 compiled lane (stale lanes never serve) with results bit-identical to
 the serial interpreter before and after.

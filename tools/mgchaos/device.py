@@ -12,7 +12,7 @@ seeded dispatch hit, in one of three injection contexts:
     kernel_request  mid-flight in a supervised kernel-server request —
                     the client must get either a correct result (after
                     typed retries) and never wedge
-    probe           during the device probe (bench.py's path) — the
+    probe           during the kernel server's device probe — the
                     failure must classify to its typed outcome
 
 A schedule is a pure function of the seed (``device_schedule_text``

@@ -18,9 +18,7 @@ Checks per manifest kernel:
                            super-linear intermediate);
 * ``envelope``           — canonical-point peak grew past the
                            BASELINE.json memory envelope (the
-                           memory-regression gate, enforced again by
-                           ``perf_gate.check_memory`` over the
-                           committed MEM record);
+                           memory-regression gate);
 * ``admission-*``        — the serving estimators vs the models
                            (:mod:`.admission`).
 """
@@ -38,8 +36,8 @@ BASELINE_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 REPO_BASELINE_PATH = os.path.join(REPO, "BASELINE.json")
 
 #: envelope headroom: canonical-point peak may grow this fraction
-#: before the gate fails (mirrors the perf gate's 15% discipline but
-#: tighter — buffer assignment is deterministic, drift is a change)
+#: before the gate fails (buffer assignment is deterministic, so
+#: drift is a change)
 DEFAULT_MAX_GROWTH = 0.10
 
 
@@ -210,46 +208,15 @@ def run_check(only=None, baseline: dict | None = None,
     return report
 
 
-def canonical_record(report: CheckReport) -> dict:
-    """The committed MEM_r*.json record ``perf_gate.check_memory``
-    re-enforces: per-kernel canonical-point facts + fitted models."""
-    from .facts import SHAPE_POINTS
-    kernels = {}
-    for kernel, fl in sorted(report.facts.items()):
-        f0 = fl[0]
-        entry = {"peak_bytes": f0.peak_bytes,
-                 "argument_bytes": f0.argument_bytes,
-                 "output_bytes": f0.output_bytes,
-                 "temp_bytes": f0.temp_bytes,
-                 "alias_bytes": f0.alias_bytes,
-                 "generated_code_bytes": f0.generated_code_bytes,
-                 "donated_aliased": f0.donated_aliased,
-                 "donation_dropped": f0.donation_dropped,
-                 "dropped_bytes": f0.dropped_bytes}
-        m = report.models.get(kernel)
-        if m is not None:
-            entry["model"] = {"const": m.const, "per_node": m.per_node,
-                              "per_edge": m.per_edge,
-                              "replicas": m.replicas, "lanes": m.lanes}
-        kernels[kernel] = entry
-    return {"schema": "mgmem-1",
-            "canonical_point": [SHAPE_POINTS[0].n_pad,
-                                SHAPE_POINTS[0].n_edges],
-            "kernels_checked": report.kernels_checked,
-            "ok": report.ok,
-            "kernels": kernels}
-
-
 def memory_envelope_from(report: CheckReport,
                          max_growth: float = DEFAULT_MAX_GROWTH) -> dict:
     """Fresh ``envelopes.memory`` content for BASELINE.json."""
     return {"_comment": "per-kernel compiled peak bytes at the mgmem "
                         "canonical point (n_pad=64, n_edges=256; "
                         "mesh kernels whole-mesh). Enforced by `python "
-                        "-m tools.mgmem check` and perf_gate."
-                        "check_memory over the committed MEM_r*.json "
-                        "record. Regenerate: `python -m tools.mgmem "
-                        "envelopes --write`.",
+                        "-m tools.mgmem check` over the freshly "
+                        "compiled facts. Regenerate: `python -m "
+                        "tools.mgmem envelopes --write`.",
             "max_growth": max_growth,
             "kernels": {k: fl[0].peak_bytes
                         for k, fl in sorted(report.facts.items())}}

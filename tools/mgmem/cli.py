@@ -33,9 +33,6 @@ def build_parser() -> argparse.ArgumentParser:
                           "baseline.json)")
     chk.add_argument("--no-baseline", action="store_true",
                      help="ignore the baseline: show every violation")
-    chk.add_argument("--record", default=None, metavar="MEM_rN.json",
-                     help="also write the canonical MEM record "
-                          "perf_gate.check_memory enforces")
     env = sub.add_parser(
         "envelopes",
         help="print (or --write into BASELINE.json) the per-kernel "
@@ -87,8 +84,8 @@ def main(argv: list[str] | None = None) -> int:
                 print(k)
         return 0
 
-    from .check import (REPO_BASELINE_PATH, canonical_record,
-                        memory_envelope_from, run_check)
+    from .check import (REPO_BASELINE_PATH, memory_envelope_from,
+                        run_check)
 
     if args.cmd == "envelopes":
         report = run_check(envelope=None, admission=False)
@@ -131,13 +128,6 @@ def main(argv: list[str] | None = None) -> int:
         print(f"mgmem: lowering unavailable on this host ({e}) — "
               "NOTHING was checked", file=sys.stderr)
         return 2
-
-    if args.record and only is None:
-        record = canonical_record(report)
-        with open(args.record, "w", encoding="utf-8") as f:
-            json.dump(record, f, indent=1, sort_keys=True)
-            f.write("\n")
-        print(f"mgmem: wrote {args.record}")
 
     if args.json:
         print(json.dumps({

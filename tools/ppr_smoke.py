@@ -3,9 +3,8 @@
 1 (requests actually shared batches), assert a repeat request hits the
 result cache, and shut down cleanly.
 
-Functional counterpart of benchmarks/ppr_serving_bench.py sized for the
-dev gate (~seconds, CPU-safe): this proves the serving plane WORKS on
-every host; the bench proves it is FAST on accelerator hosts.
+Sized for the dev gate (~seconds, CPU-safe): this proves the serving
+plane WORKS on every host; it measures no speed.
 
 Usage: python -m tools.ppr_smoke
 """

@@ -4,9 +4,8 @@ scatter-gather read, one cross-shard 2PC transaction, one LIVE
 shard-move under the same data, a worker kill + typed-error respawn,
 and a clean shutdown.
 
-Functional counterpart of the mgbench --shards group sized for the dev
-gate (~seconds, fork-safe on any host): this proves the plane WORKS
-everywhere; the bench proves it SCALES on multi-core hosts.
+Sized for the dev gate (~seconds, fork-safe on any host): this proves
+the plane WORKS everywhere; it measures no scaling.
 
 Usage: python -m tools.shard_smoke
 """

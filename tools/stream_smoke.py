@@ -6,9 +6,8 @@ batch quarantined to the dead-letter buffer with the loop alive, an
 AFTER-COMMIT trigger firing on ingested batches, the backpressure
 probe, and the stream_lag health check flipping /health.
 
-Functional counterpart of the mgbench stream_ingest scenario sized for
-the dev gate (~seconds, any host): this proves the plane WORKS; the
-bench proves it keeps up.
+Sized for the dev gate (~seconds, any host): this proves the plane
+WORKS; it measures no throughput.
 
 Usage: python -m tools.stream_smoke
 """

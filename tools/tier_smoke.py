@@ -9,10 +9,8 @@ non-streamable algorithm against the same oversized graph sheds with
 the typed non-retryable verdict instead of lying, and the compressed
 wire formats actually compress (bf16/int8 >= 1.8x vs raw COO bytes).
 
-Functional counterpart of bench.py --stage tier sized for the dev gate
-(~seconds, CPU-safe): this proves out-of-core execution WORKS on every
-host; overlap/throughput numbers are the bench's job on accelerator
-hosts.
+Sized for the dev gate (~seconds, CPU-safe): this proves out-of-core
+execution WORKS on every host; it measures no overlap or throughput.
 
 Usage: python -m tools.tier_smoke
 """
