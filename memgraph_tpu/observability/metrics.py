@@ -147,6 +147,9 @@ STAT_NAMES = (
     "delta.fallback_rebuild_total",  # wrapped log / failed splice colds
     "delta.plan_applied_total",     # in-process CALL: MXU DeltaPlan refresh
     "delta.plan_rebuild_total",     # in-process CALL: full MXU plan build
+    "delta.columnar_applied_total",  # columnar cache miss served by a patch
+    "delta.columnar_rebuild_total",  # columnar cache miss served by a sweep
+    "delta.columnar_patch_failed_total",  # of those: a patch that raised
     "delta.edge_count",             # histogram: edges per applied delta
     "delta.warm_start_total",
     "delta.cold_start_total",       # LOUD monotone-unsafe cold starts

@@ -584,7 +584,8 @@ class GraphCache:
         # touching a large fraction of the graph fall back to the full
         # export, whose delta-free fast path is cheaper per edge
         if newest is not None:
-            from ..storage.storage import ChangeLogUnknowable
+            from ..storage.storage import (ChangeLogUnknowable,
+                                           change_set_is_small)
             changed = storage.changes_between(newest[0], version)
             if isinstance(changed, ChangeLogUnknowable):
                 # typed wrap verdict: the log cannot reconstruct the
@@ -599,7 +600,7 @@ class GraphCache:
                     version)
                 changed = None
             if changed is not None and \
-                    len(changed) <= max(1024, newest[1].n_nodes // 5):
+                    change_set_is_small(len(changed), newest[1].n_nodes):
                 try:
                     g = export_csr_delta(
                         newest[1], accessor, changed,

@@ -367,38 +367,40 @@ class LaneHopCount(Op.LogicalOperator):
     def _endpoints(self, ctx, full, edges):
         """Per-version host staging, cached on the snapshots themselves:
         the gid order of the vertices, the edges' endpoint rows, the
-        edge-type mask."""
-        f_order = getattr(full, "_lane_order", None)
-        if f_order is None:
+        edge-type mask, the device copies. A cached snapshot is shared
+        between threads, so each piece is published whole, in one
+        assignment: a reader that sees it sees all of it."""
+        lane_order = getattr(full, "_lane_order", None)
+        if lane_order is None:
             f_order = np.argsort(full.gids, kind="stable")
-            full._lane_order = f_order
-            full._lane_sorted = full.gids[f_order]
-        f_sorted = full._lane_sorted
+            lane_order = full._lane_order = (f_order, full.gids[f_order])
+        f_order, f_sorted = lane_order
         endpoints = getattr(edges, "_lane_endpoints", None)
-        if endpoints is None:
+        if endpoints is None or endpoints[0] is not full:
+            # rows of THIS vertex table: another build of the version's
+            # table (a sweep where this one was patched) orders its rows
+            # differently, so the rows go with it, and with them the
+            # caches of type masks and device copies
             s_idx = _gid_rows(f_sorted, f_order, edges.src)
             d_idx = _gid_rows(f_sorted, f_order, edges.dst)
-            endpoints = (s_idx.astype(np.int32), d_idx.astype(np.int32),
-                         (s_idx >= 0) & (d_idx >= 0))
-            edges._lane_endpoints = endpoints
-        s_idx, d_idx, ep_ok = endpoints
+            endpoints = edges._lane_endpoints = (
+                full, s_idx.astype(np.int32), d_idx.astype(np.int32),
+                (s_idx >= 0) & (d_idx >= 0), {}, {})
+        _, s_idx, d_idx, ep_ok, type_masks, staged_cache = endpoints
 
         emask = ep_ok
         tkey = tuple(sorted(self.edge_types or ()))
         if self.edge_types:
-            cache = getattr(edges, "_lane_typemask", None)
-            if cache is None:
-                cache = edges._lane_typemask = {}
-            tmask_e = cache.get(tkey)
+            tmask_e = type_masks.get(tkey)
             if tmask_e is None:
                 ids = [tid for tid in
                        (ctx.storage.edge_type_mapper.maybe_name_to_id(t)
                         for t in self.edge_types) if tid is not None]
                 tmask_e = np.isin(edges.type_ids,
                                   np.asarray(ids, dtype=np.int32))
-                cache[tkey] = tmask_e
+                type_masks[tkey] = tmask_e
             emask = emask & tmask_e
-        return f_sorted, f_order, s_idx, d_idx, emask, tkey
+        return f_sorted, f_order, s_idx, d_idx, emask, tkey, staged_cache
 
     def _admit(self, ctx) -> None:
         from ...ops import pipeline as pl
@@ -422,7 +424,7 @@ class LaneHopCount(Op.LogicalOperator):
                                       abort_check=ctx.check_abort)
             ctx.check_abort()
         with mgtrace.span("lane.stage"):
-            f_sorted, f_order, s_idx, d_idx, emask, tkey = \
+            f_sorted, f_order, s_idx, d_idx, emask, tkey, staged_cache = \
                 self._endpoints(ctx, full, edges)
 
         src_preds = list(self.src_preds)
@@ -447,9 +449,6 @@ class LaneHopCount(Op.LogicalOperator):
         else:
             # edge arrays stay device-resident per (version, types,
             # direction): repeat queries move only the O(n) masks
-            staged_cache = getattr(edges, "_lane_staged", None)
-            if staged_cache is None:
-                staged_cache = edges._lane_staged = {}
             skey = (tkey, self.direction)
             staged = staged_cache.get(skey)
             if staged is None:

@@ -392,6 +392,11 @@ def _apply_wal_txn(storage, ops):
             e = storage._edges.get(gid)
             if e is not None:
                 e.properties = props
+                # as the commit that wrote the record logged them
+                # (edge_prop_endpoint_gids): the caches that follow the
+                # change log re-read an edge through its endpoints
+                changed.add(e.from_vertex.gid)
+                changed.add(e.to_vertex.gid)
         elif kind == W.OP_DELETE_EDGE:
             gid = _read_varint(buf)
             e = storage._edges.pop(gid, None)

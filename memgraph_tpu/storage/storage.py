@@ -65,6 +65,16 @@ class ChangeLogUnknowable:
                 f"oldest_logged_version={self.oldest_logged_version})")
 
 
+def change_set_is_small(n_changed: int, n_rows: int) -> bool:
+    """The one rule by which a version-keyed cache (ops/csr.py's
+    GraphCache, ops/columnar.py's ColumnarCache) takes the O(changed)
+    refresh: `n_changed` vertices of :meth:`Storage.changes_between`
+    against a table of `n_rows`, counted in vertices too. A bulk commit
+    past it takes the full export, whose delta-free fast path is cheaper
+    per object."""
+    return n_changed <= max(1024, n_rows // 5)
+
+
 @dataclass
 class StorageConfig:
     storage_mode: StorageMode = StorageMode.IN_MEMORY_TRANSACTIONAL

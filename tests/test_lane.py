@@ -367,6 +367,130 @@ class TestMVCC:
         assert run(db, q) == [[1]]
 
 
+Q_AGG = (f"MATCH (n:User) {HINT}WHERE n.age > 40 RETURN count(*), "
+         "sum(n.age), min(n.age), max(n.age)")
+Q_HOP = (f"MATCH (a:User)-[:FRIEND]->(b)-[:FRIEND]->(m) {HINT}"
+         "WHERE a.age < 2 RETURN count(m)")
+WRITES = [("MATCH (n:User {id: $id}) SET n.age = n.age + 1", ("id",)),
+          ("MATCH (a:User {id: $a}), (b:User {id: $b}) "
+           "CREATE (a)-[:FRIEND]->(b)", ("a", "b")),
+          ("CREATE (:User {id: $id, age: $id % 80})", ())]
+
+
+@pytest.fixture()
+def pokec():
+    """The small cell's schema and its three write classes, at 400 /
+    2,400: two contexts on one store, so that the lane's plans and the
+    row path's (planned with the rewrite off) both stay cached."""
+    storage = InMemoryStorage()
+    lane, rows = InterpreterContext(storage), InterpreterContext(storage)
+    rng = np.random.default_rng(5)
+    run(lane, "UNWIND range(0, 399) AS i "
+              "CREATE (:User {id: i, age: i % 80})")
+    run(lane, "CREATE INDEX ON :User(id)")
+    pairs = [[int(a), int(b)] for a, b in rng.integers(0, 400, (2400, 2))]
+    run(lane, "UNWIND $pairs AS p MATCH (a:User {id: p[0]}), "
+              "(b:User {id: p[1]}) CREATE (a)-[:FRIEND]->(b)",
+        {"pairs": pairs})
+    os.environ["MEMGRAPH_TPU_DISABLE_PARALLEL"] = "1"
+    try:
+        for q in (Q_AGG, Q_HOP):
+            run(rows, q)
+    finally:
+        os.environ.pop("MEMGRAPH_TPU_DISABLE_PARALLEL", None)
+    return lane, rows, rng
+
+
+def _write(ctx, rng, i, next_id):
+    query, keys = WRITES[i % 3]
+    params = {k: int(rng.integers(0, 400)) for k in keys} or \
+        {"id": next_id}
+    run(ctx, query, params)
+
+
+class TestSnapshotFollowsWrites:
+    """The columnar cache is keyed on the reader's version and refreshed
+    from the change log (tests/test_columnar_delta.py holds the patch to
+    the sweep); here the lane's answers through the interpreter."""
+
+    def test_lane_classes_hold_after_each_of_fifty_writes(self, pokec):
+        lane, rows, rng = pokec
+        for q in (Q_AGG, Q_HOP):
+            assert run(lane, q) == run(rows, q)
+        snap = {n: v for n, _k, v in _metrics()}
+        for i in range(50):
+            _write(lane, rng, i, 400 + i)
+            for q in (Q_AGG, Q_HOP):
+                assert run(lane, q) == run(rows, q), (i, q)
+        assert _metric_delta(snap, "lane.hit_total") == 100
+        assert _metric_delta(snap, "delta.columnar_rebuild_total") == 0
+        # edges, all vertices and :User, once a version
+        assert _metric_delta(snap, "delta.columnar_applied_total") == 150
+
+    def test_one_patch_a_table_a_version_and_stats_lists_them(self, pokec):
+        import asyncio
+        import json
+        import socket
+        import threading
+        import urllib.request
+        lane, _rows, rng = pokec
+        run(lane, Q_HOP)
+        _write(lane, rng, 1, 0)
+        snap = {n: v for n, _k, v in _metrics()}
+        run(lane, Q_HOP)
+        assert _metric_delta(snap, "delta.columnar_applied_total") == 3
+        run(lane, Q_HOP)
+        run(lane, Q_AGG)                # :User again, at the same version
+        assert _metric_delta(snap, "delta.columnar_applied_total") == 3
+        assert _metric_delta(snap, "delta.columnar_rebuild_total") == 0
+
+        from memgraph_tpu.observability.http import start_monitoring_server
+        with socket.socket() as probe:
+            probe.bind(("127.0.0.1", 0))
+            port = probe.getsockname()[1]
+        loop = asyncio.new_event_loop()
+        started = threading.Event()
+
+        def serve():
+            asyncio.set_event_loop(loop)
+            loop.run_until_complete(
+                start_monitoring_server("127.0.0.1", port, lane))
+            started.set()
+            loop.run_forever()
+
+        threading.Thread(target=serve, daemon=True).start()
+        assert started.wait(10)
+        try:
+            doc = json.loads(urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/stats", timeout=5).read())
+        finally:
+            loop.call_soon_threadsafe(loop.stop)
+        assert doc["delta"]["delta.columnar_applied_total"] >= 3
+        assert doc["delta"]["delta.columnar_rebuild_total"] >= 3
+
+    def test_a_reader_older_than_a_commit_is_not_private(self, pokec):
+        from memgraph_tpu.storage.common import IsolationLevel
+        lane, rows, rng = pokec
+        reader = Interpreter(lane)
+        reader.session_isolation = IsolationLevel.SNAPSHOT_ISOLATION
+        reader.execute("BEGIN")
+        want = {q: run(rows, q) for q in (Q_AGG, Q_HOP)}
+        snap = {n: v for n, _k, v in _metrics()}
+        for i in range(3):              # commits the reader cannot see
+            _write(lane, rng, i, 900 + i)
+            run(lane, Q_HOP)            # a newer entry stands beside its own
+            for q in (Q_AGG, Q_HOP):
+                assert reader.execute(q)[1] == want[q], (i, q)
+        assert _metric_delta(snap, "lane.hit_total") == 9
+        assert _metric_delta(snap, "lane.fallback_total.mvcc_private") == 0
+        # its own writes still are
+        reader.execute("CREATE (:User {id: 999, age: 41})")
+        got = reader.execute(Q_AGG)[1]
+        assert got[0][0] == want[Q_AGG][0][0] + 1
+        assert _metric_delta(snap, "lane.fallback_total.mvcc_private") == 1
+        reader.execute("ROLLBACK")
+
+
 class TestKernelServerLane:
     def test_lane_op_served_in_process(self):
         """The kernel server's lane op runs the same hop program the
