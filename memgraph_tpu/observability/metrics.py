@@ -150,6 +150,8 @@ STAT_NAMES = (
     "delta.columnar_applied_total",  # columnar cache miss served by a patch
     "delta.columnar_rebuild_total",  # columnar cache miss served by a sweep
     "delta.columnar_patch_failed_total",  # of those: a patch that raised
+    "delta.vector_applied_total",   # vector-index miss served by a refresh
+    "delta.vector_rebuild_total",   # vector-index miss served by a full build
     "delta.edge_count",             # histogram: edges per applied delta
     "delta.warm_start_total",
     "delta.cold_start_total",       # LOUD monotone-unsafe cold starts

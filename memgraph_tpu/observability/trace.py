@@ -106,6 +106,14 @@ SPAN_NAMES = (
     "analytics.device_wait",  # readback of rank/err/iters: ends in a block
     "analytics.rows",      # summed time in the procedure's row generator
     "analytics.consume",   # summed time of the operators above it, per row
+    "vector.index",        # the embedding index for a reader's snapshot
+    #                        (kind: hit, alias, delta, full)
+    "vector.refresh",      # its change-log refresh: O(changed) reads, scatter
+    "vector.build",        # its full build: every vertex's vector read
+    "vector.search",       # query upload, knn dispatch, readback of the top k
+    "graphrag.expand",     # hybrid retrieval: k-hop frontier and its readback
+    "graphrag.ppr",        # its personalized-PageRank fixpoint and readback
+    "graphrag.rows",       # its ranking of the masked scores into vertices
     "lane.query",          # one compiled-lane attempt, refusals included
     "lane.snapshot",       # columnar snapshot fetch (rebuilt after a write)
     "lane.stage",          # argsort/endpoints/masks + edge upload
@@ -155,6 +163,13 @@ PHASES = {
     "analytics.device_wait": ("+device_iterate", "+semiring_{backend}"),
     "analytics.rows": (),
     "analytics.consume": (),
+    "vector.index": (),
+    "vector.refresh": (),
+    "vector.build": (),
+    "vector.search": (),
+    "graphrag.expand": (),
+    "graphrag.ppr": (),
+    "graphrag.rows": (),
     "lane.query": (),
     "lane.snapshot": (),
     "lane.stage": (),

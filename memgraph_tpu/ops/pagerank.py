@@ -338,7 +338,9 @@ def personalized_pagerank(graph: DeviceGraph, source_nodes,
                 "tol": np.float32(tol)},
         n_out=graph.n_pad, setup=_ppr_setup, epilogue=_ppr_epilogue,
         max_iterations=max_iterations, sorted=True, precision=precision)
-    return rank[:graph.n_nodes], float(err), int(iters)
+    # read back padded and cut on the host, as pagerank() does: a device
+    # slice to n_nodes is a new program for every vertex count
+    return np.asarray(rank)[:graph.n_nodes], float(err), int(iters)
 
 
 def _ppr_via_kernel(graph, source_nodes, damping, max_iterations, tol,

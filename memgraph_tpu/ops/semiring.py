@@ -359,6 +359,10 @@ def _build_fixpoint(sr, *, epilogue, setup, step, n_out, max_iterations,
 
         return jax.lax.while_loop(cond, body, (x, m0, jnp.int32(0)))
 
+    # the program's name in a device trace is the algorithm's, from its
+    # epilogue hook (jit_fixpoint_ppr, jit_fixpoint_pagerank, ...)
+    hook = getattr(epilogue, "__name__", "").strip("_")
+    run.__name__ = "fixpoint_" + (hook.removesuffix("_epilogue") or "run")
     # the x0 seed is donated back to the iterate: callers pass freshly
     # built start vectors (or None, which donates nothing), so the
     # fixpoint carry never holds two live copies of the O(n) state

@@ -31,6 +31,7 @@ from . import semiring as S
 from .csr import DeviceGraph
 
 INF = jnp.float32(3.4e38)
+_UNREACHED = 1.7e38     # INF / 2 as a host number, for arrays read back
 
 
 def _sssp_step_directed(dist, A, env, P, n_out):
@@ -164,8 +165,9 @@ def _mssp_kernel(src, dst, w, sources, n_pad: int, max_iterations: int):
 
 def multi_source_sssp(graph: DeviceGraph, sources, weighted: bool = True,
                       directed: bool = True, max_iterations: int = 10_000):
-    """Distances from each of B sources: (B, n_nodes). Feeds betweenness
-    sampling and graph-context retrieval (GraphRAG expansions)."""
+    """Distances from each of B sources: (B, n_nodes), on the host.
+    Feeds betweenness sampling and graph-context retrieval (GraphRAG
+    expansions)."""
     w = graph.weights if weighted else jnp.ones_like(graph.weights)
     w = jnp.where(jnp.arange(graph.e_pad) < graph.n_edges, w, INF)
     src, dst = graph.src_idx, graph.col_idx
@@ -176,17 +178,20 @@ def multi_source_sssp(graph: DeviceGraph, sources, weighted: bool = True,
     dist = _mssp_kernel(src, dst, w,
                         jnp.asarray(sources, dtype=jnp.int32),
                         graph.n_pad, max_iterations)
-    out = dist[:, :graph.n_nodes]
-    return jnp.where(out >= INF / 2, jnp.inf, out)
+    # the padded rows are read back whole and cut on the host: a device
+    # slice to n_nodes is a new program for every vertex count, compiled
+    # inside the request that follows an inserted vertex
+    out = np.asarray(dist)[:, :graph.n_nodes]
+    return np.where(out >= _UNREACHED, np.inf, out)
 
 
 def khop_neighborhood(graph: DeviceGraph, sources, k: int,
                       directed: bool = False):
-    """Boolean mask (n_nodes,) of nodes within k hops of any source —
-    the device-side version of the GraphRAG '2-hop expand' step.
+    """Boolean mask (n_nodes,), on the host, of nodes within k hops of
+    any source — the GraphRAG '2-hop expand' step on the device.
 
     Each Bellman-Ford round extends reach by ≥1 hop, so k rounds settle
     every node within k hops."""
     levels = multi_source_sssp(graph, sources, weighted=False,
                                directed=directed, max_iterations=k + 1)
-    return jnp.any(levels <= float(k), axis=0)
+    return np.any(levels <= float(k), axis=0)

@@ -27,7 +27,7 @@ from . import mgp
 def retrieve(ctx, property, query_vector, k_seeds, hops=2, limit=10,
              damping=0.85, metric="cosine"):
     """Hybrid retrieval over the current graph snapshot."""
-    import jax.numpy as jnp
+    from ..observability import trace as mgtrace
     from ..ops.pagerank import personalized_pagerank
     from ..ops.traversal import khop_neighborhood
     from .vector_search import _get_index, _search_entry
@@ -41,15 +41,13 @@ def retrieve(ctx, property, query_vector, k_seeds, hops=2, limit=10,
 
     # 1) seed selection: vector kNN over the embedding index (MXU,
     #    delta-maintained — streaming GraphRAG never full-rebuilds)
-    q = jnp.asarray(np.asarray([query_vector], dtype=np.float32))
-    sims, idx = _search_entry(entry, q, int(k_seeds), str(metric))
+    sims, idx = _search_entry(entry, [query_vector], int(k_seeds),
+                              str(metric))
     if sims is None:
         return
-    sims = np.asarray(sims[0])
-    idx = np.asarray(idx[0])
     seed_sim: dict[int, float] = {}
     seed_indices = []
-    for sim, i in zip(sims, idx):
+    for sim, i in zip(sims[0], idx[0]):
         gid = entry.row_gids[int(i)]
         if gid is None:
             continue
@@ -81,23 +79,27 @@ def retrieve(ctx, property, query_vector, k_seeds, hops=2, limit=10,
                        "seed_similarity": seed_sim.get(int(i), 0.0)}
         return
 
-    # in-process fallback: k-hop neighborhood mask (device frontier)
-    # then personalized PageRank restarted on the seeds
-    mask = np.asarray(khop_neighborhood(graph, seed_indices, int(hops),
-                                        directed=False))
-    ranks, _, _ = personalized_pagerank(graph, seed_indices,
-                                        damping=float(damping),
-                                        max_iterations=100)
-    ranks = np.asarray(ranks)
-    scores = np.where(mask, ranks, 0.0)
-    order = np.argsort(-scores)[:int(limit)]
-    for i in order:
-        if scores[i] <= 0:
-            break
-        node = ctx.vertex_by_index(graph, int(i))
-        if node is not None:
-            yield {"node": node, "score": float(scores[i]),
-                   "seed_similarity": seed_sim.get(int(i), 0.0)}
+    # in-process: k-hop neighborhood mask (device frontier), then
+    # personalized PageRank restarted on the seeds; each phase ends in
+    # the readback that waits for its program
+    with mgtrace.span("graphrag.expand"):
+        mask = khop_neighborhood(graph, seed_indices, int(hops),
+                                 directed=False)
+    with mgtrace.span("graphrag.ppr"):
+        ranks, _, _ = personalized_pagerank(graph, seed_indices,
+                                            damping=float(damping),
+                                            max_iterations=100)
+    with mgtrace.span("graphrag.rows"):
+        scores = np.where(mask, ranks, 0.0)
+        rows = []
+        for i in np.argsort(-scores)[:int(limit)]:
+            if scores[i] <= 0:
+                break
+            node = ctx.vertex_by_index(graph, int(i))
+            if node is not None:
+                rows.append({"node": node, "score": float(scores[i]),
+                             "seed_similarity": seed_sim.get(int(i), 0.0)})
+    yield from rows
 
 
 @mgp.read_proc("graphrag.context",

@@ -25,6 +25,12 @@ Incremental maintenance design (four holes it closes):
 Rows live in a capacity-padded device matrix with a validity mask; delta
 refresh is one batched .at[rows].set scatter (device) + O(delta) MVCC
 reads (host) instead of an O(n) full scan.
+
+Observability: the phase spans ``vector.index`` (one ``_get_index``,
+whole), ``vector.refresh`` / ``vector.build`` (the miss it served) and
+``vector.search`` (upload, kernel, readback), and the counters
+``delta.vector_applied_total`` / ``delta.vector_rebuild_total``: a
+lookup that had to make an entry, by what made it.
 """
 
 from __future__ import annotations
@@ -37,15 +43,14 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from . import mgp
+from ..observability import trace as mgtrace
+from ..observability.metrics import global_metrics
 
 _CACHE_LOCK = threading.Lock()
 # storage (weak) -> {property_name: {version: _IndexEntry}}
 _CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 _KEEP_VERSIONS = 4          # concurrent readers at older snapshots
 _DELTA_MAX_FRACTION = 0.5   # larger deltas rebuild outright
-
-# observability (tests + SHOW METRICS INFO assert on these)
-STATS = {"full_builds": 0, "delta_refreshes": 0}
 
 
 @dataclass
@@ -80,7 +85,7 @@ def _read_vector(va, pid, view):
 
 def _full_build(ctx, pid, version) -> _IndexEntry:
     import jax.numpy as jnp
-    STATS["full_builds"] += 1
+    global_metrics.increment("delta.vector_rebuild_total")
     vectors, gids = [], []
     if pid is not None:
         for va in ctx.accessor.vertices(ctx.view):
@@ -191,7 +196,7 @@ def _delta_refresh(ctx, parent: _IndexEntry, changed, version):
         matrix = matrix.at[rows].set(vals)
         valid = valid.at[rows].set(1.0)
 
-    STATS["delta_refreshes"] += 1
+    global_metrics.increment("delta.vector_applied_total")
     return _IndexEntry(version, pid, parent.dim, dim_counts,
                        gid_to_row=gid_to_row, row_gids=row_gids,
                        free_rows=free_rows, offdim=offdim,
@@ -199,6 +204,16 @@ def _delta_refresh(ctx, parent: _IndexEntry, changed, version):
 
 
 def _get_index(ctx, property_name: str) -> _IndexEntry:
+    with mgtrace.span("vector.index") as sp:
+        entry, kind = _lookup_index(ctx, property_name)
+        if sp:
+            sp.set(kind=kind)
+    return entry
+
+
+def _lookup_index(ctx, property_name: str):
+    """(entry for the reader's snapshot, how it was come by: hit, alias,
+    delta, full)."""
     storage = ctx.storage
     version = getattr(ctx.accessor, "topology_snapshot",
                       storage.topology_version)
@@ -214,7 +229,7 @@ def _get_index(ctx, property_name: str) -> _IndexEntry:
         by_version = dict(per.get(property_name) or {})
     entry = by_version.get(version)
     if entry is not None and not own_writes:
-        return entry
+        return entry, "hit"
 
     parent = entry
     if parent is None:
@@ -235,18 +250,22 @@ def _get_index(ctx, property_name: str) -> _IndexEntry:
             changed = changed | own_writes
         if changed is not None and not changed:
             # nothing relevant changed: alias the parent at this version
-            entry = parent
+            entry, kind = parent, "alias"
         elif changed is not None and (
                 parent.size == 0
                 or len(changed) <= max(64,
                                        _DELTA_MAX_FRACTION * parent.size)):
-            entry = _delta_refresh(ctx, parent, changed, version)
+            with mgtrace.span("vector.refresh"):
+                entry = _delta_refresh(ctx, parent, changed, version)
+            kind = "delta"
     if entry is None:
         pid = storage.property_mapper.maybe_name_to_id(property_name)
-        entry = _full_build(ctx, pid, version)
+        with mgtrace.span("vector.build"):
+            entry = _full_build(ctx, pid, version)
+        kind = "full"
 
     if own_writes:
-        return entry                   # private view: never cached
+        return entry, kind             # private view: never cached
 
     with _CACHE_LOCK:
         per = _CACHE.get(storage)
@@ -259,17 +278,22 @@ def _get_index(ctx, property_name: str) -> _IndexEntry:
             del by_version[v]
         per[property_name] = by_version
         _CACHE[storage] = per
-    return entry
+    return entry, kind
 
 
 def _search_entry(entry: _IndexEntry, query_rows, k: int, metric: str):
-    """(scores (q, k'), row indices (q, k')) over live rows."""
+    """(scores (q, k'), row indices (q, k')) over live rows, read back
+    to the host; `query_rows` is host rows or a device array."""
+    import jax.numpy as jnp
     from ..ops.knn import knn
     k = min(k, entry.size)
     if k <= 0 or entry.matrix is None:
         return None, None
-    return knn(entry.matrix, query_rows, k=k, metric=metric,
-               valid_mask=entry.valid)
+    with mgtrace.span("vector.search"):
+        q = jnp.asarray(query_rows, dtype=jnp.float32)
+        scores, idx = knn(entry.matrix, q, k=k, metric=metric,
+                          valid_mask=entry.valid)
+        return np.asarray(scores), np.asarray(idx)
 
 
 @mgp.read_proc("vector_search.search",
@@ -278,13 +302,11 @@ def _search_entry(entry: _IndexEntry, query_rows, k: int, metric: str):
                opt_args=[("metric", "STRING", "cosine")],
                results=[("node", "NODE"), ("similarity", "FLOAT")])
 def search(ctx, property, query, limit, metric="cosine"):
-    import jax.numpy as jnp
     entry = _get_index(ctx, property)
-    q = jnp.asarray(np.asarray([query], dtype=np.float32))
-    scores, idx = _search_entry(entry, q, int(limit), str(metric))
+    scores, idx = _search_entry(entry, [query], int(limit), str(metric))
     if scores is None:
         return
-    for score, i in zip(np.asarray(scores[0]), np.asarray(idx[0])):
+    for score, i in zip(scores[0], idx[0]):
         gid = entry.row_gids[int(i)]
         if gid is None:
             continue
@@ -329,7 +351,6 @@ def ppr_search(ctx, property, query, k_seeds, limit, damping=0.85,
     is ONE coalesced round trip (batched with every concurrent caller,
     top-k extracted on device, result cache consulted); otherwise it
     runs in-process."""
-    import jax.numpy as jnp
     from ..ops.pagerank import personalized_pagerank
     from .graph_algorithms import _kernel_server_ppr
 
@@ -339,13 +360,12 @@ def ppr_search(ctx, property, query, k_seeds, limit, damping=0.85,
     graph = ctx.device_graph()
     if graph.n_nodes == 0:
         return
-    q = jnp.asarray(np.asarray([query], dtype=np.float32))
-    sims, idx = _search_entry(entry, q, int(k_seeds), str(metric))
+    sims, idx = _search_entry(entry, [query], int(k_seeds), str(metric))
     if sims is None:
         return
     seed_sim: dict[int, float] = {}
     seed_indices: list[int] = []
-    for sim, i in zip(np.asarray(sims[0]), np.asarray(idx[0])):
+    for sim, i in zip(sims[0], idx[0]):
         gid = entry.row_gids[int(i)]
         di = graph.gid_to_idx.get(gid) if gid is not None else None
         if di is not None:
@@ -394,7 +414,7 @@ def knn_get(ctx, node, property, k, metric="cosine"):
     if scores is None:
         return
     emitted = 0
-    for score, i in zip(np.asarray(scores[0]), np.asarray(idx[0])):
+    for score, i in zip(scores[0], idx[0]):
         if int(i) == row:
             continue
         if emitted >= int(k):

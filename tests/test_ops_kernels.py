@@ -226,7 +226,7 @@ def test_knn_cosine():
     rng = np.random.default_rng(0)
     corpus = rng.normal(size=(100, 16)).astype(np.float32)
     queries = corpus[:3] + 0.001 * rng.normal(size=(3, 16)).astype(np.float32)
-    scores, idx = knn(corpus, queries, k=5, metric="cosine", use_bf16=False)
+    scores, idx = knn(corpus, queries, k=5, metric="cosine")
     idx = np.asarray(idx)
     for qi in range(3):
         assert idx[qi, 0] == qi  # nearest neighbor of a near-copy is itself
@@ -236,7 +236,7 @@ def test_knn_l2():
     rng = np.random.default_rng(1)
     corpus = rng.normal(size=(50, 8)).astype(np.float32)
     q = rng.normal(size=(4, 8)).astype(np.float32)
-    _, idx = knn(corpus, q, k=3, metric="l2sq", use_bf16=False)
+    _, idx = knn(corpus, q, k=3, metric="l2sq")
     idx = np.asarray(idx)
     d = ((corpus[None, :, :] - q[:, None, :]) ** 2).sum(-1)
     exp = np.argsort(d, axis=1)[:, :3]
@@ -253,7 +253,7 @@ def test_ivf_recall():
     q = rng.normal(size=(5, 16)).astype(np.float32)
     index = IvfIndex(corpus, n_clusters=8)
     _, ids = index.search(q, k=10, n_probe=8)  # probe all cells → exact
-    _, exact = knn(corpus, q, k=10, metric="cosine", use_bf16=False)
+    _, exact = knn(corpus, q, k=10, metric="cosine")
     exact = np.asarray(exact)
     for qi in range(5):
         assert set(ids[qi]) == set(exact[qi])

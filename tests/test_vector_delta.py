@@ -8,6 +8,7 @@ src/storage/v2/indices/vector_index.cpp:22-73 (usearch update path).
 import numpy as np
 import pytest
 
+from memgraph_tpu.observability.metrics import global_metrics
 from memgraph_tpu.procedures import vector_search as vs
 from memgraph_tpu.query.interpreter import Interpreter, InterpreterContext
 from memgraph_tpu.storage import InMemoryStorage
@@ -16,6 +17,18 @@ from memgraph_tpu.storage import InMemoryStorage
 @pytest.fixture
 def db():
     return InterpreterContext(InMemoryStorage())
+
+
+def metric(name):
+    return dict((n, v) for n, _k, v in global_metrics.snapshot()).get(
+        name, 0.0)
+
+
+def counter(name):
+    """The index's counters (GET /stats section ``delta``):
+    ``delta.vector_applied_total`` moves with a change-log refresh,
+    ``delta.vector_rebuild_total`` with a full build."""
+    return metric(f"delta.vector_{name}_total")
 
 
 def run(db, q, params=None):
@@ -42,8 +55,8 @@ def test_streaming_inserts_use_delta_and_match_full_rebuild(db):
     _seed(db, n=30)
     q = [1.0, 0.0, 0.0, 0.0]
     _search(db, q)                      # prime: full build
-    full_builds_before = vs.STATS["full_builds"]
-    deltas_before = vs.STATS["delta_refreshes"]
+    full_builds_before = counter("rebuild")
+    deltas_before = counter("applied")
 
     # streaming inserts, a deletion, and an update across commits
     rng = np.random.default_rng(7)
@@ -55,9 +68,9 @@ def test_streaming_inserts_use_delta_and_match_full_rebuild(db):
     run(db, "MATCH (v:V {name: 'v002'}) SET v.emb = [9.0, 0.0, 0.0, 0.0]")
     got = _search(db, q)
 
-    assert vs.STATS["full_builds"] == full_builds_before, \
+    assert counter("rebuild") == full_builds_before, \
         "streaming updates triggered full rebuilds"
-    assert vs.STATS["delta_refreshes"] > deltas_before
+    assert counter("applied") > deltas_before
 
     # parity: identical results from a cold full rebuild
     vs._CACHE.clear()
@@ -101,7 +114,7 @@ def test_dimension_flip_triggers_full_rebuild(db):
         run(db, "CREATE (:V {name: $n, emb: [1.0, $i]})",
             {"n": f"d2_{i}", "i": float(i)})
     assert len(_search(db, [1.0, 0.0])) == 3
-    before_full = vs.STATS["full_builds"]
+    before_full = counter("rebuild")
     # add 4 three-dimensional vectors one commit at a time: dominance flips
     for i in range(4):
         run(db, "CREATE (:V {name: $n, emb: [1.0, $i, 0.5]})",
@@ -109,7 +122,7 @@ def test_dimension_flip_triggers_full_rebuild(db):
     got = run(db, "CALL vector_search.search('emb', [1.0,0.0,0.5], 50) "
                   "YIELD node RETURN node.name ORDER BY node.name")
     assert [r[0] for r in got] == ["d3_0", "d3_1", "d3_2", "d3_3"]
-    assert vs.STATS["full_builds"] > before_full
+    assert counter("rebuild") > before_full
 
 
 def test_replica_wal_apply_feeds_delta_refresh():
@@ -130,14 +143,14 @@ def test_replica_wal_apply_feeds_delta_refresh():
         main.execute(f'REGISTER REPLICA r1 SYNC TO "127.0.0.1:{port}"')
         # prime the REPLICA's index (full build once)
         assert len(_search(replica_ictx, [1.0, 0.0, 0.0, 0.0])) == 10
-        full_before = vs.STATS["full_builds"]
+        full_before = counter("rebuild")
         # streamed inserts arrive via WAL apply on the replica
         for i in range(5):
             run(main_ictx, "CREATE (:V {name: $n, emb: [1.0,0.0,0.0,$i]})",
                 {"n": f"w{i}", "i": float(i)})
             got = _search(replica_ictx, [1.0, 0.0, 0.0, 0.0])
             assert len(got) == 10 + i + 1
-        assert vs.STATS["full_builds"] == full_before, \
+        assert counter("rebuild") == full_before, \
             "replica WAL apply forced full rebuilds"
     finally:
         if getattr(replica_ictx, "replication", None) and \
@@ -207,3 +220,37 @@ def test_background_index_drop_race():
     event.wait(20)
     assert not storage.indices.label.has(lid)
     assert storage.indices.label.candidates(lid) is None
+
+
+def test_spans_and_counters_account_for_every_lookup(db):
+    """One ``vector.index`` span a lookup and one ``vector.search`` a
+    search; a lookup that had to make an entry closes ``vector.build``
+    or ``vector.refresh`` once and moves the matching counter, so the
+    two counters add up to the lookups that were no hit and no alias."""
+    from memgraph_tpu.observability import trace as T
+    _seed(db, n=12)
+    q = [1.0, 0.0, 0.0, 0.0]
+    names = ("index", "refresh", "build", "search")
+    T.enable()
+    try:
+        T.TRACER.reset()
+        before = {n: metric(f"span.vector.{n}.count") for n in names}
+        applied, rebuilt = counter("applied"), counter("rebuild")
+        _search(db, q)                              # full build
+        _search(db, q)                              # hit
+        run(db, "CREATE (:V {name: 'v900', emb: [1.0, 0.0, 0.0, 0.0]})")
+        _search(db, q)                              # change-log refresh
+        run(db, "CREATE (:W {name: 'no embedding'})")
+        _search(db, q)                              # refresh, nothing to set
+        _search(db, q)                              # hit
+        got = {n: metric(f"span.vector.{n}.count") - before[n] for n in names}
+        kinds = [s["attrs"].get("kind")
+                 for spans in T.TRACER.finished_traces()
+                 for s in spans if s["name"] == "vector.index"]
+    finally:
+        T.disable()
+        T.TRACER.reset()
+    assert got == {"index": 5, "refresh": 2, "build": 1, "search": 5}
+    assert sorted(kinds) == ["delta", "delta", "full", "hit", "hit"]
+    assert counter("rebuild") - rebuilt == 1 == kinds.count("full")
+    assert counter("applied") - applied == 2 == kinds.count("delta")
