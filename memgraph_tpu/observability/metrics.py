@@ -147,6 +147,8 @@ STAT_NAMES = (
     "delta.fallback_rebuild_total",  # wrapped log / failed splice colds
     "delta.plan_applied_total",     # in-process CALL: MXU DeltaPlan refresh
     "delta.plan_rebuild_total",     # in-process CALL: full MXU plan build
+    "delta.export_applied_total",   # GraphCache miss served by the splice
+    "delta.export_rebuild_total",   # GraphCache miss served by a full export
     "delta.columnar_applied_total",  # columnar cache miss served by a patch
     "delta.columnar_rebuild_total",  # columnar cache miss served by a sweep
     "delta.columnar_patch_failed_total",  # of those: a patch that raised

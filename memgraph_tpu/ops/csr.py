@@ -268,27 +268,40 @@ def export_csr_delta(prev: DeviceGraph, accessor, changed_gids,
                      edge_type_filter=None, pad: bool = True,
                      to_device: bool = True):
     """O(changed) re-export: splice the changed vertices' edges into the
-    previous snapshot's host arrays instead of walking ALL edges in
-    Python (the full export is the dominant per-version cost at 10M
-    edges). Valid only while the VERTEX SET of the view is unchanged —
-    returns None when it cannot guarantee that (caller falls back to
-    export_csr). Rebuild = drop every edge incident to a changed vertex
+    previous snapshot's host arrays instead of walking ALL vertices and
+    edges in Python (the full export is the dominant per-version cost at
+    10M edges). Rebuild = drop every edge incident to a changed vertex
     from the previous COO, append the changed vertices' current edges
     read from storage (O(changed x degree)), then one native/numpy
     from_coo pass.
+
+    The view's vertex set may GROW across the gap: a changed vertex that
+    the accessor sees (with the filter's label, where one is set) and
+    that `prev` lacks JOINS, at the next dense index after
+    `prev.n_nodes`, in ascending gid. It has no edge in `prev.host_coo`,
+    so the rule for every changed vertex (all its out-edges re-emit, and
+    its in-edges from unchanged sources) covers it, also where it gained
+    the label and brings edges it already had. For vertices committed in
+    creation order the result is export_csr's, array for array; a vertex
+    that joins behind a younger one stands later in the dense order than
+    export_csr would put it, the same graph under `node_gids`.
+
+    The set may not SHRINK: removing a row shifts every dense id behind
+    it. Returns None (caller falls back to export_csr) for a vertex of
+    `prev` that left the view (deleted, lost the label), a vertex gone
+    from storage, an edge whose other endpoint is neither in `prev` nor
+    joined, and a `prev` without host arrays. A changed vertex that is
+    in neither view (created and deleted inside the gap, or one that
+    never carried the label) is skipped: it has no row and, being
+    invisible or unindexed, no edge that export_csr would emit.
     """
     if prev.host_coo is None:
         return None
     storage = accessor.storage
-    changed = list(changed_gids)
-    bitmap = np.zeros(prev.n_nodes, dtype=bool)
-    from ..storage.storage import VertexAccessor
-    fresh_src: list = []
-    fresh_dst: list = []
-    fresh_w: list = []
-    has_w = weight_property is not None
-    for gid in changed:
-        idx = prev.gid_to_idx.get(gid)
+    from ..storage.storage import EdgeAccessor, VertexAccessor
+    in_view = []                  # (gid, vertex) with a row in the result
+    joined = []
+    for gid in changed_gids:
         vertex = storage._vertices.get(gid)
         if vertex is None:
             return None               # vertex gone: node set changed
@@ -296,13 +309,31 @@ def export_csr_delta(prev: DeviceGraph, accessor, changed_gids,
         visible = va.is_visible(View.OLD)
         if label_filter is not None and visible:
             visible = va.has_label(label_filter, View.OLD)
-        if idx is None or not visible:
-            return None               # joined/left the view: full export
+        if gid in prev.gid_to_idx:
+            if not visible:
+                return None           # left the view: dense ids shift
+        elif visible:
+            joined.append(gid)
+        else:
+            continue                  # in neither view: no row, no edge
+        in_view.append((gid, vertex))
+    joined.sort()
+    joined_idx = {gid: prev.n_nodes + i for i, gid in enumerate(joined)}
+
+    def index_of(gid):
+        idx = prev.gid_to_idx.get(gid)
+        return joined_idx.get(gid) if idx is None else idx
+
+    n_nodes = prev.n_nodes + len(joined)
+    rows = [(index_of(gid), vertex) for gid, vertex in in_view]
+    bitmap = np.zeros(n_nodes, dtype=bool)
+    for idx, _vertex in rows:
         bitmap[idx] = True
-    from ..storage.storage import EdgeAccessor
-    for gid in changed:
-        idx = prev.gid_to_idx[gid]
-        vertex = storage._vertices[gid]
+    fresh_src: list = []
+    fresh_dst: list = []
+    fresh_w: list = []
+    has_w = weight_property is not None
+    for idx, vertex in rows:
         # raw MVCC state, NOT VertexAccessor.out_edges/in_edges: those
         # apply the SESSION's fine-grained permissions (_fg_edge_ok),
         # and a globally cached snapshot must match export_csr's
@@ -315,9 +346,9 @@ def export_csr_delta(prev: DeviceGraph, accessor, changed_gids,
             ea = EdgeAccessor(edge, accessor)
             if not ea.is_visible(View.OLD):
                 continue
-            di = prev.gid_to_idx.get(edge.to_vertex.gid)
+            di = index_of(edge.to_vertex.gid)
             if di is None:
-                return None           # new endpoint: node set changed
+                return None           # endpoint in neither prev nor joined
             # every out-edge of a changed vertex re-emits exactly once
             # here; edges INTO a changed vertex from an UNCHANGED source
             # re-emit in the in_edges pass below
@@ -333,7 +364,7 @@ def export_csr_delta(prev: DeviceGraph, accessor, changed_gids,
             ea = EdgeAccessor(edge, accessor)
             if not ea.is_visible(View.OLD):
                 continue
-            si = prev.gid_to_idx.get(edge.from_vertex.gid)
+            si = index_of(edge.from_vertex.gid)
             if si is None:
                 return None
             if bitmap[si]:
@@ -353,8 +384,12 @@ def export_csr_delta(prev: DeviceGraph, accessor, changed_gids,
     if has_w:
         weights = np.concatenate(
             [p_w[keep], np.asarray(fresh_w, dtype=np.float32)])
-    g = from_coo(src, dst, weights, n_nodes=prev.n_nodes,
-                 node_gids=prev.node_gids, pad=pad)
+    node_gids = prev.node_gids
+    if joined:
+        node_gids = np.concatenate(
+            [node_gids, np.asarray(joined, dtype=np.int64)])
+    g = from_coo(src, dst, weights, n_nodes=n_nodes,
+                 node_gids=node_gids, pad=pad)
     return g.to_device() if to_device else g
 
 
@@ -532,6 +567,13 @@ class GraphCache:
     `storage.topology_version` is unchanged; any commit that touches
     topology (or properties, conservatively) bumps the version.
 
+    A miss with an older snapshot of the same view in the cache follows
+    the change log (export_csr_delta: edge and property changes, and
+    vertices that join the view); a vertex that left, a gap the log
+    cannot answer or a large change set takes the full export_csr. Which
+    of the two served a miss is counted: `delta.export_applied_total` /
+    `delta.export_rebuild_total`.
+
     Keyed on the storage object itself via a WeakKeyDictionary so snapshots
     die with their storage (no id()-recycling hazard, no leak).
     """
@@ -578,6 +620,7 @@ class GraphCache:
                     newest = (k[0], v)
         if hit is not None:
             return hit
+        from ..observability.metrics import global_metrics
         g = None
         # O(changed) incremental export (the python walk over ALL edges
         # is the dominant per-version cost at 10M+ edges); bulk commits
@@ -592,7 +635,6 @@ class GraphCache:
                 # gap — full export, LOUDLY counted (a silently-partial
                 # delta here would cache a wrong snapshot)
                 import logging
-                from ..observability.metrics import global_metrics
                 global_metrics.increment("delta.fallback_rebuild_total")
                 logging.getLogger(__name__).info(
                     "change log unknowable (%s) for versions (%d, %d]; "
@@ -613,7 +655,10 @@ class GraphCache:
                         "delta CSR export failed; falling back to full "
                         "export", exc_info=True)
                     g = None
-        if g is None:
+        if g is not None:
+            global_metrics.increment("delta.export_applied_total")
+        else:
+            global_metrics.increment("delta.export_rebuild_total")
             g = export_csr(accessor, weight_property=weight_property,
                            label_filter=label_filter,
                            edge_type_filter=edge_type_filter)
