@@ -109,6 +109,7 @@ STAT_NAMES = (
     # PPR serving plane (r16): coalesced batched multi-source PPR
     "ppr.requests_total",
     "ppr.batches_total",
+    "ppr.riders_total",            # members of every executed batch
     "ppr.batch_size",              # histogram of executed batch widths
     "ppr.coalesced_total",         # requests that shared a batch
     "ppr.cache_hit_total",

@@ -123,6 +123,9 @@ SPAN_NAMES = (
     "kernel.request",      # client->kernel-server round trip
     "kernel.dispatch",     # server-side supervised dispatch
     "kernel.generation",   # its resident generation: delta decode, splice
+    "ppr.queue",           # PPR plane: a rider enqueued -> its batch starts
+    "ppr.batch",           # one batch: pack, upload, fixpoint, top-k, readback
+    "ppr.reply",           # its cache fill and the riders' reply arrays
     "analytics.route_meta",  # change log -> what a routed CALL sends
     "device.transfer",     # partition-centric blocking + device_put
     "device.chunk",        # one compiled chunk of device iterations
@@ -179,6 +182,9 @@ PHASES = {
     "kernel.request": (),
     "kernel.dispatch": (),
     "kernel.generation": (),
+    "ppr.queue": (),
+    "ppr.batch": (),
+    "ppr.reply": (),
     "analytics.route_meta": (),
     "device.transfer": ("device_transfer",),
     "device.chunk": ("device_iterate", "semiring_{backend}"),

@@ -18,7 +18,6 @@ int8-streaming variants (semiring.PRECISION_BOUNDS documents the bounds).
 from __future__ import annotations
 
 import threading
-from functools import partial
 
 import jax.numpy as jnp
 import numpy as np
@@ -422,7 +421,8 @@ def _build_ppr_batch(n_out: int, max_iterations: int, precision: str,
                      warm: bool):
     import jax
 
-    def run(A, P, x0):
+    # the name the program has in a device trace: jit_ppr_batch
+    def ppr_batch(A, P, x0):
         # batched analog of _ppr_setup: identical hoisted invariants,
         # personalization columns normalized per lane
         n_nodes = P["n_nodes"]
@@ -446,7 +446,9 @@ def _build_ppr_batch(n_out: int, max_iterations: int, precision: str,
             dangling_mass = jnp.sum(x * dangling_f[:, None], axis=0)
             new_x = (1.0 - P["damping"]) * pm \
                 + P["damping"] * (acc + dangling_mass[None, :] * pm)
-            new_err = jnp.sum(jnp.abs(new_x - x), axis=0)
+            # once an iteration: what a trace counts iterations by
+            with jax.named_scope("ppr_converged"):
+                new_err = jnp.sum(jnp.abs(new_x - x), axis=0)
             # freeze converged lanes: their retained iterate is exactly
             # the sequential loop's stopping state
             x = jnp.where(done[None, :], x, new_x)
@@ -469,7 +471,7 @@ def _build_ppr_batch(n_out: int, max_iterations: int, precision: str,
     # iterate — the serving plane builds a fresh x0 per batch, so the
     # seed never needs to outlive the call (cold runs pass x0=None:
     # nothing to donate, pm doubles as the start AND the restart vector)
-    return jax.jit(run, donate_argnums=(2,))
+    return jax.jit(ppr_batch, donate_argnums=(2,))
 
 
 def personalized_pagerank_batch(graph: DeviceGraph, source_sets,
@@ -545,23 +547,34 @@ def personalized_pagerank_batch(graph: DeviceGraph, source_sets,
 _PPR_TOPK_CACHE: dict = {}
 
 
+def _build_ppr_topk(k: int):
+    import jax
+
+    def topk(m, n_nodes):
+        # columns past n_nodes are padding: masked, not cut, so the
+        # program follows the padded shape and not the vertex count
+        live = jnp.arange(m.shape[1], dtype=jnp.int32) < n_nodes
+        return jax.lax.top_k(jnp.where(live[None, :], m, -jnp.inf), k)
+
+    # the name the program has in a device trace: jit_ppr_topk
+    topk.__name__ = "ppr_topk"
+    return jax.jit(topk)
+
+
 def ppr_topk(ranks_matrix, n_nodes: int, k: int, raw: bool = False):
     """Per-lane top-k over a (B, n) rank matrix ON DEVICE — the serving
     plane extracts each request's answer before the reply ships, so a
     top-10 query never pays an O(n) result transfer per rider beyond
-    the batch's own cache fill.
+    the batch's own cache fill. n may be padded past ``n_nodes``.
 
     Returns (values (B, k), indices (B, k)) as host arrays, or as
     DEVICE handles with ``raw=True`` so the serving plane can fold them
     into its one fused result transfer per batch (mglint MG009)."""
-    import jax
-    m = jnp.asarray(ranks_matrix)[:, :n_nodes]
     k = max(1, min(int(k), int(n_nodes)))
     fn = _PPR_TOPK_CACHE.get(k)
     if fn is None:
-        fn = _PPR_TOPK_CACHE[k] = jax.jit(
-            partial(jax.lax.top_k, k=k))
-    vals, idx = fn(m)
+        fn = _PPR_TOPK_CACHE[k] = _build_ppr_topk(k)
+    vals, idx = fn(jnp.asarray(ranks_matrix), np.int32(n_nodes))
     if raw:
         return vals, idx
     return np.asarray(vals), np.asarray(idx)  # mglint: disable=MG009 — host-array return contract for direct callers; the serving plane passes raw=True and folds these into its one fused device_get per chunk

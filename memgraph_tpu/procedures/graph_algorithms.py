@@ -64,6 +64,17 @@ def _rank_results(ctx, graph, values, field_name):
         mgtrace.record_span("analytics.consume", started, outside)
 
 
+def _top_rank_results(ctx, graph, indices, values, field_name):
+    """The rows of a top-k answer, in the order given."""
+    with mgtrace.span("analytics.rows"):
+        rows = []
+        for i, value in zip(indices, values):
+            node = ctx.vertex_by_index(graph, int(i))
+            if node is not None:
+                rows.append({"node": node, field_name: float(value)})
+    yield from rows
+
+
 def _kernel_route_socket(ctx) -> str | None:
     """The resident-kernel-server socket analytics should route through,
     or None for the in-process path. Config key ``kernel_server_socket``
@@ -327,11 +338,23 @@ for _name in ("pagerank.get", "pagerank_tpu.get", "pagerank_online.get"):
 @mgp.read_proc("pagerank.personalized",
                args=[("source_nodes", "LIST")],
                opt_args=[("max_iterations", "INTEGER", 100),
-                         ("damping_factor", "FLOAT", 0.85)],
+                         ("damping_factor", "FLOAT", 0.85),
+                         ("top_k", "INTEGER", None)],
                results=[("node", "NODE"), ("rank", "FLOAT")])
 def personalized_pagerank(ctx, source_nodes, max_iterations=100,
-                          damping_factor=0.85):
+                          damping_factor=0.85, top_k=None):
+    """Personalized PageRank restarted uniformly on ``source_nodes``.
+
+    ``top_k`` (not in the reference's signature) asks for the best k
+    rows only, best first, ties by the lower dense index: through a
+    resident kernel server the plane takes them on the device and k
+    rows cross the socket instead of a rank per vertex. Without it,
+    one row per vertex in the graph's order."""
     from ..ops.pagerank import personalized_pagerank as ppr
+    if top_k is not None and int(top_k) < 1:
+        from ..exceptions import QueryException
+        raise QueryException("pagerank.personalized: top_k must be a "
+                             "positive integer")
     graph = ctx.device_graph()
     if graph.n_nodes == 0:
         return
@@ -339,16 +362,26 @@ def personalized_pagerank(ctx, source_nodes, max_iterations=100,
                if v is not None and v.gid in graph.gid_to_idx]
     if not sources:
         return
+    k = 0 if top_k is None else min(int(top_k), graph.n_nodes)
     served = _kernel_server_ppr(ctx, graph, sources,
                                 float(damping_factor),
-                                int(max_iterations), 1e-6)
-    if served is not None:
-        _h, out = served
-        ranks = np.asarray(out["ranks"])[:graph.n_nodes]
+                                int(max_iterations), 1e-6, top_k=k)
+    if served is not None and k:
+        best, values = served[1]["topk_idx"], served[1]["topk_val"]
     else:
-        ranks, _, _ = ppr(graph, sources, damping=float(damping_factor),
-                          max_iterations=int(max_iterations))
-    yield from _rank_results(ctx, graph, np.asarray(ranks), "rank")
+        if served is not None:
+            ranks = served[1]["ranks"]
+        else:
+            ranks, _, _ = ppr(graph, sources,
+                              damping=float(damping_factor),
+                              max_iterations=int(max_iterations))
+        ranks = np.asarray(ranks)[:graph.n_nodes]
+        if not k:
+            yield from _rank_results(ctx, graph, ranks, "rank")
+            return
+        best = np.argsort(-ranks, kind="stable")[:k]
+        values = ranks[best]
+    yield from _top_rank_results(ctx, graph, best, values, "rank")
 
 
 def _katz_impl(ctx, alpha=0.2, epsilon=1e-2):

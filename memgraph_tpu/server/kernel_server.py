@@ -570,8 +570,12 @@ class _PprPending:
 def _topk_host(vec: np.ndarray, k: int):
     """Host-side top-k for cache hits (no device round trip)."""
     k = max(1, min(int(k), len(vec)))
-    idx = np.argpartition(-vec, k - 1)[:k]
-    idx = idx[np.argsort(-vec[idx], kind="stable")]
+    # everything at or above the k-th best, in index order, then a
+    # stable sort: ties go to the lower index, as the device's top_k
+    # breaks them, so a hit and a computed answer are the same rows
+    kth = np.partition(vec, len(vec) - k)[len(vec) - k]
+    idx = np.flatnonzero(vec >= kth)
+    idx = idx[np.argsort(-vec[idx], kind="stable")][:k]
     return vec[idx].astype(np.float32), idx.astype(np.int32)
 
 
@@ -601,6 +605,7 @@ class PprServingPlane:
         self._thread = None
         self._thread_lock = tracked_lock("PprServingPlane._thread_lock")
         self._graph_versions: dict = {}   # batcher-thread only
+        self._warmed: set = set()         # batcher-thread only
 
     # --- request side (connection threads) ---------------------------------
 
@@ -837,18 +842,26 @@ class PprServingPlane:
         server = self.server
         did = server._dispatch_begin(server.wedge_after_s)
         global_metrics.increment("ppr.batches_total")
+        global_metrics.increment("ppr.riders_total", delta=len(members))
         global_metrics.observe("ppr.batch_size", float(len(members)))
         if len(members) > 1:
             global_metrics.increment("ppr.coalesced_total",
                                      delta=len(members))
         t0 = time.perf_counter()
         t_wall = time.time()
+        now = time.monotonic()
+        for m in members:
+            # what a rider waited: the window it was held for, and the
+            # batches that ran ahead of its own
+            waited = now - m.t_enqueued
+            mgtrace.record_span("ppr.queue", t_wall - waited, waited)
         acc = mgstats.StageAccumulator()
         results = None
         live = []
         try:
             try:
-                with mgstats.collecting_stages(acc):
+                with mgstats.collecting_stages(acc), \
+                        mgtrace.span("ppr.batch", riders=len(members)):
                     with server._dispatch_lock:
                         device_fault_point()
                         g = self._resolve_group_graph(members)
@@ -881,16 +894,65 @@ class PprServingPlane:
             stages = {name: {"seconds": slot["seconds"] * share,
                              "count": slot["count"]}
                       for name, slot in snap.items()} if snap else None
-            for m, res in zip(live, results):
-                ranks, err, iters, cache_state, topk = res
-                m.reply, m.out_arrays = self._reply_from_vector(
-                    m.header, ranks, err, iters, cache=cache_state,
-                    batch_size=len(members),
-                    coalesced=len(members) > 1, stages=stages,
-                    carrier=m.carrier, t_wall=t_wall, dur=dur,
-                    topk=topk)
-                server._count("completed")
-                m.event.set()
+            with mgtrace.span("ppr.reply", riders=len(live)):
+                self._fill_cache(g, live, results)
+                for m, res in zip(live, results):
+                    ranks, err, iters, cache_state, topk = res
+                    m.reply, m.out_arrays = self._reply_from_vector(
+                        m.header, ranks, err, iters, cache=cache_state,
+                        batch_size=len(members),
+                        coalesced=len(members) > 1, stages=stages,
+                        carrier=m.carrier, t_wall=t_wall, dur=dur,
+                        topk=topk)
+                    server._count("completed")
+                    m.event.set()
+        finally:
+            server._dispatch_end(did)
+        if live:
+            self._warm_lane_buckets(g, live)
+
+    def _warm_lane_buckets(self, g, members) -> None:
+        """Once per graph shape and parameter group, right after its
+        first batch has been answered: run the batched program and its
+        top-k once at every lane bucket a batch can be padded to, so
+        that each is compiled (or loaded from the compile cache) now. A
+        bucket first met under load compiles inside its riders'
+        requests with every other rider queued behind it: 2.8-3.8 s a
+        program on a v5e's host, 9 such compiles in one 51 s window of
+        twelve clients (PERF.md section 5, PR 35). Warm-started batches
+        (a seed matrix after a commit) are other programs and are not
+        warmed here."""
+        from ..ops.pagerank import (_PPR_LANE_BUCKETS, _bucket_lanes,
+                                    personalized_pagerank_batch, ppr_topk)
+        h0 = members[0].header
+        params = (float(h0.get("damping", 0.85)), float(h0.get("tol", 1e-6)),
+                  int(h0.get("max_iterations", 100)),
+                  str(h0.get("precision", "f32")))
+        top_k = max(int(m.header.get("top_k") or 0) for m in members)
+        key = (g.n_pad, int(g.csc_src.shape[0]), params[2:], top_k)
+        if key in self._warmed:
+            return
+        self._warmed.add(key)
+        server = self.server
+        widest = min(self.max_batch, _ppr_chunk_lanes(
+            g.n_nodes, g.n_edges, server.hbm_budget_bytes))
+        sources = [np.asarray(members[0].arrays["sources"], dtype=np.int32)]
+        did = server._dispatch_begin(server.wedge_after_s)
+        try:
+            with server._dispatch_lock:
+                for lanes in _PPR_LANE_BUCKETS:
+                    if lanes >= 2 * widest:
+                        break
+                    if lanes == _bucket_lanes(len(members)):
+                        continue        # the batch just ran it
+                    x_dev, _err, _iters = personalized_pagerank_batch(
+                        g, sources * lanes, damping=params[0],
+                        max_iterations=params[2], tol=params[1],
+                        precision=params[3], raw=True)
+                    if top_k:
+                        ppr_topk(x_dev.T, g.n_nodes, top_k, raw=True)
+        except Exception:   # noqa: BLE001 — warming is best effort
+            log.exception("ppr: warming the lane buckets failed")
         finally:
             server._dispatch_end(did)
 
@@ -906,8 +968,6 @@ class PprServingPlane:
         tol = float(h0.get("tol", 1e-6))
         max_iterations = int(h0.get("max_iterations", 100))
         precision = str(h0.get("precision", "f32"))
-        graph_key = h0.get("graph_key")
-        version = self._graph_versions.get(graph_key, 0)
 
         live = []
         for m in members:
@@ -966,8 +1026,10 @@ class PprServingPlane:
             tvals = tidx = None
             device_out = [x_dev, err_dev, iter_dev]
             if k_max > 0:
-                device_out += list(ppr_topk(x_dev.T[:len(chunk)],
-                                            g.n_nodes, k_max, raw=True))
+                # over every lane of the bucket, padding included: one
+                # program a bucket, not one a rider count
+                device_out += list(ppr_topk(x_dev.T, g.n_nodes, k_max,
+                                            raw=True))
             # THE one fused host sync per chunk: every device output of
             # the batch (iterate, per-lane err/iters, top-k) crosses in
             # a single device_get instead of one transfer per epilogue
@@ -980,14 +1042,6 @@ class PprServingPlane:
             warm_set = set(warm_lanes)
             for lane, m in enumerate(chunk):
                 vec = np.ascontiguousarray(ranks[lane])
-                if graph_key is not None:
-                    ckey = self.cache.key(
-                        graph_key, m.arrays["sources"], damping, tol,
-                        precision)
-                    self.cache.insert(ckey, _PprCacheEntry(
-                        version, vec, float(errs[lane]),
-                        int(iters[lane]),
-                        _source_neighborhood(g, m.arrays["sources"])))
                 topk = (tvals[lane], tidx[lane]) \
                     if tvals is not None else None
                 results.append((vec, float(errs[lane]),
@@ -995,6 +1049,25 @@ class PprServingPlane:
                                 "warm" if lane in warm_set else "miss",
                                 topk))
         return live, results
+
+    def _fill_cache(self, g, live, results) -> None:
+        """Every rider's vector into the result cache, under the
+        version its batch resolved the graph at."""
+        if not live:
+            return
+        h0 = live[0].header
+        graph_key = h0.get("graph_key")
+        if graph_key is None:
+            return
+        version = self._graph_versions.get(graph_key, 0)
+        for m, (vec, err, iters, _state, _topk) in zip(live, results):
+            ckey = self.cache.key(
+                graph_key, m.arrays["sources"],
+                float(h0.get("damping", 0.85)), float(h0.get("tol", 1e-6)),
+                str(h0.get("precision", "f32")))
+            self.cache.insert(ckey, _PprCacheEntry(
+                version, vec, err, iters,
+                _source_neighborhood(g, m.arrays["sources"])))
 
 
 # --------------------------------------------------------------------------
@@ -1836,13 +1909,22 @@ class KernelClient:
         self._sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         self._sock.settimeout(timeout)
         self._sock.connect(socket_path)
+        #: False while a call is between its request and its whole
+        #: reply: the stream then holds part of a frame, and the
+        #: connection cannot carry another call
+        self.in_sync = True
 
     def settimeout(self, timeout: float | None) -> None:
         self._sock.settimeout(timeout)
 
     def call(self, header: dict, arrays=None):
+        """One request and its reply. The stream carries one call at a
+        time: two threads never share a KernelClient
+        (:class:`SupervisedKernelClient` leases one per call)."""
+        self.in_sync = False
         _send_msg(self._sock, header, arrays)
         h, out = _recv_msg(self._sock)
+        self.in_sync = True
         # spans the server recorded for OUR trace come home on the
         # reply; adopt them so the retained trace is connected
         spans = h.pop("trace_spans", None)
@@ -2087,35 +2169,32 @@ class SupervisedKernelClient:
         self.idle_timeout_s = idle_timeout_s
         self.deadline_s = deadline_s
         self.spawn = spawn
-        # leaf lock guarding the (client, pid) pair: swapped by the
+        # leaf lock guarding the pool and the pid: touched by every
         # caller thread AND the health loop; network I/O always happens
         # OUTSIDE it
         self._state_lock = tracked_lock("SupervisedKernelClient._state_lock")
-        self._client: KernelClient | None = None
+        self._idle: list[KernelClient] = []
+        self._epoch = 0         # bumped by _drop(): older leases close
         self._pid: int | None = None
         self._stop = threading.Event()
         self._health_thread = None
-        shared_field(self, "_client", "_pid")
+        shared_field(self, "_idle", "_pid")
 
     # --- connection management ---------------------------------------------
+    #
+    # The daemon's stream protocol carries one call at a time, and the
+    # Bolt server runs queries on many threads through ONE supervised
+    # client per daemon (shared_client). So every in-flight call leases
+    # a connection of its own: concurrent callers neither share a stream
+    # nor wait for each other, and the daemon, which serves each
+    # connection on its own thread, sees them as concurrently as they
+    # were sent (its PPR plane can only coalesce riders it holds at
+    # once). Connections are opened on demand and kept for reuse.
 
-    def _install(self, client: KernelClient | None):
-        from ..utils.sanitize import shared_write
-        with self._state_lock:
-            shared_write(self, "_client")
-            old, self._client = self._client, client
-        if old is not None:
-            try:
-                old.close()
-            except OSError as e:
-                log.debug("closing stale kernel client: %s", e)
-        return client
-
-    def _current(self) -> KernelClient | None:
-        from ..utils.sanitize import shared_read
-        with self._state_lock:
-            shared_read(self, "_client")
-            return self._client
+    #: idle connections kept for reuse, one for each of the Bolt
+    #: server's executor threads (server/bolt.py); a lease returned
+    #: beyond that is closed
+    POOL_KEEP = 32
 
     def _set_pid(self, pid: int | None) -> None:
         from ..utils.sanitize import shared_write
@@ -2129,10 +2208,7 @@ class SupervisedKernelClient:
             shared_read(self, "_pid")
             return self._pid
 
-    def _connect(self) -> KernelClient:
-        c = self._current()
-        if c is not None:
-            return c
+    def _open(self) -> KernelClient:
         timeout = self.retry.attempt_timeout or 300.0
         if self.spawn:
             c = ensure_server(self.socket_path,
@@ -2150,10 +2226,49 @@ class SupervisedKernelClient:
             self._set_pid(h.get("pid"))
         except (OSError, ConnectionError) as e:
             log.debug("post-connect ping failed: %s", e)
-        return self._install(c)
+        return c
+
+    def _lease(self) -> tuple:
+        """(connection, epoch) for one call: an idle one, else new."""
+        from ..utils.sanitize import shared_write
+        with self._state_lock:
+            shared_write(self, "_idle")
+            epoch = self._epoch
+            if self._idle:
+                return self._idle.pop(), epoch
+        return self._open(), epoch
+
+    def _release(self, lease: tuple) -> None:
+        """Back to the pool, unless a call was cut short on it, the pool
+        was dropped since it was leased, or the pool is full."""
+        from ..utils.sanitize import shared_write
+        c, epoch = lease
+        with self._state_lock:
+            shared_write(self, "_idle")
+            if c.in_sync and epoch == self._epoch \
+                    and len(self._idle) < self.POOL_KEEP:
+                self._idle.append(c)
+                return
+        self._close(c)
+
+    @staticmethod
+    def _close(c: KernelClient) -> None:
+        try:
+            c.close()
+        except OSError as e:
+            log.debug("closing kernel client: %s", e)
 
     def _drop(self) -> None:
-        self._install(None)
+        """Forget every connection (the daemon is gone or being
+        replaced): the idle ones close now, the leased ones when their
+        calls return."""
+        from ..utils.sanitize import shared_write
+        with self._state_lock:
+            shared_write(self, "_idle")
+            idle, self._idle = self._idle, []
+            self._epoch += 1
+        for c in idle:
+            self._close(c)
 
     # --- supervision --------------------------------------------------------
 
@@ -2254,12 +2369,13 @@ class SupervisedKernelClient:
         supervised op shares (pagerank, ppr, ...)."""
         last: Exception | None = None
         for _attempt in self.retry.attempts():
+            lease = None
             try:
-                c = self._connect()
+                lease = self._lease()
                 t0 = time.perf_counter()
                 with mgtrace.span("kernel.request", op=op,
                                   attempt=_attempt):
-                    result = invoke(c)
+                    result = invoke(lease[0])
                 # client-observed dispatch wall time (request + device +
                 # reply) for the caller's PROFILE attribution
                 mgstats.record_stage("kernel_dispatch",
@@ -2283,13 +2399,16 @@ class SupervisedKernelClient:
                     "kernel_server.client.retries_total")
             except (ConnectionError, OSError) as e:
                 # daemon gone (device.lost kill) or socket timed out:
-                # drop the connection; _connect respawns when allowed
+                # drop the connections; _open respawns when allowed
                 last = e
                 self._drop()
                 if not idempotent:
                     raise
                 global_metrics.increment(
                     "kernel_server.client.retries_total")
+            finally:
+                if lease is not None:
+                    self._release(lease)
         raise KernelServerError(
             f"kernel request failed after {self.retry.max_retries + 1} "
             f"supervised attempts: {last}",
@@ -2407,7 +2526,8 @@ def ensure_server(socket_path: str = DEFAULT_SOCKET,
 
 
 #: per-socket supervised clients shared process-wide (a client owns a
-#: connection + supervision state; one per daemon is the contract)
+#: pool of connections + supervision state; one per daemon is the
+#: contract)
 _SHARED_CLIENTS: dict = {}
 _shared_clients_guard = threading.Lock()
 

@@ -206,6 +206,117 @@ def test_coalescing_concurrent_requests(server):
         assert h["iters"] == iters
 
 
+def test_shared_client_gives_every_thread_its_own_answer(server):
+    """The product path: the Bolt server's threads all call the ONE
+    supervised client that ``shared_client`` keeps per daemon. Riders
+    released together through it each get their own answer, bit for bit
+    the sequential one, and they share a fixpoint: the client leases
+    every call a connection of its own, where one shared stream would
+    let the daemon see one rider at a time and the threads read each
+    other's replies."""
+    from memgraph_tpu.server.kernel_server import shared_client
+    _srv, _client, sock = server
+    g, (src, dst, n) = _graph(seed=5)
+    _client.ppr([0], src=src, dst=dst, n_nodes=n, graph_key="shared",
+                graph_version=1, tol=TOL)
+    client = shared_client(sock)
+    assert client is shared_client(sock)
+    riders = 8
+    before = _counter("ppr.coalesced_total")
+    results, failures = {}, {}
+    barrier = threading.Barrier(riders)
+
+    def worker(i):
+        try:
+            barrier.wait(timeout=30)
+            results[i] = client.ppr([i + 1, i + 40], graph_key="shared",
+                                    graph_version=1, n_nodes=n, tol=TOL,
+                                    top_k=5, deadline_s=60.0)
+        except Exception as e:      # noqa: BLE001 - the test reports it
+            failures[i] = repr(e)
+
+    threads = [threading.Thread(target=worker, args=(i,), daemon=True)
+               for i in range(riders)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not failures, failures
+        assert sorted(results) == list(range(riders))
+        for i, (h, out) in results.items():
+            ranks, _, iters = personalized_pagerank(g, [i + 1, i + 40],
+                                                    tol=TOL)
+            ranks = np.asarray(ranks)
+            best = np.argsort(-ranks, kind="stable")[:5]
+            np.testing.assert_array_equal(out["topk_idx"], best)
+            np.testing.assert_array_equal(out["topk_val"], ranks[best])
+            assert h["iters"] == iters
+        assert _counter("ppr.coalesced_total") > before
+        assert max(h["batch_size"] for h, _ in results.values()) > 1
+    finally:
+        client.close()
+
+
+def test_lane_buckets_are_warm_after_the_first_request(tmp_path):
+    """A graph shape's first batch warms every lane bucket a batch can
+    be padded to: riders that later meet in a wider bucket compile
+    nothing (the witness counts every backend compile and cache load of
+    the process)."""
+    from memgraph_tpu.utils.jax_cache import install_compile_counter
+    assert install_compile_counter()
+    sock = str(tmp_path / "ks.sock")
+    srv = KernelServer(sock, wedge_after_s=60)
+    srv._ppr.window_s, srv._ppr.max_batch = 0.05, 8
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    deadline = time.monotonic() + 120
+    while True:
+        try:
+            client = KernelClient(sock, timeout=120)
+            break
+        except OSError:
+            assert time.monotonic() < deadline
+            time.sleep(0.05)
+    try:
+        # a shape no other test of this process has used
+        rng = np.random.default_rng(9)
+        n, e = 1100, 5000
+        src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
+        client.ppr([1, 2], src=src, dst=dst, n_nodes=n, graph_key="warm",
+                   graph_version=1, top_k=5)
+        # answered after the warming, which holds the batcher
+        client.ppr([3, 4], graph_key="warm", graph_version=1, n_nodes=n,
+                   top_k=5)
+        assert len(srv._ppr._warmed) == 1
+        compiled = _counter("jit.compile_total")
+        assert compiled > 0
+        results = {}
+        barrier = threading.Barrier(6)
+
+        def worker(i):
+            c = KernelClient(sock, timeout=120)
+            try:
+                barrier.wait(timeout=30)
+                results[i] = c.ppr([10 + i, 20 + i], graph_key="warm",
+                                   graph_version=1, n_nodes=n, top_k=5)
+            finally:
+                c.close()
+
+        threads = [threading.Thread(target=worker, args=(i,), daemon=True)
+                   for i in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert len(results) == 6
+        assert max(h["batch_size"] for h, _ in results.values()) > 2
+        assert _counter("jit.compile_total") == compiled
+        assert len(srv._ppr._warmed) == 1
+    finally:
+        client.shutdown()
+        client.close()
+
+
 def test_mixed_parameter_groups_never_share_a_fixpoint(server):
     """Requests with differing damping/tol in one arrival window
     execute as SEPARATE fixpoints — each bit-exact vs its own
