@@ -117,6 +117,9 @@ STAT_NAMES = (
     "ppr.cache_invalidate_total",
     "ppr.warm_start_total",
     "ppr.shed_total",
+    # the cache fill's invalidation sets (_source_neighborhood)
+    "ppr.neigh_offsets_total",     # read from the snapshot's host CSR rows
+    "ppr.neigh_scan_total",        # offsets built first: one O(E) pass
     "ppr.queue_depth",             # coalescing queue backlog gauge
     "ppr.window_occupancy",        # last batch width / max width gauge
     # device compile plane (r17, mgxla): runtime witness for the static

@@ -65,6 +65,10 @@ class DeviceGraph:
     host_coo:   optional (src, dst, w) HOST arrays of the true edges —
                 kept so a successor snapshot can diff edges for the
                 O(delta) MXU plan refresh (ops/spmv_mxu.DeltaPlan)
+    host_csr:   optional (row_ptr, col_idx) HOST arrays, the ones from_coo
+                built before to_device placed them — kept by reference so
+                a host reader takes a row as col_idx[row_ptr[s]:row_ptr[s+1]]
+                without a pass over host_coo or a device readback
     """
 
     row_ptr: object
@@ -82,6 +86,8 @@ class DeviceGraph:
     node_gids: np.ndarray
     gid_to_idx: dict = field(repr=False, hash=False, compare=False)
     host_coo: tuple = field(default=None, repr=False, hash=False,
+                            compare=False)
+    host_csr: tuple = field(default=None, repr=False, hash=False,
                             compare=False)
 
     def to_device(self) -> "DeviceGraph":
@@ -108,7 +114,7 @@ class DeviceGraph:
             n_nodes=self.n_nodes, n_edges=self.n_edges,
             n_pad=self.n_pad, e_pad=self.e_pad,
             node_gids=self.node_gids, gid_to_idx=self.gid_to_idx,
-            host_coo=self.host_coo)
+            host_coo=self.host_coo, host_csr=self.host_csr)
 
 
 def from_coo(src: np.ndarray, dst: np.ndarray,
@@ -194,7 +200,8 @@ def from_coo(src: np.ndarray, dst: np.ndarray,
                        node_gids=np.asarray(node_gids, dtype=np.int64),
                        gid_to_idx=gid_to_idx,
                        host_coo=(src.astype(np.int32), dst.astype(np.int32),
-                                 weights))
+                                 weights),
+                       host_csr=(row_ptr, dst_full))
 
 
 def export_csr(accessor, weight_property: Optional[int] = None,

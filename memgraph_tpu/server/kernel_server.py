@@ -418,20 +418,50 @@ def _env_float(name: str, default: float) -> float:
 PPR_NEIGH_CAP = 4096
 
 
+def _host_offsets(graph):
+    """Host ``(row_ptr, col_idx)`` of ``graph``'s true edges, or None for
+    a snapshot without host edges. ``from_coo`` hands them on with every
+    snapshot it builds (``DeviceGraph.host_csr``: two references). One
+    built elsewhere gets them here, once: a stable argsort of ``src``,
+    O(E), remembered on the snapshot object for every later rider."""
+    offsets = graph.host_csr
+    if offsets is None and graph.host_coo is not None:
+        src, dst, _w = graph.host_coo
+        src = np.asarray(src)
+        row_ptr = np.zeros(graph.n_nodes + 1, dtype=np.int64)
+        np.cumsum(np.bincount(src, minlength=graph.n_nodes),
+                  out=row_ptr[1:])
+        offsets = (row_ptr,
+                   np.asarray(dst)[np.argsort(src, kind="stable")])
+        # the snapshot is frozen for its users, not for what it caches
+        object.__setattr__(graph, "host_csr", offsets)
+        global_metrics.increment("ppr.neigh_scan_total")
+    return offsets
+
+
 def _source_neighborhood(graph, sources, cap: int = PPR_NEIGH_CAP):
     """Dense indices whose mutation must invalidate a cached PPR vector
     restarted on ``sources``: the sources plus their out-neighbors (the
     rows the restart mass crosses first). None = unbounded (treat every
-    change as relevant)."""
-    if graph.host_coo is None:
+    change as relevant): more than ``cap`` of them, or a snapshot
+    without host edges.
+
+    Costs the sources' out-degrees: each row is one slice of the
+    snapshot's host CSR, ``col_idx[row_ptr[s]:row_ptr[s + 1]]``
+    (:func:`_host_offsets`). Never a pass over ``host_coo`` per call
+    and never a readback of the device's ``row_ptr`` / ``col_idx``."""
+    offsets = _host_offsets(graph)
+    if offsets is None:
         return None
-    src, dst, _w = graph.host_coo
-    sel = np.isin(np.asarray(src), np.asarray(sources))
-    neigh = set(int(i) for i in np.asarray(dst)[sel])
-    neigh.update(int(s) for s in np.asarray(sources))
+    row_ptr, col_idx = offsets
+    global_metrics.increment("ppr.neigh_offsets_total")
+    sources = np.asarray(sources, dtype=np.int64)
+    rows = sources[(sources >= 0) & (sources < len(row_ptr) - 1)]
+    neigh = np.unique(np.concatenate(
+        [sources] + [col_idx[row_ptr[s]:row_ptr[s + 1]] for s in rows]))
     if len(neigh) > cap:
         return None
-    return frozenset(neigh)
+    return frozenset(int(i) for i in neigh)
 
 
 class _PprCacheEntry:

@@ -413,6 +413,94 @@ def test_unknowable_delta_invalidates_whole_key(server):
     assert h["cache"] != "hit"
 
 
+def _round_of_sets(sock, key, n, base, riders=6):
+    """``riders`` clients released together, each with its own 4 sources."""
+    replies, failures = {}, {}
+    barrier = threading.Barrier(riders)
+
+    def worker(i):
+        c = KernelClient(sock, timeout=120)
+        try:
+            barrier.wait(timeout=30)
+            replies[i] = c.ppr([base + 4 * i + j for j in range(4)],
+                               graph_key=key, graph_version=1, n_nodes=n,
+                               top_k=5)
+        except Exception as e:      # noqa: BLE001 - the test reports it
+            failures[i] = repr(e)
+        finally:
+            c.close()
+
+    threads = [threading.Thread(target=worker, args=(i,), daemon=True)
+               for i in range(riders)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not failures, failures
+    assert len(replies) == riders
+    return replies
+
+
+def test_cache_fill_reads_offsets_and_scans_nothing(server):
+    """Batches of several riders: a neighbourhood a rider, every one
+    from the row offsets its snapshot carried (no pass over the edges),
+    and both counters in the daemon's health reply."""
+    _srv, client, sock = server
+    _, (src, dst, n) = _graph(seed=11)
+    scans = _counter("ppr.neigh_scan_total")
+    reads = _counter("ppr.neigh_offsets_total")
+    batches = _counter("ppr.batches_total")
+    client.ppr([0, 1, 2, 3], src=src, dst=dst, n_nodes=n,
+               graph_key="neigh", graph_version=1, top_k=5)
+    riders = 1
+    for rnd in range(3):
+        replies = _round_of_sets(sock, "neigh", n, 4 + 24 * rnd)
+        assert all(h["cache"] == "miss" for h, _ in replies.values())
+        riders += len(replies)
+    assert _counter("ppr.batches_total") - batches < riders
+    assert _counter("ppr.neigh_scan_total") == scans
+    assert _counter("ppr.neigh_offsets_total") == reads + riders
+    counters = client.health()["counters"]
+    assert counters["ppr.neigh_offsets_total"] == reads + riders
+    assert counters.get("ppr.neigh_scan_total", 0.0) == scans
+
+
+def test_snapshot_handed_over_without_offsets_is_scanned_once(server):
+    """A DeviceGraph that reaches the plane without host offsets gets
+    them built once, however many riders follow, and the sets filed
+    from them invalidate exactly as the definition's do."""
+    import dataclasses
+    srv, client, sock = server
+    _, (src, dst, n) = _graph(seed=12)
+    client.ppr([0, 1, 2, 3], src=src, dst=dst, n_nodes=n,
+               graph_key="bare", graph_version=1, top_k=5)
+    with srv._dispatch_lock:
+        gen = srv._graphs["bare"]
+        gen._graph = bare = dataclasses.replace(gen.graph, host_csr=None)
+    scans = _counter("ppr.neigh_scan_total")
+    reads = _counter("ppr.neigh_offsets_total")
+    riders = sum(len(_round_of_sets(sock, "bare", n, 4 + 24 * rnd))
+                 for rnd in range(2))
+    assert srv._graphs["bare"].graph is bare
+    assert bare.host_csr is not None
+    assert _counter("ppr.neigh_scan_total") == scans + 1
+    assert _counter("ppr.neigh_offsets_total") == reads + riders
+    assert client.health()["counters"]["ppr.neigh_scan_total"] == \
+        scans + 1
+    mine = [4, 5, 6, 7]
+    reach = set(mine) | set(int(d) for d in dst[np.isin(src, mine)])
+    far = [v for v in range(n) if v not in reach][:2]
+    h, _ = client.ppr(mine, src=src, dst=dst, graph_key="bare",
+                      graph_version=2, base_version=1, changed=far,
+                      n_nodes=n, top_k=5)
+    assert h["cache"] == "hit"            # provably untouched
+    near = sorted(reach - set(mine))[:1]
+    h, _ = client.ppr(mine, src=src, dst=dst, graph_key="bare",
+                      graph_version=3, base_version=2, changed=near,
+                      n_nodes=n, top_k=5)
+    assert h["cache"] == "warm"           # demoted by a neighbour's change
+
+
 def test_one_bad_request_does_not_poison_the_batch(server):
     """Outcome matrix: an invalid request (sources out of range) and an
     oversized request ride the same window as good ones — each gets its
