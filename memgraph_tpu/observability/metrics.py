@@ -71,7 +71,6 @@ STAT_NAMES = (
     "query.topk_total",            # a TopK cursor ran: ORDER BY … LIMIT
     "query.sort_full_total",       # an OrderBy sorted its whole input
     # bolt session pool
-    "bolt.prepare_latency_sec",
     "bolt.connections_rejected_total",
     "bolt.sessions_live",
     "bolt.sessions_max",
@@ -293,7 +292,14 @@ class Metrics:
         self._counters: dict[str, float] = defaultdict(int)
         self._gauges: dict[str, float] = {}
         self._histograms: dict[str, Histogram] = {}
+        #: run before every read, outside the lock: how a writer that
+        #: may take no lock hands its counts over (Python's cyclic
+        #: collector, observability/trace.py ``_hand_over``)
+        self._folds: list = []
         shared_field(self, "_counters", "_gauges", "_histograms")
+
+    def add_fold(self, fold) -> None:
+        self._folds.append(fold)
 
     def increment(self, name: str, delta: float = 1) -> None:
         with self._lock:
@@ -329,6 +335,8 @@ class Metrics:
             h.observe(value, trace_id)
 
     def snapshot(self) -> list[tuple[str, str, float]]:
+        for fold in self._folds:
+            fold()
         with self._lock:
             shared_read(self, "_counters")
             out = [(n, "Counter", float(v))
@@ -345,6 +353,8 @@ class Metrics:
 
     def prometheus_text(self) -> str:
         lines = []
+        for fold in self._folds:
+            fold()
         with self._lock:
             shared_read(self, "_counters")
             counters = sorted(self._counters.items())

@@ -26,8 +26,10 @@ Design rules:
   ``enable()``). Every public entry point starts with one attribute
   read; disarmed, ``span()`` of a non-phase name returns a shared no-op
   context manager and ``inject()``/``activate()`` return ``None``/
-  no-ops. A phase costs two clock reads and one locked add; at most
-  three lie on a point read's path. The overhead-guard test
+  no-ops. A phase costs two clock reads and one locked add; six close
+  on a point read's path (``bolt.run``, ``bolt.wait`` for its RUN and
+  its PULL, ``bolt.prepare``, ``bolt.pull``, ``bolt.encode``), seven on
+  a write's (``mvcc.commit``). The overhead-guard test
   (tests/test_mgtrace.py) enforces the ≤2% budget on a tier-1
   micro-benchmark over both kinds of site.
 
@@ -54,14 +56,21 @@ Design rules:
   the caller ``adopt_spans()``-s them — so the retained trace in the
   querying process is the whole connected picture, not a stub.
 
-Exports: ``traces_json()`` (the /traces endpoint), ``to_jsonl()``, and
+* **Python's cyclic collector is a phase too.** One ``gc.callbacks``
+  entry a process (installed when this module is first imported:
+  the Bolt server and the kernel-server daemon both import it) opens
+  ``python.gc`` on every collection, and ``python.gc.full`` over the
+  same extent of a generation-2 one, on the thread the collection
+  interrupted. See :func:`_on_collect` for what it does differently.
+
+Exports: ``traces_json()`` (the /traces endpoint) and
 ``chrome_trace()`` — Chrome trace-event JSON loadable in Perfetto /
 chrome://tracing.
 """
 
 from __future__ import annotations
 
-import json
+import gc
 import logging
 import os
 import sys
@@ -86,6 +95,13 @@ ENV_RING = "MEMGRAPH_TPU_TRACE_RING"
 SPAN_NAMES = (
     "bolt.run",            # RUN received -> last PULL answered (session root)
     "bolt.wait",           # message decoded -> executor thread starts on it
+    "bolt.prepare",        # RUN on its worker thread: parse, plan, prepare
+    "bolt.pull",           # PULL/DISCARD on its worker thread: execute,
+    #                        the rows, the operators above them, commit
+    "bolt.encode",         # the records' PackStream and SUCCESS, on the loop
+    "python.gc",           # one pass of Python's cyclic collector, any
+    #                        generation, on the thread it interrupted
+    "python.gc.full",      # the same extent, of a generation-2 collection
     "query",               # interpreter root: prepare -> summary
     "query.parse",         # text -> AST (cache-aware)
     "query.plan",          # AST -> operator tree (cache-aware)
@@ -155,6 +171,11 @@ SPAN_NAMES = (
 PHASES = {
     "bolt.run": (),
     "bolt.wait": (),
+    "bolt.prepare": (),
+    "bolt.pull": (),
+    "bolt.encode": (),
+    "python.gc": (),
+    "python.gc.full": (),
     "mvcc.commit": (),
     "query.sort": (),
     "storage.gc": (),
@@ -798,16 +819,6 @@ def traces_json(trace_id: str | None = None) -> list[list[dict]]:
     return traces
 
 
-def to_jsonl(traces=None) -> str:
-    """One span per line — grep/jq-friendly archival form."""
-    traces = TRACER.finished_traces() if traces is None else traces
-    lines = []
-    for spans in traces:
-        for s in spans:
-            lines.append(json.dumps(s, sort_keys=True, default=str))
-    return "\n".join(lines) + ("\n" if lines else "")
-
-
 def chrome_trace(traces=None) -> dict:
     """Chrome trace-event JSON (load in Perfetto / chrome://tracing).
 
@@ -831,3 +842,95 @@ def chrome_trace(traces=None) -> dict:
                 "pid": s.get("pid", 0), "tid": s.get("tid", 0),
                 "args": args})
     return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
+# --------------------------------------------------------------------------
+# Python's cyclic collector
+# --------------------------------------------------------------------------
+
+
+class _CollectorPhase(_PhaseSpan):
+    """A phase the collector's callback opens. Its close adds to
+    :data:`_collected` and takes no lock; :func:`_hand_over` moves that
+    into the phase's counters before ``global_metrics`` is read."""
+
+    __slots__ = ()
+
+    def __init__(self, name: str) -> None:
+        super().__init__(name, _PHASE_KEYS[name], {})
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = seconds = time.perf_counter() - self._t0
+        if self._ann is not None:
+            _exit_annotation(self._ann)
+        total, count = _collected[self._name]
+        _collected[self._name] = (total + seconds, count + 1)
+        return False
+
+
+#: phase name -> (seconds, closes) the collector has closed, ever. Only
+#: the callback writes it (collections never nest), one whole tuple at a
+#: time into a key that is already there
+_collected = {"python.gc": (0.0, 0), "python.gc.full": (0.0, 0)}
+#: what _hand_over has moved into global_metrics of it
+_handed = dict(_collected)
+_hand_lock = threading.Lock()
+#: the collection in progress: its open phases, innermost last
+_collecting: list = []
+
+
+def _on_collect(phase: str, info: dict) -> None:
+    """``gc.callbacks``: Python's cyclic collector as ``python.gc`` (every
+    collection) and ``python.gc.full`` (the same extent, generation 2:
+    the whole heap, what a ``gc.unfreeze`` thawed included). A
+    collection's start and stop come on the thread it interrupted.
+
+    Accounted and in a live profiler session's xplane like any phase,
+    armed or not; unlike one, a collection joins **no trace** and its
+    close takes **no lock**: it runs at whatever bytecode the thread
+    had reached, which may lie inside the tracer's lock, the metrics
+    registry's, or the lock witness's own, and a recorded span or a
+    locked add would wait there for itself. Never raises into the
+    collector."""
+    try:
+        if phase == "start":
+            _collecting.append(_CollectorPhase("python.gc").__enter__())
+            if info.get("generation") == 2:
+                _collecting.append(
+                    _CollectorPhase("python.gc.full").__enter__())
+        else:
+            while _collecting:
+                _collecting.pop().__exit__(None, None, None)
+    except Exception:  # mglint: disable=MG003 — the collector must never see one, and a log line could wait on a lock the interrupted thread holds
+        _collecting.clear()
+
+
+def _hand_over() -> None:
+    """The collector's closes since the last read, into
+    ``span.python.gc*.seconds_total`` / ``.count`` (``global_metrics``
+    runs this before every read)."""
+    with _hand_lock:
+        now = dict(_collected)
+        moved = [(name, total - _handed[name][0], count - _handed[name][1])
+                 for name, (total, count) in now.items()
+                 if count != _handed[name][1]]
+        _handed.update(now)
+    for name, seconds, count in moved:
+        keys = _PHASE_KEYS[name]
+        global_metrics.increment(keys[0], seconds)
+        global_metrics.increment(keys[1], count)
+
+
+def install_collector_phases() -> bool:
+    """Install :func:`_on_collect` once a process, however often this
+    module is set up (a reload included). True if this call did."""
+    if any(getattr(cb, "__module__", None) == __name__
+           and getattr(cb, "__name__", None) == "_on_collect"
+           for cb in gc.callbacks):
+        return False
+    gc.callbacks.append(_on_collect)
+    global_metrics.add_fold(_hand_over)
+    return True
+
+
+install_collector_phases()

@@ -281,24 +281,31 @@ class BoltSession:
     def _unregister_session(self) -> None:
         getattr(self.ictx, "active_sessions", {}).pop(self.session_id, None)
 
-    async def _offload(self, fn, *args):
+    async def _offload(self, fn, *args, phase=None):
         if self._executor is None:
-            return self._offloaded(fn, args)
+            return self._offloaded(phase, fn, args)
         loop = asyncio.get_running_loop()
         return await loop.run_in_executor(self._executor, self._offloaded,
-                                          fn, args)
+                                          phase, fn, args)
 
-    def _offloaded(self, fn, args):
+    def _offloaded(self, phase, fn, args):
         """On the worker thread: close the message's ``bolt.wait`` (its
         decode -> this thread starting on it: the executor's queue),
         then run fn under the session's trace context (thread-local, so
-        the activation must happen ON this thread)."""
+        the activation must happen ON this thread), inside the phase
+        span the caller's ``phase()`` opens, if it gives one. The
+        interpreter's own spans stay children of ``bolt.run``, beside
+        the phase."""
         handle = self._bolt_trace
-        with mgtrace.activate(handle.ctx if handle is not None else None):
+        ctx = handle.ctx if handle is not None else None
+        with mgtrace.activate(ctx):
             wall, t0 = self._msg_decoded
             mgtrace.record_span("bolt.wait", wall,
                                 time.perf_counter() - t0)
-            return fn(*args)
+            if phase is None:
+                return fn(*args)
+            with phase(), mgtrace.activate(ctx):
+                return fn(*args)
 
     # --- wire framing -------------------------------------------------------
 
@@ -566,13 +573,9 @@ class BoltSession:
         trace_id = self._bolt_trace.trace_id
         parameters = {k: bolt_to_value(v)
                       for k, v in (parameters or {}).items()}
-        t0 = time.perf_counter()
-        prepared = await self._offload(self.interpreter.prepare, query,
-                                       parameters)
-        from ..observability.metrics import global_metrics
-        global_metrics.observe(
-            "bolt.prepare_latency_sec", time.perf_counter() - t0,
-            trace_id=trace_id)
+        prepared = await self._offload(
+            self.interpreter.prepare, query, parameters,
+            phase=lambda: mgtrace.span("bolt.prepare"))
         self._prepared = prepared
         meta = {"fields": prepared.columns, "t_first": 0, "qid": 0}
         if trace_id is not None:
@@ -585,30 +588,35 @@ class BoltSession:
         storage = self.interpreter.ctx.storage  # honors USE DATABASE
         from ..storage.common import View
         rows, has_more, summary = await self._offload(
-            self.interpreter.pull, n)
-        for row in rows:
-            self.send(M_RECORD,
-                      [value_to_bolt(v, storage, View.NEW, self.version)
-                       for v in row])
-        meta = {"has_more": has_more}
-        if not has_more:
-            meta["t_last"] = 0
-            meta["type"] = self._prepared.summary_type if self._prepared \
-                else "r"
-            stats = summary.get("stats") if summary else None
-            if stats and any(stats.values()):
-                meta["stats"] = {k.replace("_", "-"): v
-                                 for k, v in stats.items() if v}
-            if self._bolt_trace is not None \
-                    and self._bolt_trace.trace_id is not None:
-                meta["trace_id"] = self._bolt_trace.trace_id
-        self.send_success(meta)
+            self.interpreter.pull, n,
+            phase=lambda: mgtrace.span("bolt.pull"))
+        handle = self._bolt_trace
+        # no await inside: the phase opens and closes on the loop thread
+        with mgtrace.activate(handle.ctx if handle is not None else None), \
+                mgtrace.span("bolt.encode"):
+            for row in rows:
+                self.send(M_RECORD,
+                          [value_to_bolt(v, storage, View.NEW, self.version)
+                           for v in row])
+            meta = {"has_more": has_more}
+            if not has_more:
+                meta["t_last"] = 0
+                meta["type"] = self._prepared.summary_type \
+                    if self._prepared else "r"
+                stats = summary.get("stats") if summary else None
+                if stats and any(stats.values()):
+                    meta["stats"] = {k.replace("_", "-"): v
+                                     for k, v in stats.items() if v}
+                if handle is not None and handle.trace_id is not None:
+                    meta["trace_id"] = handle.trace_id
+            self.send_success(meta)
         if not has_more:
             self._finish_bolt_trace("ok")
         return True
 
     async def on_discard(self, extra: dict) -> bool:
-        await self._offload(self.interpreter.pull, -1)
+        await self._offload(self.interpreter.pull, -1,
+                            phase=lambda: mgtrace.span("bolt.pull"))
         self._finish_bolt_trace("ok")
         self.send_success({"has_more": False})
         return True

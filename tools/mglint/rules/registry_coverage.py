@@ -612,6 +612,8 @@ def _check_spmv_registry(project: Project):
 #: the sanctioned span-opening API (all context-manager / atomic-record
 #: shaped; no caller can leave a span open by mistake)
 _SPAN_OPEN_FUNCS = ("span", "record_span", "begin_trace")
+#: trace.py's own open site: Python's cyclic collector's phases
+_COLLECTOR_PHASE = "_CollectorPhase"
 
 
 def _collect_phase_marks(tr) -> dict[str, int]:
@@ -650,7 +652,14 @@ def _check_span_registry(project: Project):
                         "SPAN_NAMES — a phase is a span name marked as "
                         "always accounted, not a second vocabulary",
                 fingerprint=f"phase-undeclared:{phase}"))
-    opened: set[str] = set()
+    # the collector's phases open in trace.py itself, through the one
+    # class that joins no trace and takes no lock (``_on_collect``)
+    opened: set[str] = {
+        node.args[0].value for node in ast.walk(tr.tree)
+        if isinstance(node, ast.Call)
+        and (dotted(node.func) or "") == _COLLECTOR_PHASE
+        and node.args and isinstance(node.args[0], ast.Constant)
+        and node.args[0].value in names}
     for rel, sf in project.files.items():
         if sf is tr:
             continue
