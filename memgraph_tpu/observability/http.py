@@ -105,7 +105,8 @@ async def start_monitoring_server(host: str, port: int, ictx):
                     # (mxu.*), in-process fixpoint iterations (device.*)
                     # and every phase span's seconds and closes (span.*),
                     # with the two counters that say which operator the
-                    # query.sort span's closes were (TopK or OrderBy)
+                    # query.sort span's closes were (TopK or OrderBy) and
+                    # the CALLs that yielded a TopK's bound only
                     "device": {name: value for name, _k, value
                                in global_metrics.snapshot()
                                if name.startswith(

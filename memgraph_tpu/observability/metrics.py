@@ -70,6 +70,7 @@ STAT_NAMES = (
     # ORDER BY, by the operator that ran it (plan/operators.py)
     "query.topk_total",            # a TopK cursor ran: ORDER BY … LIMIT
     "query.sort_full_total",       # an OrderBy sorted its whole input
+    "query.topk_pushdown_total",   # a CALL under a TopK yielded its bound
     # bolt session pool
     "bolt.connections_rejected_total",
     "bolt.sessions_live",

@@ -25,6 +25,7 @@ import time
 import numpy as np
 
 from ..observability import trace as mgtrace
+from ..observability.metrics import global_metrics
 from . import mgp
 
 log = logging.getLogger(__name__)
@@ -40,16 +41,25 @@ _PPR_PUSHED_LOCK = threading.Lock()
 
 
 def _rank_results(ctx, graph, values, field_name):
-    """One row per vertex. Two phases, recorded once when the generator
-    ends: ``analytics.rows``, the time spent in here making the rows,
-    and ``analytics.consume``, the time the plan's operators above the
-    CALL took between two rows (a TopK's selection on the sort keys;
-    without a LIMIT, Produce's expressions and OrderBy's collecting)."""
+    """One row per vertex, or under the call's ``row_bound`` on this
+    field only the rows that can reach the result (_bounded_rows). Two
+    phases, recorded once when the generator ends: ``analytics.rows``,
+    the time spent in here choosing and making the rows, and
+    ``analytics.consume``, the time the plan's operators above the CALL
+    took between two rows (a TopK's selection on the sort keys; without
+    a LIMIT, Produce's expressions and OrderBy's collecting)."""
     started = time.time()
     inside = outside = 0.0
     t0 = time.perf_counter()
     try:
-        for i in range(graph.n_nodes):
+        indices, bound = None, ctx.row_bound
+        if bound is not None and bound.field == field_name:
+            indices = _bounded_rows(ctx, graph, values, bound)
+        if indices is None:
+            indices = range(graph.n_nodes)
+        else:
+            global_metrics.increment("query.topk_pushdown_total")
+        for i in indices:
             node = ctx.vertex_by_index(graph, i)
             if node is not None:
                 row = {"node": node, field_name: float(values[i])}
@@ -62,6 +72,33 @@ def _rank_results(ctx, graph, values, field_name):
     finally:
         mgtrace.record_span("analytics.rows", started, inside)
         mgtrace.record_span("analytics.consume", started, outside)
+
+
+def _bounded_rows(ctx, graph, values, bound):
+    """The indices of the ``bound.count`` visible vertices whose values
+    sort first and of every vertex tied with the last of them, in index
+    order: the order the full stream has them, so that a TopK's
+    tie-break by arrival sees what it saw. None where every row is to be
+    yielded: a NaN among the values (the sort orders it apart), or a
+    bound that leaves nothing out."""
+    n = graph.n_nodes
+    values = np.asarray(values)[:n]
+    if not 0 < bound.count < n or np.isnan(values).any():
+        return None
+    keys = -values if bound.descending else values
+    taken = bound.count
+    while taken < n:
+        best = np.argpartition(keys, taken - 1)[:taken]
+        visible = 0
+        for i in best[np.lexsort((best, keys[best]))].tolist():
+            if ctx.vertex_by_index(graph, i) is not None:
+                visible += 1
+                if visible == bound.count:
+                    # every key below this one's is among the ``taken``
+                    # best, so it is the count-th best visible key
+                    return np.flatnonzero(keys <= keys[i]).tolist()
+        taken *= 2      # deleted vertices among the best: look further
+    return None
 
 
 def _top_rank_results(ctx, graph, indices, values, field_name):

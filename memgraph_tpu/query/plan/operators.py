@@ -1659,6 +1659,10 @@ class CallProcedureOp(LogicalOperator):
     result_fields: list[str]
     output_symbols: list[str]
     memory_limit: "Optional[int]" = None   # PROCEDURE MEMORY LIMIT, bytes
+    #: (field, descending, limit, skip) of the TopK right above, where
+    #: no row it drops could change the result (planner.topk_rewrite):
+    #: each call gets it as a RowBound
+    topk_bound: Optional[tuple] = None
 
     def cursor(self, ctx):
         from ..procedures.registry import global_registry
@@ -1669,6 +1673,14 @@ class CallProcedureOp(LogicalOperator):
             raise SemanticException(f"unknown procedure: {self.proc_name}")
         from .planner import _literal_matches_type
         proc_bytes = 0   # yielded-record accounting vs PROCEDURE limit
+        row_bound = None
+        if self.topk_bound is not None:
+            from ..procedures.registry import RowBound
+            field, descending, limit, skip = self.topk_bound
+            count = _limit_count(ctx, limit)
+            if skip is not None:
+                count += _skip_count(ctx, skip)
+            row_bound = RowBound(field, descending, count)
         for frame in self.input.cursor(ctx):
             ctx.check_abort()
             args = [ctx.evaluator.eval(e, frame) for e in self.args]
@@ -1686,7 +1698,7 @@ class CallProcedureOp(LogicalOperator):
                 if getattr(proc, "void", False):
                     yield dict(frame)
                 continue
-            for record in proc.call(ctx, args):
+            for record in proc.call(ctx, args, row_bound):
                 if self.memory_limit is not None:
                     proc_bytes += approx_size(record)
                     if proc_bytes > self.memory_limit:

@@ -1354,7 +1354,18 @@ def topk_rewrite(plan):
     projection's own ``Produce``, the Produce folded in (see Op.TopK).
     Chosen by the plan's shape alone. Runs after parallel_rewrite and
     lane_rewrite: a shape they claimed has no OrderBy left, and their
-    ``fallback`` subplans stay as they were planned."""
+    ``fallback`` subplans stay as they were planned. A TopK right above
+    a CALL, in a plan that writes nothing, hands the CALL its bound
+    (call_bound)."""
+    over_calls: list = []
+    plan = _rewrite_topk(plan, over_calls)
+    reads_only = bool(over_calls) and _reads_only(plan)
+    for topk in over_calls:
+        topk.input.topk_bound = call_bound(topk) if reads_only else None
+    return plan
+
+
+def _rewrite_topk(plan, over_calls: list):
     if type(plan) is Op.Limit:
         inner, skip = plan.input, None
         if type(inner) is Op.Skip:
@@ -1362,9 +1373,48 @@ def topk_rewrite(plan):
         if type(inner) is Op.OrderBy:
             plan = _fold_projection(
                 Op.TopK(inner.input, inner.items, plan.expr, skip))
+    if type(plan) is Op.TopK and type(plan.input) is Op.CallProcedureOp:
+        over_calls.append(plan)
     for name, child in _child_operators(plan):   # (no ``fallback`` there)
-        setattr(plan, name, topk_rewrite(child))
+        setattr(plan, name, _rewrite_topk(child, over_calls))
     return plan
+
+
+#: result types whose properties read without raising (a vertex, an
+#: edge: Op.TopK's guarded items)
+_ENTITY_TYPES = ("NODE", "RELATIONSHIP")
+
+
+def call_bound(topk: "Op.TopK"):
+    """The CALL's ``topk_bound`` (Op.CallProcedureOp) under ``topk``, or
+    None where a row the procedure leaves out could change the result.
+    The first sort item names a field the CALL yields, and every sort
+    and projection item raises on no row or on every one: an identifier,
+    a literal, a parameter, or a property of a parameter or of a NODE or
+    RELATIONSHIP the CALL yields (in a plan that writes nothing, such a
+    projection item is deferred: no eager slot)."""
+    from ..procedures.registry import global_registry
+    call = topk.input
+    proc = global_registry.find(call.proc_name)
+    first, ascending = topk.items[0]
+    fields = dict(zip(call.output_symbols, call.result_fields))
+    if proc is None or call.memory_limit is not None or not isinstance(
+            first, A.Identifier) or first.name not in fields:
+        return None
+    kinds = dict(proc.results)
+    entities = {symbol for symbol, field in fields.items()
+                if kinds.get(field) in _ENTITY_TYPES}
+    for expr in [e for e, _ in topk.items] + [
+            e for e, _, _ in topk.projection or ()]:
+        if isinstance(expr, A.PropertyLookup):
+            base = expr.expr
+            if not (isinstance(base, A.Parameter) or (
+                    isinstance(base, A.Identifier)
+                    and base.name in entities)):
+                return None
+        elif not isinstance(expr, (A.Identifier, A.Literal, A.Parameter)):
+            return None
+    return fields[first.name], not ascending, topk.limit, topk.skip
 
 
 def _fold_projection(topk: "Op.TopK") -> "Op.TopK":

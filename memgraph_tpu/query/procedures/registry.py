@@ -34,16 +34,33 @@ class Procedure:
     # (openCypher TCK distinction, ProcedureCallAcceptance)
     void: bool = False
 
-    def call(self, exec_ctx, args: list) -> Iterable[dict]:
-        pctx = ProcedureContext(exec_ctx)
+    def call(self, exec_ctx, args: list,
+             row_bound: Optional["RowBound"] = None) -> Iterable[dict]:
+        pctx = ProcedureContext(exec_ctx, row_bound)
         return self.func(pctx, *args)
 
 
-class ProcedureContext:
-    """What a procedure sees: graph access + device snapshot export."""
+@dataclass(frozen=True)
+class RowBound:
+    """What an ``ORDER BY … [SKIP s] LIMIT k`` right above one CALL
+    lets through (planner.topk_rewrite): of the rows the call yields,
+    only the ``count`` (s + k) that sort first on the yielded ``field``,
+    in that direction, and every row tied with the last of them, can
+    reach the result. Advisory: a procedure that ignores it yields every
+    row. Any subset it yields keeps the order the full stream had."""
+    field: str
+    descending: bool
+    count: int
 
-    def __init__(self, exec_ctx) -> None:
+
+class ProcedureContext:
+    """What a procedure sees: graph access + device snapshot export,
+    and the ``row_bound`` of this one call (None: every row counts)."""
+
+    def __init__(self, exec_ctx,
+                 row_bound: Optional[RowBound] = None) -> None:
         self.exec_ctx = exec_ctx
+        self.row_bound = row_bound
         self.accessor = exec_ctx.accessor
         self.storage = exec_ctx.accessor.storage
         self.view = exec_ctx.view

@@ -228,3 +228,27 @@ def test_routed_call_sees_every_committed_burst(deployment, monkeypatch):
     assert counters["span.kernel.generation.seconds_total"] <= \
         counters["span.kernel.dispatch.seconds_total"]
     assert "jit.compile_total" in counters
+
+
+def test_the_cells_query_yields_only_its_bound(deployment):
+    """The benchmark's query through Bolt and the route: the CALL yields
+    the TopK's bound (``query.topk_pushdown_total`` once), its two row
+    phases close once, and the 100 rows are the best of the full stream."""
+    client, metrics_port, _kernel = deployment
+    client.execute("UNWIND range(0, 149) AS i CREATE (:Pushed {id: -1 - i})")
+    before = _metrics(metrics_port)
+    _, rows, _ = client.execute(
+        "CALL pagerank.get() YIELD node, rank "
+        "RETURN node.id AS id, rank ORDER BY rank DESC LIMIT 100")
+    after = _metrics(metrics_port)
+    for name in ("query.topk_pushdown_total", "span.analytics.rows.count",
+                 "span.analytics.consume.count",
+                 "analytics.kernel_routed_total"):
+        assert after.get(name, 0.0) - before.get(name, 0.0) == 1, name
+    _, every, _ = client.execute(CALL)
+    full = dict(map(tuple, every))
+    ranks = [rank for _id, rank in rows]
+    assert len(rows) == 100 and ranks == sorted(ranks, reverse=True)
+    assert all(abs(full[i] - rank) < TOL for i, rank in rows)
+    left_out = set(full) - {i for i, _rank in rows}
+    assert max(full[i] for i in left_out) <= ranks[-1] + TOL
