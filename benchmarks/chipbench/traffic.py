@@ -4,10 +4,10 @@ that drives a plan over one Bolt connection.
 
 A mix (``traffic/<mix>.json``) names its clients, how a client picks
 its next class (``weighted``: every block of sum-of-shares requests
-holds each class exactly ``share`` times, shuffled by the seed, so every
-seed offers the same work in another order; ``sequence``: the classes
-in order, one pass being one cycle), the key distribution, and for
-each class its Cypher text, what draws each parameter (a generator of
+holds each class exactly ``share`` times, shuffled by the seed;
+``sequence``: the classes in order, one pass being one cycle), the key
+distribution (with a ``seed`` of its own every run seed offers the same
+work in another order), and for each class its Cypher text, what draws each parameter (a generator of
 the deployment's data set, ``datasets/<name>.py`` ``GENERATORS``, or
 one of the three here), and the name of its semantics
 (``semantics/<name>.py``).
@@ -49,12 +49,14 @@ class Request:
 
 class Keys:
     """Ids drawn with the mix's Zipf skew (theta 0 is uniform) over a
-    seeded permutation."""
+    seeded permutation. A mix whose keys name a ``seed`` keeps one hot
+    set for every run seed, as the graph is one (and ``Plan`` one stream
+    of parameters for each class)."""
 
     def __init__(self, spec: dict, n_ids: int, seed: int):
         if spec["distribution"] != "zipf":
             raise ValueError(f"no key distribution {spec['distribution']!r}")
-        rng = np.random.default_rng([seed, 0xC0FFEE])
+        rng = np.random.default_rng([spec.get("seed", seed), 0xC0FFEE])
         self.ids = rng.permutation(n_ids) if spec.get("permuted") \
             else np.arange(n_ids)
         weight = 1.0 / np.arange(1, n_ids + 1) ** float(spec["theta"])
@@ -67,13 +69,21 @@ class Keys:
 
 
 class Plan:
-    """One client's requests, drawn from (seed, client index) alone."""
+    """One client's requests, drawn from (seed, client index) alone, or
+    their parameters from (the keys' seed, client index, class)."""
 
     def __init__(self, mix: dict, n_ids: int, seed: int, client: int,
                  keys: Keys | None, dataset=None):
         self.mix, self.n_ids, self.client, self.keys = mix, n_ids, client, keys
         self.generators = getattr(dataset, "GENERATORS", {})
-        self.rng = np.random.default_rng([seed, 1 + client])
+        self.order = self.rng = np.random.default_rng([seed, 1 + client])
+        # a mix whose keys name a ``seed`` draws each class's parameters
+        # from a stream of its own, the same for every run seed: the run's
+        # seed then orders the classes inside each block and nothing more
+        fixed = mix.get("keys", {}).get("seed")
+        self.streams = {} if fixed is None else {
+            c["name"]: np.random.default_rng([int(fixed), 1 + client, i])
+            for i, c in enumerate(mix["classes"])}
         self.by_name = {c["name"]: c for c in mix["classes"]}
         self._new_ids = 0
         self._block: list = []
@@ -96,6 +106,7 @@ class Plan:
 
     def request(self, name: str) -> Request:
         cls = self.by_name[name]
+        self.rng = self.streams.get(name, self.order)
         params = {k: self._param(v) for k, v in cls["params"].items()}
         return Request(cls, params, self.client)
 
@@ -111,7 +122,7 @@ class Plan:
                 names = [c["name"] for c in classes
                          for _ in range(int(c["share"]))]
                 self._block = [names[i]
-                               for i in self.rng.permutation(len(names))]
+                               for i in self.order.permutation(len(names))]
             else:
                 raise ValueError(f"no schedule {self.mix['schedule']!r}")
         return self.request(self._block.pop())

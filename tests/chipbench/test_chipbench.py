@@ -87,8 +87,10 @@ def test_oltp_mixed_end_to_end(tmp_path, seen):
     result = drive(cell, tmp_path, seen)
     assert result["correct"] is True, result["compared"]
     assert result["attempted"] > 50 and result["failed"] == 0
-    assert set(result["metrics"]) == {"oltp_queries_per_s",
-                                      "oltp_query_p95_ms", "setup_s"}
+    # what the cell lists, which is these three and may be more
+    assert set(result["metrics"]) == {m["name"] for m in cell["end_to_end"]}
+    assert {"oltp_queries_per_s", "oltp_query_p95_ms",
+            "setup_s"} <= set(result["metrics"])
     assert all(m["value"] > 0 for m in result["metrics"].values())
     for name in ("reads_out_of_bounds", "quiesced_mismatches",
                  "readback_mismatches"):
@@ -261,7 +263,8 @@ def _result_lines(stdout):
 def test_script_fails_without_a_tpu():
     proc = subprocess.run(
         [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
-         CELLS[-1], "--seed", str(SEED), "--seconds", "1", "--trace", "0"],
+         cell_of_mix("oltp_mixed"), "--seed", str(SEED), "--seconds", "1",
+         "--trace", "0"],
         cwd=REPO, capture_output=True, text=True, timeout=300)
     assert proc.returncode != 0, proc.stdout
     assert not _result_lines(proc.stdout)
@@ -276,8 +279,9 @@ def test_script_fails_in_a_bare_directory(tmp_path):
                         ignore=shutil.ignore_patterns("__pycache__"))
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     proc = subprocess.run(
-        BENCHMARK["command"] + ["--workload", CELLS[0], "--seed", "1",
-                                "--seconds", "1", "--trace", "0"],
+        BENCHMARK["command"] + ["--workload", cell_of_mix("oltp_mixed"),
+                                "--seed", "1", "--seconds", "1",
+                                "--trace", "0"],
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
     assert proc.returncode != 0
     assert not _result_lines(proc.stdout)
@@ -494,6 +498,31 @@ def test_keys_are_skewed_and_new_ids_do_not_collide():
     new = [p.request("vertex_write").params["id"]
            for p in plans for _ in range(100)]
     assert len(set(new)) == 400 and min(new) > 10_000
+
+
+@pytest.mark.parametrize("seeds", [(1, 2), (SEED, 2**31 + 7)])
+def test_oltp_work_is_the_same_for_every_seed(seeds):
+    mix = _mix("oltp_mixed")
+    first, second = (traffic.Keys(mix["keys"], 10_000, s) for s in seeds)
+    assert first.ids.tolist() == second.ids.tolist()
+    assert first.ids.tolist() != list(range(10_000))    # still permuted
+    plans = [traffic.Plan(mix, 10_000, s, 0, k)
+             for s, k in zip(seeds, (first, second))]
+    drawn = [[(r.name, r.params) for r in (next(p) for _ in range(300))]
+             for p in plans]
+    assert drawn[0] != drawn[1]                 # another order ...
+    for name in {n for n, _ in drawn[0]}:       # ... of the same requests
+        assert [p for n, p in drawn[0] if n == name] \
+            == [p for n, p in drawn[1] if n == name]
+    # without the keys' own seed the run seed draws the hot set and keys
+    loose = dict(mix, keys={k: v for k, v in mix["keys"].items()
+                            if k != "seed"})
+    first, second = (traffic.Keys(loose["keys"], 10_000, s) for s in seeds)
+    assert first.ids.tolist() != second.ids.tolist()
+    plans = [traffic.Plan(loose, 10_000, s, 0, first) for s in seeds]
+    hops = [[r.params for r in (p.request("two_hop") for _ in range(20))]
+            for p in plans]
+    assert hops[0] != hops[1]
 
 
 def test_zipf_theta_0_is_uniform():

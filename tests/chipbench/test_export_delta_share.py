@@ -19,14 +19,15 @@ BENCH = os.path.join(REPO, "benchmarks", "chipbench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
+import bench_pins  # noqa: E402
 import layers  # noqa: E402
 import run  # noqa: E402
 
 NAME = "export_delta_share"
 CELLS = ["pokec_medium.analytics_fresh",
          "pokec_medium_daemon.analytics_fresh"]
-#: runs the same GraphCache once a cycle, and cannot list the metric
-#: until test_graphrag_cell.py stops pinning its per-layer list
+#: runs the same GraphCache once a cycle, and inserts a vertex in each:
+#: the one cell in which the share can read under 100
 RETRIEVAL = "graphrag_medium.retrieve_fresh"
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
@@ -55,14 +56,21 @@ def drive(cell, tmp_path, seconds):
                         t_start=time.perf_counter())
 
 
+def hold_pins(root=REPO):
+    """What this file holds of the BENCHMARK.json under `root`: the
+    share is an entry reported in every cell that exports, under any
+    name of the span it splits."""
+    bench = bench_pins.read(root)
+    bench_pins.entry_except_workloads(
+        bench_pins.entry(bench["per_layer"], NAME),
+        {"name": NAME, "unit": "%", "better": "higher",
+         "source": "program_counter", "layer": "CSR export",
+         "moves": "fresh_cycle_s", "workloads": CELLS + [RETRIEVAL]})
+
+
 def test_the_entry_names_the_export_cells_and_its_file_is_data():
-    entry, = [m for m in BENCHMARK["per_layer"] if m["name"] == NAME]
-    assert {k: v for k, v in entry.items() if k != "workloads"} == {
-        "name": NAME, "unit": "%", "better": "higher",
-        "source": "program_counter", "layer": "CSR export",
-        "moves": "fresh_cycle_s"}
-    # at least the cells that read the span it splits, under any name
-    assert set(CELLS) <= set(entry["workloads"])
+    hold_pins()
+    entry = bench_pins.entry(BENCHMARK["per_layer"], NAME)
     cells = {w["name"] for w in BENCHMARK["workloads"]}
     moved = next(m for m in BENCHMARK["end_to_end"]
                  if m["name"] == entry["moves"])
@@ -170,9 +178,7 @@ def test_the_retrieval_cells_inserted_vertex_is_spliced_too(tmp_path):
     before its hybrid CALL: the counters, read as the harness reads
     them, say every export of the window followed it."""
     cell = small(RETRIEVAL)
-    cell["per_layer"] = list(cell["per_layer"]) + [
-        dict(next(m for m in run.load_cell(CELLS[0])["per_layer"]
-                  if m["name"] == NAME))]
+    assert NAME in [m["name"] for m in cell["per_layer"]]
     result = drive(cell, tmp_path, 2.0)
     assert result["correct"] is True, result["compared"]
     assert result["cycles"] >= 2

@@ -21,6 +21,7 @@ BENCH = os.path.join(REPO, "benchmarks", "chipbench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
+import bench_pins  # noqa: E402
 import layers  # noqa: E402
 import run  # noqa: E402
 import seams  # noqa: E402
@@ -33,8 +34,6 @@ SEED = 2_147_483_693            # the driver's seeds pass 2**31
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCHMARK = json.load(_f)
-NEW_METRICS = [m["name"] for m in BENCHMARK["per_layer"]
-               if m.get("workloads") == [CELL]]
 PROGRAM_METRICS = ["ppr_batch_ms", "ppr_queue_ms", "ppr_reply_ms",
                    "ppr_riders_per_batch", "ppr_cache_hit_share",
                    "route_request_ms.ppr", "bolt_wait_ms.ppr",
@@ -42,6 +41,8 @@ PROGRAM_METRICS = ["ppr_batch_ms", "ppr_queue_ms", "ppr_reply_ms",
                    "daemon_routed_share.ppr"]
 TRACE_METRICS = ["device_idle_pct.ppr", "ppr_batch_device_ms",
                  "ppr_topk_device_ms", "ppr_batch_roofline"]
+#: the cell's own per-layer metrics, in the order they were appended
+NEW_METRICS = TRACE_METRICS + PROGRAM_METRICS
 
 sem = seams.load_module(None, "semantics", "ppr_sets")
 dataset = seams.load_module(None, "datasets", "pokec_catalogue")
@@ -292,16 +293,34 @@ def test_the_mix_and_the_deployment_state_what_the_issue_fixed():
     assert set(config["reduced_why"]) >= {"nodes, edges", "schema", "device"}
 
 
-def test_the_new_entries_are_appended_and_name_files():
-    entry = BENCHMARK["configs"][-1]
-    assert entry["name"] == CONFIG and len(entry["source"]) <= 200
-    assert BENCHMARK["workloads"][-1]["name"] == CELL
-    assert BENCHMARK["workloads"][-1]["chips"] == 1
-    assert len(BENCHMARK["workloads"][-1]["why"]) <= 200
-    names = [m["name"] for m in BENCHMARK["per_layer"]]
-    assert names[-len(NEW_METRICS):] == NEW_METRICS
-    for m in BENCHMARK["per_layer"][-len(NEW_METRICS):]:
-        assert m["moves"] == "oltp_queries_per_s" and m["workloads"] == [CELL]
+def hold_pins(root=REPO):
+    """What this file holds of the BENCHMARK.json under `root`: the
+    deployment, the cell and the cell's metrics are entries in the order
+    they were appended; the cell reports each of them and the
+    end-to-end metrics it has."""
+    bench = bench_pins.read(root)
+    bench_pins.stand_in_order(bench["configs"], [CONFIG])
+    bench_pins.stand_in_order(bench["workloads"], [CELL])
+    bench_pins.stand_in_order(bench["per_layer"], NEW_METRICS)
+    for name in NEW_METRICS:
+        bench_pins.listed_for(bench_pins.entry(bench["per_layer"], name),
+                              [CELL])
+    for name in ("oltp_queries_per_s", "setup_s"):
+        bench_pins.listed_for(bench_pins.entry(bench["end_to_end"], name),
+                              [CELL])
+    bench_pins.stand_in_order(run.load_cell(CELL, root)["per_layer"],
+                              NEW_METRICS)
+
+
+def test_the_new_entries_stand_in_order_and_name_files():
+    hold_pins()
+    entry = bench_pins.entry(BENCHMARK["configs"], CONFIG)
+    assert len(entry["source"]) <= 200
+    cell_entry = bench_pins.entry(BENCHMARK["workloads"], CELL)
+    assert cell_entry["chips"] == 1 and len(cell_entry["why"]) <= 200
+    for name in NEW_METRICS:
+        m = bench_pins.entry(BENCHMARK["per_layer"], name)
+        assert m["moves"] == "oltp_queries_per_s"
         with open(os.path.join(BENCH, "layer_metrics",
                                m["name"] + ".json")) as f:
             spec = json.load(f)
@@ -311,9 +330,6 @@ def test_the_new_entries_are_appended_and_name_files():
     layers_named = {m["layer"] for m in BENCHMARK["per_layer"]
                     if m["name"] in NEW_METRICS}
     assert "PPR plane" in layers_named
-    for m in BENCHMARK["end_to_end"]:
-        if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL
     # every seam name against its file
     cell = run.load_cell(CELL)
     layout, data, semantics = run.seams_of(cell)

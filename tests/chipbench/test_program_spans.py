@@ -22,6 +22,7 @@ BENCH = os.path.join(REPO, "benchmarks", "chipbench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
+import bench_pins  # noqa: E402
 import gap_spans  # noqa: E402
 import layers  # noqa: E402
 import run  # noqa: E402
@@ -74,11 +75,23 @@ def values(result):
 # the metric files
 # --------------------------------------------------------------------------
 
+def hold_pins(root=REPO):
+    """What this file holds of the BENCHMARK.json under `root`: each
+    metric is an entry that reads the program and is reported in the
+    cells it was written for."""
+    bench = bench_pins.read(root)
+    for name in PROGRAM_METRICS:
+        entry = bench_pins.entry(bench["per_layer"], name)
+        assert entry["source"] in ("program_span", "program_counter")
+        assert "workloads" in entry
+        bench_pins.listed_for(entry, [MEDIUM] if name in MEDIUM_NEW
+                              else [SMALL])
+
+
 @pytest.mark.parametrize("name", PROGRAM_METRICS)
 def test_metric_file_is_data_for_a_reader_that_exists(name):
-    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
-    assert entry["source"] in ("program_span", "program_counter")
-    assert entry["workloads"] == [MEDIUM if name in MEDIUM_NEW else SMALL]
+    hold_pins()
+    entry = bench_pins.entry(BENCHMARK["per_layer"], name)
     with open(os.path.join(BENCH, "layer_metrics", name + ".json")) as f:
         spec = json.load(f)
     assert spec["kind"] in layers.READERS

@@ -20,6 +20,7 @@ BENCH = os.path.join(REPO, "benchmarks", "chipbench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
+import bench_pins  # noqa: E402
 import layers  # noqa: E402
 import run  # noqa: E402
 
@@ -110,9 +111,22 @@ def per_count(ctx, phase):
 # the files
 # --------------------------------------------------------------------------
 
+def hold_pins(root=REPO):
+    """What this file holds of the BENCHMARK.json under `root`: the six
+    stand in the order of the table, each is the table's entry, and is
+    reported in the table's cells."""
+    bench = bench_pins.read(root)
+    bench_pins.stand_in_order(bench["per_layer"], NAMES)
+    for name, unit, layer, _num, _den, moves, workloads in TABLE:
+        bench_pins.entry_except_workloads(
+            bench_pins.entry(bench["per_layer"], name),
+            {"name": name, "unit": unit, "better": "lower",
+             "source": "program_span", "layer": layer,
+             "moves": moves, "workloads": workloads})
+
+
 def test_the_six_are_appended_in_the_order_of_the_table():
-    tail = [m["name"] for m in BENCHMARK["per_layer"][-len(TABLE):]]
-    assert tail == NAMES
+    hold_pins()
     assert len({m["name"] for m in BENCHMARK["per_layer"]}) == \
         len(BENCHMARK["per_layer"])
 
@@ -120,16 +134,14 @@ def test_the_six_are_appended_in_the_order_of_the_table():
 @pytest.mark.parametrize("row", TABLE, ids=NAMES)
 def test_entry_and_file_are_the_tables(row):
     name, unit, layer, numerator, denominator, moves, workloads = row
-    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
-    assert entry == {"name": name, "unit": unit, "better": "lower",
-                     "source": "program_span", "layer": layer,
-                     "moves": moves, "workloads": workloads}
+    hold_pins()
+    entry = bench_pins.entry(BENCHMARK["per_layer"], name)
     spec = spec_of(name)
     assert spec["kind"] == "stats_delta" and spec["kind"] in layers.READERS
     assert spec["params"] == {"numerator": [numerator],
                               "denominator": denominator, "scale": 1000.0}
     # a cell lists a metric only where it reports what the metric moves
-    for cell in workloads:
+    for cell in entry["workloads"]:
         assert any(m["name"] == moves and cell in m.get("workloads", [cell])
                    for m in BENCHMARK["end_to_end"])
 
