@@ -102,16 +102,19 @@ async def start_monitoring_server(host: str, port: int, ictx):
                                 ("ppr.", "kernel_server.daemon.ppr."))},
                     # the chip owner's plane: the compile witness
                     # (jit.*), the MXU program table's hits and misses
-                    # (mxu.*), in-process fixpoint iterations (device.*)
-                    # and every phase span's seconds and closes (span.*),
-                    # with the two counters that say which operator the
-                    # query.sort span's closes were (TopK or OrderBy) and
-                    # the CALLs that yielded a TopK's bound only
+                    # (mxu.*), in-process fixpoint iterations (device.*),
+                    # the analytics procedures' calls, iterations and
+                    # routing (analytics.*) and every phase span's
+                    # seconds and closes (span.*), with the two counters
+                    # that say which operator the query.sort span's
+                    # closes were (TopK or OrderBy) and the CALLs that
+                    # yielded a TopK's bound only
                     "device": {name: value for name, _k, value
                                in global_metrics.snapshot()
                                if name.startswith(
                                    ("jit.", "mxu.", "device.", "span.",
-                                    "query.topk_", "query.sort_full_"))},
+                                    "analytics.", "query.topk_",
+                                    "query.sort_full_"))},
                     # incremental analytics plane (r19, mgdelta):
                     # delta applies/compactions/fallbacks, warm-start
                     # counters, resident-generation gauge (local plus

@@ -182,6 +182,16 @@ STAT_NAMES = (
     "analytics.device_fault.*",    # typed per-kind device-fault counters
     "analytics.kernel_routed_total",
     "analytics.kernel_route_fallback_total",
+    # the Graphalytics procedures: one call each, and the iterations its
+    # kernel returned (0 for a stored answer)
+    "analytics.bfs.calls_total",
+    "analytics.bfs.iterations_total",
+    "analytics.sssp.calls_total",
+    "analytics.sssp.iterations_total",
+    "analytics.wcc.calls_total",
+    "analytics.wcc.iterations_total",
+    "analytics.cdlp.calls_total",
+    "analytics.cdlp.iterations_total",
     # streaming ingestion plane (r17, mgstream): supervised exactly-once
     # consumers — transactional offsets, quarantine, backpressure
     "stream.batches_total",         # batches durably committed

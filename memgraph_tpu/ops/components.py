@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import trace as mgtrace
 from . import semiring as S
 from .csr import DeviceGraph
 
@@ -54,15 +55,18 @@ def weakly_connected_components(graph: DeviceGraph,
     if comp0 is not None:
         arr = np.asarray(comp0, dtype=np.int32)[:graph.n_nodes]
         start[:len(arr)] = arr
-    comp, _, iters = S.fixpoint(
-        "min_first",
-        arrays={"src": graph.src_idx, "dst": graph.col_idx},
-        x0=jnp.asarray(start), n_out=graph.n_pad,
-        epilogue=_wcc_epilogue, max_iterations=max_iterations,
-        metric="changed", direction="both")
-    # one fused host transfer for the whole result tuple (MG009)
-    comp_h, iters_h = jax.device_get((comp[:graph.n_nodes], iters))  # mglint: disable=MG009 — results must ship host; this IS the single fused transfer for the whole tuple
-    return comp_h, int(iters_h)
+    with mgtrace.span("analytics.launch"):
+        comp, _, iters = S.fixpoint(
+            "min_first",
+            arrays={"src": graph.src_idx, "dst": graph.col_idx},
+            x0=jnp.asarray(start), n_out=graph.n_pad,
+            epilogue=_wcc_epilogue, max_iterations=max_iterations,
+            metric="changed", direction="both")
+    # one fused host transfer for the whole result tuple (MG009), cut to
+    # n_nodes on the host: a device slice is a program per vertex count
+    with mgtrace.span("analytics.device_wait", backend="segment"):
+        comp_h, iters_h = jax.device_get((comp, iters))  # mglint: disable=MG009 — results must ship host; this IS the single fused transfer for the whole tuple
+    return np.asarray(comp_h)[:graph.n_nodes], int(iters_h)
 
 
 @partial(jax.jit, static_argnames=("n_pad", "max_iterations"))

@@ -683,13 +683,15 @@ class GraphCache:
                     and getattr(base_g, "_mxu_state", None) is not None:
                 object.__setattr__(g, "_delta_ctx", (base_g, changed))
         with self._lock:
-            # keep base anchors, this version's variants (e.g. other
-            # weight properties), and NEWER versions (an older-view txn
-            # storing must not evict a newer snapshot — r5 review);
-            # drop strictly older version snapshots
+            # keep base anchors, NEWER versions (an older-view txn
+            # storing must not evict a newer snapshot — r5 review) and
+            # the other views' snapshots (e.g. other weight properties:
+            # each is the next delta export's base of its own view);
+            # drop this view's strictly older snapshots
             per = self._cache.get(storage) or {}
             prev = {k: v for k, v in per.items()
-                    if k[0] == "base" or k[0] >= version}
+                    if k[0] == "base" or k[0] >= version
+                    or k[1:] != key[1:]}
             # the previous snapshot becomes the base anchor once a FULL
             # plan was built on it (pagerank marks _mxu_base_self)
             for k, v in per.items():

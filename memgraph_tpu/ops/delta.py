@@ -31,11 +31,21 @@ idea to the whole semiring core:
     katz iterate contractions with a unique fixpoint — ANY seed
     converges to the same answer at the same tol, so the previous
     solution is always a valid x0 (residual-equivalent to cold,
-    enforced by tests/test_delta.py). WCC's min-label propagation and
-    labelprop's election are only warm-safe when the delta is
-    monotone (edge ADDITIONS only — components can merge but never
-    split, labels can only be re-elected over a superset); a delta with
-    removals forces a LOUD cold start (``delta.cold_start_total``).
+    enforced by tests/test_delta.py). WCC's min-label propagation is
+    warm-safe when the delta is monotone (edge ADDITIONS only —
+    components can merge but never split, so the fixpoint from the old
+    labels is the cold one); a delta with removals forces a LOUD cold
+    start (``delta.cold_start_total``). A label-propagation election
+    is NOT warm-safe: its answer is the labels after T synchronous
+    rounds from the vertex ids (LDBC Graphalytics CDLP), and T rounds
+    from the previous labels elect other labels after almost any
+    commit. The exact procedures (``label_propagation.get``,
+    ``community_detection.get``) therefore run cold on a moved graph
+    (``cdlp``: ``never``) and only serve their stored answer on the
+    unchanged one; the warm seed is kept for
+    ``community_detection_online.get`` alone (``labelprop``), whose
+    upstream counterpart (LabelRankT) is approximate by nature, under
+    the monotone gate.
 
 The warm-start framing follows "Accelerating Personalized PageRank
 Vector Computation" (PAPERS.md): after a small perturbation the residual
@@ -80,12 +90,20 @@ DELTA_MAX_FRACTION = float(
 #:   "adds_only"  — monotone iteration; warm only when the cumulative
 #:                  delta since the seed solution added edges but never
 #:                  removed any, else LOUD cold start
+#:   "never"      — the answer depends on the seed, and the seed is the
+#:                  vertex ids: a moved graph runs cold, silently, and
+#:                  only the unchanged graph's stored answer is served
 WARM_START_POLICY = {
     "pagerank": "always",
     "ppr": "always",
     "katz": "always",
     "wcc": "adds_only",
+    # community_detection_online.get only: an election seeded by the
+    # previous labels is a fixpoint of the election on the new graph,
+    # not the cold answer (see the module docstring)
     "labelprop": "adds_only",
+    # label_propagation.get / community_detection.get: exact CDLP
+    "cdlp": "never",
 }
 
 
@@ -922,6 +940,8 @@ class LocalWarmPool:
                 return None, None  # dense ids shifted: seed meaningless
             if version == sol.version:
                 return np.asarray(sol.x), None
+            if WARM_START_POLICY.get(algo) == "never":
+                return None, None
             monotone_ok = sol.monotone_ok
             if version != entry["version"]:
                 changed = storage.changes_between(entry["version"],

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import trace as mgtrace
 from . import semiring as S
 from .csr import DeviceGraph
 
@@ -78,9 +79,12 @@ def label_propagation(graph: DeviceGraph, max_iterations: int = 30,
     `mesh` (MeshContext | Mesh | int | None) routes through the
     multi-chip layer; see ops.pagerank.pagerank.
 
-    `labels0` warm-starts the election from a previous labeling —
-    callers must hold the ops/delta.py monotone contract (adds-only
-    deltas; a removal must cold-start LOUDLY).
+    `labels0` seeds the election from a previous labeling. The answer
+    then depends on the seed: rounds from the old labels are not the
+    same rounds from the ids, so an exact label propagation never seeds
+    (ops/delta.py ``WARM_START_POLICY``); the approximate online variant
+    that does must hold the monotone contract there (adds-only deltas;
+    a removal must cold-start LOUDLY).
     """
     backend, ctx = S.route_backend(graph, mesh, semiring="max_min")
     if backend == "mesh":
@@ -100,13 +104,16 @@ def label_propagation(graph: DeviceGraph, max_iterations: int = 30,
     if labels0 is not None:
         arr = np.asarray(labels0, dtype=np.int32)[:graph.n_nodes]
         start[:len(arr)] = arr
-    labels, _, iters = S.fixpoint(
-        "max_min",
-        arrays={"src": src2, "dst": dst2, "w": w2},
-        params={"self_weight": np.float32(self_weight)},
-        x0=jnp.asarray(start), n_out=graph.n_pad,
-        step=_labelprop_step, epilogue=_labelprop_epilogue,
-        max_iterations=max_iterations, metric="changed")
-    # one fused host transfer for the whole result tuple (MG009)
-    labels_h, iters_h = jax.device_get((labels[:graph.n_nodes], iters))  # mglint: disable=MG009 — results must ship host; this IS the single fused transfer for the whole tuple
-    return labels_h, int(iters_h)
+    with mgtrace.span("analytics.launch"):
+        labels, _, iters = S.fixpoint(
+            "max_min",
+            arrays={"src": src2, "dst": dst2, "w": w2},
+            params={"self_weight": np.float32(self_weight)},
+            x0=jnp.asarray(start), n_out=graph.n_pad,
+            step=_labelprop_step, epilogue=_labelprop_epilogue,
+            max_iterations=max_iterations, metric="changed")
+    # one fused host transfer for the whole result tuple (MG009), cut to
+    # n_nodes on the host: a device slice is a program per vertex count
+    with mgtrace.span("analytics.device_wait", backend="segment"):
+        labels_h, iters_h = jax.device_get((labels, iters))  # mglint: disable=MG009 — results must ship host; this IS the single fused transfer for the whole tuple
+    return np.asarray(labels_h)[:graph.n_nodes], int(iters_h)

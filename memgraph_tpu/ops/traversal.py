@@ -8,11 +8,15 @@ regime: when the query wants distances/paths from a source over the whole
 graph, a min-plus semiring fixpoint (Bellman-Ford: gather + ⊕=min until
 fixpoint) beats pull-based expansion by orders of magnitude on TPU.
 
-BFS additionally rides the core's direction-optimizing push/pull
-selection (semiring.select_pull, the Beamer/GraphBLAST heuristic): a
-sparse frontier relaxes push-style (frontier-masked contributions), a
-dense one pulls over every edge — both exact, chosen per level from the
-frontier's out-edge mass.
+Directed BFS additionally rides the core's direction-optimizing
+push/pull selection (semiring.select_pull, the Beamer/GraphBLAST
+heuristic): a sparse frontier relaxes push-style (frontier-masked
+contributions), a dense one pulls over every edge — both exact, chosen
+per level from the frontier's out-edge mass. Undirected BFS (Graph500
+kernel 2) is a program of its own, jit_fixpoint_bfs_undirected: int32
+levels, a hop is +1 and no weight is read, both orientations of every
+edge an iteration. Every entry point returns host arrays and records
+the ``analytics.launch`` / ``analytics.device_wait`` phases.
 
 The point-query regime (short anchored expansions) stays on the host
 executor, which walks adjacency directly — same split the reference makes
@@ -27,11 +31,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability import trace as mgtrace
 from . import semiring as S
 from .csr import DeviceGraph
 
 INF = jnp.float32(3.4e38)
 _UNREACHED = 1.7e38     # INF / 2 as a host number, for arrays read back
+#: the int32 level of a vertex the undirected sweep has not reached; a
+#: hop added to it stays far below int32's top
+_NO_LEVEL = 1 << 30
+
+
+def _read_back(x, iters, n_nodes: int):
+    """The padded iterate and the iteration count in one transfer, cut
+    to ``n_nodes`` on the host: a device slice would be a program of its
+    own for every vertex count."""
+    with mgtrace.span("analytics.device_wait", backend="segment"):
+        x_h, iters_h = jax.device_get((x, iters))  # mglint: disable=MG009 — the one fused result transfer of the call
+    return np.asarray(x_h)[:n_nodes], int(iters_h)
 
 
 def _sssp_step_directed(dist, A, env, P, n_out):
@@ -57,8 +74,9 @@ def _sssp_epilogue(dist, new, env, P):
 def sssp(graph: DeviceGraph, source: int, weighted: bool = True,
          directed: bool = True, max_iterations: int = 10_000):
     """Bellman-Ford SSSP as a min-plus fixpoint. Returns
-    (dist[:n_nodes] float32, iterations); unreachable nodes get +inf.
-    With weighted=False computes hop counts (= BFS levels)."""
+    (dist[:n_nodes] float32 on the host, iterations); unreachable nodes
+    get +inf. With weighted=False computes hop counts (= BFS levels).
+    Undirected, an iteration relaxes both orientations of every edge."""
     w = graph.weights if weighted else jnp.where(
         jnp.arange(graph.e_pad) < graph.n_edges, 1.0, INF).astype(jnp.float32)
     if weighted:
@@ -66,16 +84,17 @@ def sssp(graph: DeviceGraph, source: int, weighted: bool = True,
         w = jnp.where(jnp.arange(graph.e_pad) < graph.n_edges, w, INF)
     dist0 = np.full((graph.n_pad,), float(INF), dtype=np.float32)
     dist0[source] = 0.0
-    dist, _, iters = S.fixpoint(
-        "min_plus",
-        arrays={"src": graph.src_idx, "dst": graph.col_idx, "w": w},
-        x0=jnp.asarray(dist0), n_out=graph.n_pad,
-        step=(_sssp_step_directed if directed
-              else _sssp_step_undirected),
-        epilogue=_sssp_epilogue, max_iterations=max_iterations,
-        metric="changed")
-    out = dist[:graph.n_nodes]
-    return jnp.where(out >= INF / 2, jnp.inf, out), int(iters)
+    with mgtrace.span("analytics.launch"):
+        dist, _, iters = S.fixpoint(
+            "min_plus",
+            arrays={"src": graph.src_idx, "dst": graph.col_idx, "w": w},
+            x0=jnp.asarray(dist0), n_out=graph.n_pad,
+            step=(_sssp_step_directed if directed
+                  else _sssp_step_undirected),
+            epilogue=_sssp_epilogue, max_iterations=max_iterations,
+            metric="changed")
+    out, iters = _read_back(dist, iters, graph.n_nodes)
+    return np.where(out >= _UNREACHED, np.inf, out), iters
 
 
 def _bfs_step(x, A, env, P, n_out):
@@ -104,39 +123,69 @@ def _bfs_epilogue(x, new, env, P):
 
 def do_bfs(graph: DeviceGraph, source: int, max_iterations: int = 10_000):
     """Direction-optimizing BFS (directed): returns (dist f32 hops with
-    +inf for unreachable, iterations).  Level-exact vs the plain
-    min-plus fixpoint — only the push/pull execution strategy differs."""
+    +inf for unreachable, on the host; iterations).  Level-exact vs the
+    plain min-plus fixpoint — only the push/pull execution strategy
+    differs."""
     w = jnp.where(jnp.arange(graph.e_pad) < graph.n_edges, 1.0,
                   INF).astype(jnp.float32)
     dist0 = np.full((graph.n_pad,), float(INF), dtype=np.float32)
     dist0[source] = 0.0
     frontier0 = np.zeros(graph.n_pad, dtype=bool)
     frontier0[source] = True
-    (dist, _), _, iters = S.fixpoint(
-        "min_plus",
-        arrays={"src": graph.src_idx, "dst": graph.col_idx, "w": w,
-                "deg": graph.out_degree},
-        params={"n_edges": np.float32(graph.n_edges)},
-        x0=(jnp.asarray(dist0), jnp.asarray(frontier0)),
-        n_out=graph.n_pad, step=_bfs_step, epilogue=_bfs_epilogue,
-        max_iterations=max_iterations, metric="changed")
-    out = dist[:graph.n_nodes]
-    return jnp.where(out >= INF / 2, jnp.inf, out), int(iters)
+    with mgtrace.span("analytics.launch"):
+        (dist, _), _, iters = S.fixpoint(
+            "min_plus",
+            arrays={"src": graph.src_idx, "dst": graph.col_idx, "w": w,
+                    "deg": graph.out_degree},
+            params={"n_edges": np.float32(graph.n_edges)},
+            x0=(jnp.asarray(dist0), jnp.asarray(frontier0)),
+            n_out=graph.n_pad, step=_bfs_step, epilogue=_bfs_epilogue,
+            max_iterations=max_iterations, metric="changed")
+    out, iters = _read_back(dist, iters, graph.n_nodes)
+    return np.where(out >= _UNREACHED, np.inf, out), iters
+
+
+def _bfs_undirected_step(level, A, env, P, n_out):
+    """A hop is +1 and no weight is read: both orientations of every
+    edge, the second over the levels the first just lowered (as
+    _sssp_step_undirected)."""
+    hop = jnp.int32(1)
+    new = jnp.minimum(level, S.spmv("min_plus", level, A["src"], A["dst"],
+                                    hop, n_out=n_out))
+    back = S.spmv("min_plus", new, A["dst"], A["src"], hop, n_out=n_out)
+    return jnp.minimum(new, back)
+
+
+def _bfs_undirected_epilogue(level, new, env, P):
+    return new, jnp.any(new < level)
+
+
+def _bfs_undirected(graph: DeviceGraph, source: int, max_iterations: int):
+    """int32 levels over both orientations of every edge: the program
+    jit_fixpoint_bfs_undirected. Padding edges run sink to sink, which
+    no source reaches, so they stay inert."""
+    level0 = np.full((graph.n_pad,), _NO_LEVEL, dtype=np.int32)
+    level0[source] = 0
+    with mgtrace.span("analytics.launch"):
+        level, _, iters = S.fixpoint(
+            "min_plus",
+            arrays={"src": graph.src_idx, "dst": graph.col_idx},
+            x0=jnp.asarray(level0), n_out=graph.n_pad,
+            step=_bfs_undirected_step, epilogue=_bfs_undirected_epilogue,
+            max_iterations=max_iterations, metric="changed")
+    level, iters = _read_back(level, iters, graph.n_nodes)
+    return np.where(level >= _NO_LEVEL, -1, level).astype(np.int32), iters
 
 
 def bfs_levels(graph: DeviceGraph, source: int, directed: bool = True,
                max_iterations: int = 10_000):
-    """BFS levels from source (-1 for unreachable).  The directed case
-    rides the direction-optimizing push/pull core path; the undirected
-    view falls back to the Gauss-Seidel min-plus fixpoint."""
-    if directed:
-        dist, iters = do_bfs(graph, source, max_iterations=max_iterations)
-    else:
-        dist, iters = sssp(graph, source, weighted=False,
-                           directed=directed,
-                           max_iterations=max_iterations)
-    levels = jnp.where(jnp.isinf(dist), -1, dist.astype(jnp.int32))
-    return levels, iters
+    """BFS levels from source (-1 for unreachable), int32 on the host.
+    The directed case rides the direction-optimizing push/pull core
+    path; the undirected one is a unit-hop sweep of its own."""
+    if not directed:
+        return _bfs_undirected(graph, source, max_iterations)
+    dist, iters = do_bfs(graph, source, max_iterations=max_iterations)
+    return np.where(np.isinf(dist), -1, dist).astype(np.int32), iters
 
 
 @partial(jax.jit, static_argnames=("n_pad", "max_iterations"))

@@ -42,27 +42,35 @@ _PPR_PUSHED_LOCK = threading.Lock()
 
 def _rank_results(ctx, graph, values, field_name):
     """One row per vertex, or under the call's ``row_bound`` on this
-    field only the rows that can reach the result (_bounded_rows). Two
+    field only the rows that can reach the result (_bounded_rows)."""
+    def choose():
+        bound = ctx.row_bound
+        if bound is not None and bound.field == field_name:
+            indices = _bounded_rows(ctx, graph, values, bound)
+            if indices is not None:
+                global_metrics.increment("query.topk_pushdown_total")
+                return indices
+        return range(graph.n_nodes)
+    yield from _vertex_rows(ctx, graph, choose, values, field_name, float)
+
+
+def _vertex_rows(ctx, graph, indices, values, field_name, convert):
+    """``{"node", field_name: convert(values[i])}`` for each index that
+    ``indices()`` gives, in that order, where the vertex is visible. Two
     phases, recorded once when the generator ends: ``analytics.rows``,
     the time spent in here choosing and making the rows, and
     ``analytics.consume``, the time the plan's operators above the CALL
     took between two rows (a TopK's selection on the sort keys; without
-    a LIMIT, Produce's expressions and OrderBy's collecting)."""
+    a LIMIT, Produce's expressions and OrderBy's collecting, or an
+    aggregation's grouping)."""
     started = time.time()
     inside = outside = 0.0
     t0 = time.perf_counter()
     try:
-        indices, bound = None, ctx.row_bound
-        if bound is not None and bound.field == field_name:
-            indices = _bounded_rows(ctx, graph, values, bound)
-        if indices is None:
-            indices = range(graph.n_nodes)
-        else:
-            global_metrics.increment("query.topk_pushdown_total")
-        for i in indices:
+        for i in indices():
             node = ctx.vertex_by_index(graph, i)
             if node is not None:
-                row = {"node": node, field_name: float(values[i])}
+                row = {"node": node, field_name: convert(values[i])}
                 t1 = time.perf_counter()
                 inside += t1 - t0
                 yield row
@@ -446,38 +454,60 @@ for _name in ("katz_centrality.get", "katz_centrality_tpu.get",
                   results=[("node", "NODE"), ("rank", "FLOAT")])(_katz_impl)
 
 
-def _community_impl(ctx, max_iterations=30, weight_property=None):
+def _community_impl(ctx, max_iterations=30, weight_property=None,
+                    online=False):
+    """LDBC Graphalytics CDLP: ``max_iterations`` synchronous rounds from
+    the vertex ids, each vertex taking the label most frequent among its
+    neighbours (weighted by ``weight_property`` where given), ties to
+    the smallest id; labels are dense indices, which are the vertices'
+    creation order. After a commit the exact procedures run cold: T
+    rounds from the previous labels elect other labels
+    (ops/delta.py ``WARM_START_POLICY``). ``online`` (the approximate
+    community_detection_online.get) seeds the election from the previous
+    labels after an adds-only commit."""
     from ..ops.labelprop import label_propagation
     graph = ctx.device_graph(weight_property=weight_property)
     if graph.n_nodes == 0:
         return
-    # warm seed only over monotone (adds-only) deltas — the pool
-    # verifies against the real edge diff and cold-starts LOUDLY else
+    algo = "labelprop" if online else "cdlp"
     cached, labels0, store = _warm_prepare(
-        ctx, graph, "labelprop",
-        ("labelprop", int(max_iterations), weight_property))
+        ctx, graph, algo, (algo, int(max_iterations), weight_property))
+    iters = 0
     if cached is not None:
         labels = cached
     else:
+        # an exact election never seeds, not even from a PROFILE-d
+        # CALL's demoted cache hit
         labels, iters = label_propagation(
-            graph, max_iterations=int(max_iterations), labels0=labels0)
-        store(labels, iters)
-    labels = np.asarray(labels)
+            graph, max_iterations=int(max_iterations),
+            labels0=labels0 if online else None)
+        store(labels, iters if online else None)
+    # apart from device.fixpoint_iterations_total, which is PageRank's
+    global_metrics.increment("analytics.cdlp.calls_total")
+    global_metrics.increment("analytics.cdlp.iterations_total", iters)
     # compact community ids to 1..k (reference convention: ids start at 1)
-    uniq = {int(l): i + 1 for i, l in enumerate(sorted(set(labels.tolist())))}
-    for i in range(graph.n_nodes):
-        node = ctx.vertex_by_index(graph, i)
-        if node is not None:
-            yield {"node": node, "community_id": uniq[int(labels[i])]}
+    _, community = np.unique(np.asarray(labels), return_inverse=True)
+    community += 1
+    yield from _vertex_rows(ctx, graph, lambda: range(graph.n_nodes),
+                            community, "community_id", int)
 
 
 for _name in ("community_detection.get", "community_detection_tpu.get",
-              "community_detection_online.get", "label_propagation.get"):
+              "label_propagation.get"):
     mgp.read_proc(_name,
                   opt_args=[("max_iterations", "INTEGER", 30),
                             ("weight_property", "STRING", None)],
                   results=[("node", "NODE"),
                            ("community_id", "INTEGER")])(_community_impl)
+
+
+@mgp.read_proc("community_detection_online.get",
+               opt_args=[("max_iterations", "INTEGER", 30),
+                         ("weight_property", "STRING", None)],
+               results=[("node", "NODE"), ("community_id", "INTEGER")])
+def _community_online(ctx, max_iterations=30, weight_property=None):
+    yield from _community_impl(ctx, max_iterations, weight_property,
+                               online=True)
 
 
 def _wcc_impl(ctx):
@@ -488,16 +518,16 @@ def _wcc_impl(ctx):
     # warm seed only over monotone (adds-only) deltas — min-labels can
     # merge components but never split; removals cold-start LOUDLY
     cached, comp0, store = _warm_prepare(ctx, graph, "wcc", ("wcc",))
+    iters = 0
     if cached is not None:
         comp = cached
     else:
         comp, iters = weakly_connected_components(graph, comp0=comp0)
         store(comp, iters)
-    comp = np.asarray(comp)
-    for i in range(graph.n_nodes):
-        node = ctx.vertex_by_index(graph, i)
-        if node is not None:
-            yield {"node": node, "component_id": int(comp[i])}
+    global_metrics.increment("analytics.wcc.calls_total")
+    global_metrics.increment("analytics.wcc.iterations_total", iters)
+    yield from _vertex_rows(ctx, graph, lambda: range(graph.n_nodes),
+                            np.asarray(comp), "component_id", int)
 
 
 for _name in ("weakly_connected_components.get", "wcc.get",
@@ -619,6 +649,8 @@ def betweenness_get(ctx, normalized=True, directed=True, num_samples=64):
                opt_args=[("directed", "BOOLEAN", True)],
                results=[("node", "NODE"), ("level", "INTEGER")])
 def bfs_get(ctx, source, directed=True):
+    """Graph500 kernel 2 with ``directed`` false: levels over both
+    orientations of every relationship."""
     from ..ops.traversal import bfs_levels
     graph = ctx.device_graph()
     if graph.n_nodes == 0 or source is None:
@@ -626,20 +658,22 @@ def bfs_get(ctx, source, directed=True):
     sidx = graph.gid_to_idx.get(source.gid)
     if sidx is None:
         return
-    levels, _ = bfs_levels(graph, sidx, directed=bool(directed))
-    levels = np.asarray(levels)
-    for i in range(graph.n_nodes):
-        if levels[i] >= 0:
-            node = ctx.vertex_by_index(graph, i)
-            if node is not None:
-                yield {"node": node, "level": int(levels[i])}
+    levels, iters = bfs_levels(graph, sidx, directed=bool(directed))
+    global_metrics.increment("analytics.bfs.calls_total")
+    global_metrics.increment("analytics.bfs.iterations_total", iters)
+    yield from _vertex_rows(ctx, graph,
+                            lambda: np.flatnonzero(levels >= 0).tolist(),
+                            levels, "level", int)
 
 
 @mgp.read_proc("sssp.get",
                args=[("source", "NODE")],
-               opt_args=[("weight_property", "STRING", "weight")],
+               opt_args=[("weight_property", "STRING", "weight"),
+                         ("directed", "BOOLEAN", True)],
                results=[("node", "NODE"), ("distance", "FLOAT")])
-def sssp_get(ctx, source, weight_property="weight"):
+def sssp_get(ctx, source, weight_property="weight", directed=True):
+    """Graph500 kernel 3 with ``directed`` false: distances over both
+    orientations of every relationship."""
     from ..ops.traversal import sssp
     graph = ctx.device_graph(weight_property=weight_property)
     if graph.n_nodes == 0 or source is None:
@@ -647,13 +681,12 @@ def sssp_get(ctx, source, weight_property="weight"):
     sidx = graph.gid_to_idx.get(source.gid)
     if sidx is None:
         return
-    dist, _ = sssp(graph, sidx, weighted=True, directed=True)
-    dist = np.asarray(dist)
-    for i in range(graph.n_nodes):
-        if np.isfinite(dist[i]):
-            node = ctx.vertex_by_index(graph, i)
-            if node is not None:
-                yield {"node": node, "distance": float(dist[i])}
+    dist, iters = sssp(graph, sidx, weighted=True, directed=bool(directed))
+    global_metrics.increment("analytics.sssp.calls_total")
+    global_metrics.increment("analytics.sssp.iterations_total", iters)
+    yield from _vertex_rows(ctx, graph,
+                            lambda: np.flatnonzero(np.isfinite(dist)).tolist(),
+                            dist, "distance", float)
 
 
 @mgp.read_proc("graph_util.khop",

@@ -182,6 +182,71 @@ def test_segment_pagerank_fixpoint_compiles(sds, pokec):
                       tol="float32"), None).compile()
 
 
+#: graph500_s17_inproc's padded shapes: 90,162 vertices, 1,864,185
+#: relationships (benchmarks/chipbench/configs/graph500_s17_inproc.json)
+GRAPH500_N_PAD, GRAPH500_E_PAD = 1 << 17, 1 << 21
+
+
+def _while_body_ops(compiled) -> list:
+    """The names of the instructions of the compiled program's while
+    body, as the device trace's XLA Ops line names its events."""
+    text = compiled.as_text()
+    body = re.search(r"body=%?([\w.\-]+)", text).group(1)
+    block = re.search(r"\n%?" + re.escape(body) + r" [^\n]*\{\n(.*?)\n\}",
+                      text, re.S).group(1)
+    return [re.match(r"\s*(?:ROOT )?(%[\w.\-]+) =", line).group(1)
+            for line in block.splitlines() if " = " in line]
+
+
+@pytest.fixture(scope="module")
+def sweeps(sds):
+    """The undirected sweeps of graph500_s17.graphalytics_fresh, compiled
+    at its shapes: {program: its while body's instruction names}."""
+    from memgraph_tpu.ops import components, semiring as S, traversal
+    n, e = GRAPH500_N_PAD, GRAPH500_E_PAD
+    edges = {"src": sds((e,), "int32"), "dst": sds((e,), "int32")}
+    built = {
+        "bfs": (S._build_fixpoint(
+            S.resolve_semiring("min_plus"),
+            epilogue=traversal._bfs_undirected_epilogue, setup=None,
+            step=traversal._bfs_undirected_step, n_out=n,
+            max_iterations=10_000, metric="changed", precision="f32",
+            sorted=False, sorted_backward=False, direction="fwd"),
+            edges, sds((n,), "int32")),
+        "sssp": (S._build_fixpoint(
+            S.resolve_semiring("min_plus"),
+            epilogue=traversal._sssp_epilogue, setup=None,
+            step=traversal._sssp_step_undirected, n_out=n,
+            max_iterations=10_000, metric="changed", precision="f32",
+            sorted=False, sorted_backward=False, direction="fwd"),
+            dict(edges, w=sds((e,), "float32")), sds((n,), "float32")),
+        "wcc": (S._build_fixpoint(
+            S.resolve_semiring("min_first"),
+            epilogue=components._wcc_epilogue, setup=None, step=None,
+            n_out=n, max_iterations=200, metric="changed", precision="f32",
+            sorted=False, sorted_backward=False, direction="both"),
+            edges, sds((n,), "int32")),
+    }
+    return {algo: _while_body_ops(fn.lower(arrays, {}, x0).compile())
+            for algo, (fn, arrays, x0) in built.items()}
+
+
+@pytest.mark.parametrize("algo", ["bfs", "sssp", "wcc"])
+def test_graphalytics_roofline_counts_one_op_an_iteration(sweeps, algo):
+    """The op a roofline counts as one iteration stands once in its
+    program's loop body and in no other sweep of the cell's cycle."""
+    import json
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chipbench",
+        "layer_metrics", f"{algo}_roofline.json")
+    with open(path) as f:
+        tick, = json.load(f)["params"]["once_per_iteration"]
+    assert sweeps[algo].count(tick) == 1, sweeps[algo]
+    for other, ops in sweeps.items():
+        if other != algo:
+            assert tick not in ops, (other, tick)
+
+
 def test_lane_hop_counts_compiles(sds, pokec):
     """The two-hop filtered aggregate of README §Compiled read lane."""
     from memgraph_tpu.ops import pipeline as pl
