@@ -6,7 +6,10 @@ the metric out of the line; it never returns 0 for a share.
   trace_idle      100 * (1 - device busy / traced slice)
   trace_ops       seconds of the rows of "table" ("ops": device ops,
                   "modules": jitted programs) whose names match, per cycle
-  trace_roofline  least seconds for the traced iterations / their ops' seconds
+  trace_roofline  least seconds for the traced iterations / their ops' seconds;
+                  the iterations are the delta of the program's counters
+                  across the trace slice ("iterations_counter"), or else
+                  the runs of an op the compiler named ("once_per_iteration")
   stats_delta     a ratio of /stats counter deltas over the window
   client_class    the median of the named classes' request times
 """
@@ -50,26 +53,42 @@ def trace_ops(params, ctx):
         r["seconds"] for r in rows.values()) / cycles
 
 
+def _counter_sum(stats: dict, names: list) -> float:
+    return sum(value for key, value in stats.items()
+               if any(fnmatch.fnmatchcase(key, n) for n in names))
+
+
+def _traced_iterations(params, ctx, trace) -> float:
+    """The iterations whose device time the trace holds: by the named
+    counters where the metric names them, which count the same work
+    whatever the compiler calls its ops; else by the runs of the op the
+    loop body makes once. A counter named where the slice has no
+    snapshots counts nothing."""
+    names = params.get("iterations_counter")
+    if names is None:
+        ticks = _matching(trace["ops"], params["once_per_iteration"])
+        return sum(r["count"] for r in ticks.values())
+    before = ctx.get("trace_stats_before")
+    after = ctx.get("trace_stats_after")
+    if before is None or after is None:
+        return 0
+    return _counter_sum(after, names) - _counter_sum(before, names)
+
+
 def trace_roofline(params, ctx):
     trace = _device_trace(ctx)
     if trace is None:
         return None
     rows = _matching(trace[params["table"]], params["patterns"])
-    ticks = _matching(trace["ops"], params["once_per_iteration"])
     seconds = sum(r["seconds"] for r in rows.values())
-    iterations = sum(r["count"] for r in ticks.values())
-    if not seconds or not iterations:
+    iterations = _traced_iterations(params, ctx, trace)
+    if not seconds or iterations <= 0:
         return None
     module = seams.load_module(ctx.get("dirs"), "rooflines",
                                params["roofline"])
     least = module.least_seconds(ctx["n_nodes"], ctx["n_edges"], iterations,
                                  ctx["peak"])
     return 100.0 * least["seconds"] / seconds
-
-
-def _counter_sum(stats: dict, names: list) -> float:
-    return sum(value for key, value in stats.items()
-               if any(fnmatch.fnmatchcase(key, n) for n in names))
 
 
 def stats_delta(params, ctx):

@@ -176,13 +176,25 @@ def run_window(mix: dict, plans, transports, seconds: float, owner,
                trace_dir: str | None):
     """Closed loops for `seconds`; with a trace directory, the owner's
     profiler covers a slice at the start. Returns (t0, requests by
-    client, trace info)."""
+    client, trace info).
+
+    The trace info holds the owner's counters at the slice's two edges
+    (``stats_before``, ``stats_after``), for the readers that count the
+    traced work by the program's own counters. The first is taken before
+    the profiler starts, when no client runs yet, so it adds no idle
+    time to the slice; the second once the slice's last cycle has
+    returned. In the one-client mixes whose rooflines read counters
+    (``analytics_fresh``, ``graphalytics_fresh``) the next request is
+    the next cycle's burst write, which runs no analytics program, so
+    no counted program straddles an edge."""
     per_cycle = per_cycle_of(mix)
     outs = [[] for _ in plans]
     trace = None
     if trace_dir is not None:
+        stats_before = owner.stats()
         started = owner.ask("trace_start", dir=trace_dir)
-        trace = {"started_ns": started["started_ns"]}
+        trace = {"started_ns": started["started_ns"],
+                 "stats_before": stats_before}
     t0 = time.perf_counter()
     deadline = t0 + seconds
     threads = [threading.Thread(
@@ -202,6 +214,7 @@ def run_window(mix: dict, plans, transports, seconds: float, owner,
         else:
             time.sleep(min(float(want["seconds"]), seconds))
         trace["requests_in_slice"] = sum(len(o) for o in outs)
+        trace["stats_after"] = owner.stats()
         stopped = owner.ask("trace_stop", timeout_s=TRACE_STOP_TIMEOUT_S)
         trace["window_s"] = (stopped["stopped_ns"]
                              - trace["started_ns"]) / 1e9
@@ -616,6 +629,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         "trace": trace_summary, "peak": peaks.get(device["kind"]),
         "trace_window_s": (trace_info or {}).get("window_s"),
         "traced_cycles": (trace_info or {}).get("cycles"),
+        "trace_stats_before": (trace_info or {}).get("stats_before"),
+        "trace_stats_after": (trace_info or {}).get("stats_after"),
     }
     by_class: dict = {}
     for r in reqs:
