@@ -192,6 +192,9 @@ STAT_NAMES = (
     "analytics.wcc.iterations_total",
     "analytics.cdlp.calls_total",
     "analytics.cdlp.iterations_total",
+    # label propagation's election by counts (a view without a weight
+    # property, ops/labelprop.py): one a call that runs its program
+    "analytics.labelprop.count_election_total",
     # streaming ingestion plane (r17, mgstream): supervised exactly-once
     # consumers — transactional offsets, quarantine, backpressure
     "stream.batches_total",         # batches durably committed

@@ -200,6 +200,30 @@ def edge_reduce(kind, vals, ids, num_segments: int, sorted: bool = False):
     raise ValueError(f"unknown ⊕ {kind!r}")
 
 
+def run_starts(first):
+    """At every position of a sorted key column, the first position of
+    its run of equal keys (``first`` marks where a run starts; position
+    0 must). ``idx - run_starts(first) + 1`` counts the run so far, an
+    exact int32 that is the run's length at its last position: the
+    sorted-run form of ``edge_reduce("sum", 1, run_id)``, with no
+    scatter."""
+    import jax
+    import jax.numpy as jnp
+    idx = jnp.arange(first.shape[0], dtype=jnp.int32)
+    return jax.lax.cummax(jnp.where(first, idx, 0))
+
+
+def segment_cummax(vals, seg_start):
+    """Running max of int32 ``vals`` that restarts at every segment of a
+    sorted layout (``seg_start``: each position's segment's first
+    position, from :func:`run_starts`): one global cummax over keys
+    offset by the segment's start, with no scatter. Exact where every
+    value lies in [0, its segment's length]: an earlier segment's keys
+    then never pass a later segment's start."""
+    import jax
+    return jax.lax.cummax(seg_start + vals) - seg_start
+
+
 def reduce_identity(sr, dtype):
     """The ⊕ identity (what masked-out edges must contribute)."""
     import jax.numpy as jnp

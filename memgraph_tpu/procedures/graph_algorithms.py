@@ -480,7 +480,8 @@ def _community_impl(ctx, max_iterations=30, weight_property=None,
         # CALL's demoted cache hit
         labels, iters = label_propagation(
             graph, max_iterations=int(max_iterations),
-            labels0=labels0 if online else None)
+            labels0=labels0 if online else None,
+            weighted=weight_property is not None)
         store(labels, iters if online else None)
     # apart from device.fixpoint_iterations_total, which is PageRank's
     global_metrics.increment("analytics.cdlp.calls_total")

@@ -305,6 +305,24 @@ def test_counters_and_spans_move_by_one_call(algo, query, kernel,
     assert moved == [1, returned[0], 1, 1, 1]
 
 
+@pytest.mark.parametrize("weight_argument,elections", [("", 1),
+                                                       (", 'weight'", 0)],
+                         ids=["unweighted", "weighted"])
+def test_cdlp_elects_by_counts_unless_the_view_is_weighted(
+        weight_argument, elections):
+    """A view without a weight property runs the election by counts,
+    once a call; a weighted view sums its weights and never counts."""
+    graph = ref.kronecker(8, 16, 81)
+    ictx = loaded(graph)
+    names = ("analytics.labelprop.count_election_total",
+             "analytics.cdlp.calls_total")
+    before = [counter(n) for n in names]
+    execute(ictx, CDLP.replace("$rounds", "$rounds" + weight_argument),
+            {"rounds": ROUNDS})
+    assert [counter(n) - b for n, b in zip(names, before)] == \
+        [elections, 1]
+
+
 def test_index_order_is_creation_order():
     """CDLP's smallest-label rule is Graphalytics's smallest-id rule only
     where a vertex's dense index follows its id: the device graph's

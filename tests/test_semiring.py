@@ -355,6 +355,66 @@ def test_labelprop_bit_exact(graph):
     assert np.array_equal(np.asarray(old[:N]), np.asarray(new))
 
 
+_UNIT_CASES = ("uniform", "isolated", "hub_ties", "skewed", "self_weight",
+               "directed", "warm")
+
+
+def _unit_weight_case(case):
+    """A seeded unit-weight graph and label_propagation's options for it:
+    (n, src, dst, options)."""
+    rng = np.random.default_rng(_UNIT_CASES.index(case))
+    n, e = 300, 2000
+    src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
+    options = {}
+    if case == "isolated":          # the upper half has no edge
+        src, dst = src // 2, dst // 2
+    elif case == "hub_ties":        # a star over pairs: equal counts
+        leaves = np.arange(1, n)
+        src = np.concatenate([np.zeros(n - 1, np.int64), leaves[:-1:2]])
+        dst = np.concatenate([leaves, leaves[1::2]])
+    elif case == "skewed":          # one dst's segment is most of them
+        dst = (rng.random(e) ** 6 * n).astype(np.int64)
+    elif case == "self_weight":
+        options["self_weight"] = 2.0
+    elif case == "directed":
+        options["directed"] = True
+    elif case == "warm":
+        options["labels0"] = rng.integers(0, n // 10, n)
+    return n, src, dst, options
+
+
+@pytest.mark.parametrize("case", _UNIT_CASES)
+def test_labelprop_count_election_is_exact(case):
+    """On a unit-weight graph the election by counts (a view without a
+    weight property) gives the labels and rounds of the election by
+    weights, of the frozen pre-refactor kernel where it applies (it
+    starts from the ids) and of the Graphalytics CDLP reference where
+    that applies (undirected, no self weight, from the ids)."""
+    import graphalytics_reference as ref
+    from memgraph_tpu.ops.labelprop import label_propagation
+    n, src, dst, options = _unit_weight_case(case)
+    g = csr.from_coo(src, dst, None, n_nodes=n)
+    got, it = label_propagation(g, max_iterations=10, weighted=False,
+                                **options)
+    want, want_it = label_propagation(g, max_iterations=10, **options)
+    assert it == want_it
+    assert np.array_equal(got, want)
+    if "labels0" not in options:
+        if options.get("directed"):
+            src2, dst2, w2 = g.src_idx, g.col_idx, g.weights
+        else:
+            src2 = jnp.concatenate([g.src_idx, g.col_idx])
+            dst2 = jnp.concatenate([g.col_idx, g.src_idx])
+            w2 = jnp.concatenate([g.weights, g.weights])
+        old, oit = _old_labelprop(
+            src2, dst2, w2, g.n_pad, int(src2.shape[0]), 10,
+            jnp.float32(options.get("self_weight", 0.0)))
+        assert int(oit) == it
+        assert np.array_equal(np.asarray(old[:n]), got)
+    if not options:
+        assert np.array_equal(got, ref.cdlp(n, src, dst, 10))
+
+
 @partial(jax.jit, static_argnames=("n_pad", "max_iterations"))
 def _old_wcc(src, dst, n_pad, max_iterations):
     comp0 = jnp.arange(n_pad, dtype=jnp.int32)

@@ -247,6 +247,47 @@ def test_graphalytics_roofline_counts_one_op_an_iteration(sweeps, algo):
             assert tick not in ops, (other, tick)
 
 
+def _while_body_lines(compiled) -> list:
+    """The instructions of the compiled program's while body and of every
+    computation they call (fusions, comparators), as HLO text lines."""
+    text = compiled.as_text()
+    blocks = dict(re.findall(r"\n%?([\w.\-]+) [^\n]*\{\n(.*?)\n\}", text,
+                             re.S))
+    todo = [re.search(r"body=%?([\w.\-]+)", text).group(1)]
+    seen, lines = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        block = blocks[name].splitlines()
+        lines += block
+        for line in block:
+            todo += re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line)
+    return lines
+
+
+def test_cdlp_count_election_scatters_nothing(sds):
+    """The election by counts (label_propagation.get on a view without
+    a weight property) at graph500_s17.graphalytics_fresh's shapes: its
+    round is one sort and cumulative passes, no scatter, and the
+    program keeps the name cdlp_device_ms and cdlp_roofline match."""
+    from memgraph_tpu.ops import labelprop, semiring as S
+    n, e2 = GRAPH500_N_PAD, 2 * GRAPH500_E_PAD
+    fn = S._build_fixpoint(
+        S.resolve_semiring("max_min"), epilogue=labelprop._labelprop_epilogue,
+        setup=labelprop._count_setup, step=labelprop._count_step, n_out=n,
+        max_iterations=10, metric="changed", precision="f32", sorted=False,
+        sorted_backward=False, direction="fwd")
+    compiled = fn.lower(
+        {"src": sds((e2,), "int32"), "dst": sds((e2,), "int32")},
+        _scalars(sds, self_weight="float32"), sds((n,), "int32")).compile()
+    assert re.match(r"HloModule jit_fixpoint_labelprop\b", compiled.as_text())
+    body = _while_body_lines(compiled)
+    assert not [line for line in body if " scatter(" in line]
+    assert len([line for line in body if " sort(" in line]) == 1
+
+
 def test_lane_hop_counts_compiles(sds, pokec):
     """The two-hop filtered aggregate of README §Compiled read lane."""
     from memgraph_tpu.ops import pipeline as pl
